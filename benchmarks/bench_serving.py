@@ -98,9 +98,11 @@ The MESH scenario measures the data-parallel tier split (scheduler
 ``mesh=``): R=4 S replicas shard_map'd over the ``data`` axis, each owning
 a disjoint slot slice + its own paged-pool shard, escalations staged
 through the double-buffered device transfer written at tick top.  It runs
-in a subprocess with forced host devices (theta=0, the S-resident regime —
-on a CI host the mesh "devices" share one core, so the L tier's GSPMD
-replication would measure the host, not the design) and reports req/s vs
+in-process over the backend's devices when there are at least four, else in
+a CPU-pinned subprocess with forced host devices (theta=0, the S-resident
+regime — on a CI host the mesh "devices" share one core, so the L tier's
+GSPMD replication would measure the host, not the design; the result's
+``platform`` says which) and reports req/s vs
 the single-device scheduler, tick counts, and the per-tick phase buckets
 including ``transfer_overlap``.
 
@@ -126,6 +128,7 @@ from benchmarks.common import emit
 from repro.configs.base import HIConfig
 from repro.configs.registry import ARCHS
 from repro.core.calibrate import p_histogram
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.serving.audit import GateAudit
 from repro.serving.batcher import Batcher, Request, pad_to_bucket
@@ -916,9 +919,10 @@ def _calibrate_theta(eng, reqs, quantile: float = 0.25) -> float:
 
 
 # -- mesh-sharded tier-split serving -----------------------------------------
-# the data-parallel S-replica bench runs in a subprocess with forced host
-# devices (XLA_FLAGS must be set before jax import, so the parent — already
-# holding an initialized single-device jax — re-execs this module)
+# the data-parallel S-replica bench runs in-process when the backend has
+# enough devices (a 4-chip host); otherwise in a CPU-pinned subprocess with
+# forced host devices (XLA_FLAGS must be set before jax import, so the
+# parent — already holding an initialized jax — re-execs this module)
 MESH_DATA = 4                  # S replicas on the `data` axis
 MESH_SLOTS = 4                 # decode slots per replica
 MESH_STEPS = 8
@@ -926,9 +930,10 @@ MESH_REQUESTS = 48
 
 
 def _mesh_worker(smoke: bool) -> dict:
-    """Runs INSIDE the forced-multi-device subprocess (``--mesh-worker``):
-    time the mesh-sharded scheduler (``serve_stream(mesh=...)``, data=4) vs
-    the single-device scheduler on the same host, same workload.
+    """Runs over ``MESH_DATA`` real devices, or INSIDE the forced-multi-device
+    CPU subprocess (``--mesh-worker``): time the mesh-sharded scheduler
+    (``serve_stream(mesh=...)``, data=4) vs the single-device scheduler on
+    the same host, same workload.
 
     The workload is theta=0 (every request finishes on its S replica): on a
     CI host whose "devices" are forced slices of ONE core, the GSPMD
@@ -990,14 +995,22 @@ def _mesh_worker(smoke: bool) -> dict:
         "phase_ms_per_tick": {k: v / max(ticks_mesh, 1)
                               for k, v in phase_ms.items()},
         "stream_compiled_shapes": 1,
+        "platform": jax.devices()[0].platform,
     }
 
 
 def _bench_mesh(smoke: bool) -> dict:
-    """Parent-side driver: re-exec this module with forced host devices and
-    ``--mesh-worker``, parse the marker line it prints."""
+    """Run the mesh scenario over this process's devices when there are at
+    least ``MESH_DATA`` of them — one process holds every chip, so no child
+    ever competes for them.  Otherwise re-exec this module PINNED TO THE CPU
+    backend (``JAX_PLATFORMS=cpu``) with forced host devices and
+    ``--mesh-worker``, and parse the marker line it prints; the result's
+    ``platform`` is then ``"cpu"``."""
+    if len(jax.devices()) >= MESH_DATA:
+        return _mesh_worker(smoke)
     root = pathlib.Path(__file__).resolve().parent.parent
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count="
                         f"{MESH_DATA}").strip()
@@ -1322,6 +1335,7 @@ def main():
                     help="write the instrumented pass's Chrome trace_event "
                          "JSON here (load in chrome://tracing or Perfetto)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mesh_worker:
         print("MESH_BENCH_JSON:" + json.dumps(_mesh_worker(args.smoke)))
         return

@@ -1,53 +1,55 @@
-"""decode_attention — flash-style single-token GQA attention over a long KV
-cache (the decode_32k / long_500k hot spot).
+"""decode_attention — flash-style GQA attention over a PAGED KV pool.
 
-One query token attends over S cached keys.  The kernel streams the cache
-through VMEM in ``block_s`` tiles and keeps the online-softmax state
-(running max m, normaliser l, accumulator acc) in revisited output blocks —
-the grid's last dimension is sequential on TPU, which makes output revisiting
-the canonical accumulation idiom (no scratch carry needed across grid steps).
+One kernel serves every attention read of the serving tick: a block of C
+query tokens per slot (C = 1 for decode, C = chunk for the chunk lane and
+the speculative verify pass) attends over the slot's pages, which the
+kernel DMAs straight out of the shared physical pool through the block
+table.  The contiguous-cache decode entry point is the same kernel over an
+identity block table (the cache viewed as one page run per row).
 
-Grid: (batch, kv_head, S // block_s).  Each step loads:
-  q    (1, 1, G, D)   — the G query heads of this kv group   [VMEM]
-  k/v  (1, block_s, 1, D)                                      [VMEM]
-  mask (1, block_s)    — validity (pos, sliding window)        [VMEM]
-so VMEM per step is ~2 * block_s * D * itemsize, independent of S — this is
-what makes the 500k-token cache workable.
+Grid: (batch, page).  The block table is a SCALAR-PREFETCH operand, so the
+K/V index_maps dereference it: grid step (b, j) DMAs physical page
+``block[b, j]`` as a whole ``(1, page, K, D)`` tile — every KV head of the
+page in one copy — and the body loops over the K heads.  Whole trailing
+dims keep every block shape legal for the TPU's (8, 128) tiling at any
+head count and head_dim (qwen2's K=2/D=128, zamba2's K=32/D=80).  Per step
+the kernel holds:
 
-Numerical-safety choices: running max starts at -1e30 (finite, so the
-`exp(m - m_new)` correction never sees inf-inf = NaN) and masked probability
-mass is explicitly zeroed (a fully-masked tile keeps l = 0).
+  q     (1, K, C*G, D)      the slot's query rows, head-major   [VMEM]
+  k/v   (1, page, K, D)     one physical page                   [VMEM]
+  mask  (1, 1, C*G, page)   positional + intra-chunk causal     [VMEM]
+
+and keeps the online-softmax state (running max m, normaliser l, fp32
+accumulator) in VMEM scratch across the sequential page axis; the output
+block is written once, at the slot's last page.  Consecutive grid steps
+that name the same physical page (the null page 0 past a slot's live
+length) are not re-fetched.
+
+Numerical-safety choices: the running max starts at -1e30 (finite, so the
+``exp(m - m_new)`` correction never sees inf-inf = NaN) and masked
+probability mass is explicitly zeroed (a fully-masked page keeps l = 0).
 
 Quantized page pools (int8, per-page-per-head scales)
 -----------------------------------------------------
-The paged kernels optionally take the pool QUANTIZED: ``pool_k/v`` become
-(P, page, K, Dh) int8 and a symmetric fp32 scale tensor ``scale_k/v`` of
-shape (P, K) rides beside each pool (one scale per physical page per kv
-head; dequantized value = ``int8 * scale[page, head]``).  The scale is a
-fourth/fifth operand block-spec'd THROUGH THE SAME block-table index_map as
-its pool — grid step (b, kv, j) DMAs the (1, 1) scale of physical page
-``block[b, j]`` alongside the page itself — and dequantization happens
-in-register inside ``_paged_kernel``/``_chunk_paged_kernel``, right before
-the fp32 flash update.  HBM traffic per gathered page drops ~2x (int8 pages
-+ 4 bytes/head of scale vs bf16 pages) with no extra pass and no
-materialised dequantized copy.  ``scale_k=None`` (the default) traces
-exactly the unquantized graph, so bf16 pools stay bitwise identical.
-Write-side quantization (monotone per-page running-max scales) lives in
-``models/layers.py``; the scale rows move with their pages under COW via
-``copy_pages_pallas``, which is shape/dtype-generic over the pool operand.
+``pool_k/v`` may be (P, page, K, Dh) int8 with a symmetric fp32 scale
+tensor ``scale_k/v`` of shape (P, K) beside each pool (dequantized value =
+``int8 * scale[page, head]``).  The scales ride the same block-table
+index_map as their pool, viewed as (P, 1, K) so the block is one whole
+row; dequantization is one in-register multiply per head before the fp32
+flash update.  ``scale_k=None`` (the default) traces exactly the
+unquantized graph, so bf16 pools stay bitwise identical.  Write-side
+quantization lives in ``models/layers.py``; the scale rows move with their
+pages under COW via ``copy_pages_pallas``.
 
 Mesh-sharded serving (``scheduler.ContinuousScheduler(mesh=...)``)
 ------------------------------------------------------------------
-Under ``shard_map`` over the ``data`` axis (the S tier's replica fan-out),
-every kernel here sees PER-SHARD local shapes: B is the replica's slot
-count, P the replica's own page pool, and the block table is replica-local
-— nothing in the grid or the BlockSpecs changes, so the kernels compose
-with the sharded tick for any ``data`` size.  The ``model`` axis is
-different: GSPMD cannot partition a ``pallas_call`` body, so an L tier with
-``model > 1`` must run the reference (non-kernel) gather — the scheduler
-rejects ``use_kernel`` + ``model > 1`` up front, and ``_check_heads`` below
-catches the symptom (a locally-narrower K pool meeting an unsharded q)
-with a diagnosis instead of a silently wrong ``h // kh`` group size.
+Under ``shard_map`` over the ``data`` axis every kernel here sees
+PER-SHARD local shapes (the replica's slots, pool and block table), so the
+grid composes with the sharded tick for any ``data`` size.  GSPMD cannot
+partition a ``pallas_call`` body over ``model``: the scheduler rejects
+``use_kernel`` with ``model > 1``, and ``_check_heads`` below catches the
+symptom (a locally-narrower K pool meeting an unsharded q) with a
+diagnosis instead of a silently wrong group size.
 """
 from __future__ import annotations
 
@@ -83,225 +85,50 @@ def _check_heads(h: int, kh: int) -> int:
     return h // kh
 
 
-def _flash_update(q, k, v, valid, acc_ref, m_ref, l_ref, *, scale: float,
-                  init: jnp.ndarray) -> None:
-    """One online-softmax accumulation step shared by the contiguous and the
-    paged kernel.  q: (G, D); k/v: (bs, D); valid: (bs,) bool."""
+def _paged_kernel(blk_ref, q_ref, k_ref, v_ref, *refs, scale: float,
+                  kh: int, quant: bool):
+    """One (slot, page) grid step for all K heads: fold page ``block[b, j]``
+    into every query row's online-softmax state; normalise and write the
+    output at the slot's last page."""
+    del blk_ref      # consumed by the index_maps, not the body
+    if quant:
+        sk_ref, sv_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        mask_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    j = pl.program_id(1)
 
-    @pl.when(init)
+    @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    scores = (q @ k.T) * scale                        # (G, bs)
-    scores = jnp.where(valid[None, :], scores, _NEG)
+    valid = mask_ref[0, 0] > 0                          # (R, page)
+    live = valid.astype(jnp.float32)
+    for hd in range(kh):
+        q = q_ref[0, hd].astype(jnp.float32)            # (R, D)
+        k = k_ref[0, :, hd, :].astype(jnp.float32)      # (page, D)
+        v = v_ref[0, :, hd, :].astype(jnp.float32)
+        if quant:
+            k = k * sk_ref[0, :, hd:hd + 1]             # (1, 1) page scale
+            v = v * sv_ref[0, :, hd:hd + 1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid, s, _NEG)                   # (R, page)
+        m_prev = m_scr[hd]                              # (R, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new) * live
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[hd] = m_new
+        l_scr[hd] = l_scr[hd] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[hd] = acc_scr[hd] * corr + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
 
-    m_prev = m_ref[0, 0]                              # (G,)
-    l_prev = l_ref[0, 0]
-    acc_prev = acc_ref[0, 0]                          # (G, D)
-
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
-    p = jnp.exp(scores - m_new[:, None]) * valid[None, :].astype(jnp.float32)
-    corr = jnp.exp(m_prev - m_new)
-
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_ref[0, 0] = acc_prev * corr[:, None] + p @ v
-
-
-def _kernel(q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref, *,
-            scale: float):
-    _flash_update(q_ref[0, 0].astype(jnp.float32),
-                  k_ref[0, :, 0].astype(jnp.float32),
-                  v_ref[0, :, 0].astype(jnp.float32),
-                  mask_ref[0] > 0,
-                  acc_ref, m_ref, l_ref, scale=scale,
-                  init=pl.program_id(2) == 0)
-
-
-def decode_attention_pallas(q: jnp.ndarray, cache_k: jnp.ndarray,
-                            cache_v: jnp.ndarray, valid: jnp.ndarray,
-                            *, block_s: int = 512, interpret: bool = True
-                            ) -> jnp.ndarray:
-    """q: (B, 1, H, D); cache_k/v: (B, S, K, D); valid: (S,) bool.
-
-    Returns (B, 1, H, D) attention output (fp32 accumulation)."""
-    b, _, h, d = q.shape
-    s, kh = cache_k.shape[1], cache_k.shape[2]
-    g = _check_heads(h, kh)
-    qg = q.reshape(b, kh, g, d)
-    # largest divisor of S not exceeding the requested tile; block_s == s
-    # simply yields a single-step grid (nsb == 1)
-    block_s = math.gcd(s, block_s)
-    nsb = s // block_s
-    mask = jnp.broadcast_to(valid.astype(jnp.int32)[None, :], (b, s))
-
-    kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(d))
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid=(b, kh, nsb),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, ki, si: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda bi, ki, si: (bi, si, ki, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda bi, ki, si: (bi, si, ki, 0)),
-            pl.BlockSpec((1, block_s), lambda bi, ki, si: (bi, si)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, ki, si: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda bi, ki, si: (bi, ki, 0)),
-            pl.BlockSpec((1, 1, g), lambda bi, ki, si: (bi, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kh, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, g), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qg, cache_k, cache_v, mask)
-
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(b, 1, h, d).astype(q.dtype)
-
-
-# ---------------------------------------------------------------------------
-# paged variant: gather K/V through an int32 block table
-# ---------------------------------------------------------------------------
-#
-# Continuous batching stores the KV cache as ONE physical page pool shared by
-# every slot; each slot's logical pages map to physical ones via a block
-# table.  The kernel never materialises the gathered (B, S, K, D) cache: the
-# block table is a SCALAR-PREFETCH operand (available before the body runs on
-# TPU), so the K/V BlockSpec index_maps dereference it directly — grid step
-# (b, kv, j) DMAs physical page ``block[b, j]`` into VMEM.  Everything else
-# (online softmax, output revisiting over the sequential last grid dim) is the
-# contiguous kernel's discipline, shared via ``_flash_update``.
-
-
-def _paged_kernel(blk_ref, q_ref, k_ref, v_ref, mask_ref,
-                  acc_ref, m_ref, l_ref, *, scale: float):
-    del blk_ref      # consumed by the index_maps, not the body
-    _flash_update(q_ref[0, 0].astype(jnp.float32),
-                  k_ref[0, :, 0].astype(jnp.float32),
-                  v_ref[0, :, 0].astype(jnp.float32),
-                  mask_ref[0, 0] > 0,
-                  acc_ref, m_ref, l_ref, scale=scale,
-                  init=pl.program_id(2) == 0)
-
-
-def _paged_kernel_quant(blk_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref,
-                        mask_ref, acc_ref, m_ref, l_ref, *, scale: float):
-    """int8-pool variant: the page's (1, 1) per-head scale rides in via the
-    same block-table index_map as the page; dequant is one in-register
-    multiply before the shared fp32 flash update."""
-    del blk_ref      # consumed by the index_maps, not the body
-    _flash_update(q_ref[0, 0].astype(jnp.float32),
-                  k_ref[0, :, 0].astype(jnp.float32) * sk_ref[0, 0],
-                  v_ref[0, :, 0].astype(jnp.float32) * sv_ref[0, 0],
-                  mask_ref[0, 0] > 0,
-                  acc_ref, m_ref, l_ref, scale=scale,
-                  init=pl.program_id(2) == 0)
-
-
-def copy_pages_pallas(pool: jnp.ndarray, src_of: jnp.ndarray, *,
-                      interpret: bool = True) -> jnp.ndarray:
-    """Copy-on-write page duplication over a physical page pool.
-
-    pool: (L, P, page, K, D); src_of: (P,) int32 per-page SOURCE map —
-    identity everywhere except the COW destinations, which name the page
-    they clone.  The map is a scalar-prefetch operand (the same idiom as the
-    paged decode kernel's block table): grid step (l, p) DMAs physical page
-    ``src_of[p]`` into VMEM and writes it back out as page ``p``, so the
-    copy never round-trips through HBM-resident gather/scatter buffers and
-    every page is written exactly once (identity pages stream through
-    unchanged — no aliasing or output-revisiting hazards).
-
-    Admission schedules at most one COW per admitted request, so on TPU the
-    non-identity traffic is a handful of pages; the identity passthrough is
-    the price of a single well-formed grid.  Returns the updated pool.
-    """
-    l, p = pool.shape[:2]
-    blk = (1, 1) + pool.shape[2:]
-
-    def kernel(src_ref, in_ref, out_ref):
-        del src_ref          # consumed by the index_map, not the body
-        out_ref[...] = in_ref[...]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(l, p),
-        in_specs=[
-            pl.BlockSpec(blk, lambda li, pi, src: (li, src[pi]) +
-                         (0,) * (len(blk) - 2)),
-        ],
-        out_specs=pl.BlockSpec(blk, lambda li, pi, src: (li, pi) +
-                               (0,) * (len(blk) - 2)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        interpret=interpret,
-    )(src_of, pool)
-
-
-def _chunk_paged_kernel(blk_ref, q_ref, k_ref, v_ref, mask_ref,
-                        acc_ref, m_ref, l_ref, *, scale: float):
-    """Multi-token generalisation of ``_paged_kernel``: a query BLOCK of C
-    tokens per slot (per-slot start positions are already folded into the
-    validity mask, which carries the intra-chunk causal structure).  The
-    online-softmax state gains a leading C axis; everything else — scalar-
-    prefetched block table, page DMA via the index_map, output revisiting
-    over the sequential last grid dim — is the single-token kernel's
-    discipline."""
-    del blk_ref      # consumed by the index_maps, not the body
-    _chunk_flash_update(q_ref[0, 0].astype(jnp.float32),        # (C, G, D)
-                        k_ref[0, :, 0].astype(jnp.float32),     # (page, D)
-                        v_ref[0, :, 0].astype(jnp.float32),
-                        mask_ref[0, :, 0] > 0,                  # (C, page)
-                        acc_ref, m_ref, l_ref, scale=scale)
-
-
-def _chunk_paged_kernel_quant(blk_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref,
-                              mask_ref, acc_ref, m_ref, l_ref, *,
-                              scale: float):
-    """int8-pool variant of ``_chunk_paged_kernel`` — same in-register
-    per-page-per-head dequant as ``_paged_kernel_quant``."""
-    del blk_ref      # consumed by the index_maps, not the body
-    _chunk_flash_update(q_ref[0, 0].astype(jnp.float32),
-                        k_ref[0, :, 0].astype(jnp.float32) * sk_ref[0, 0],
-                        v_ref[0, :, 0].astype(jnp.float32) * sv_ref[0, 0],
-                        mask_ref[0, :, 0] > 0,
-                        acc_ref, m_ref, l_ref, scale=scale)
-
-
-def _chunk_flash_update(q, k, v, valid, acc_ref, m_ref, l_ref, *,
-                        scale: float) -> None:
-    """Chunked online-softmax step: q (C, G, D); k/v (page, D); valid
-    (C, page)."""
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    scores = jnp.einsum("cgd,sd->cgs", q, k) * scale       # (C, G, page)
-    scores = jnp.where(valid[:, None, :], scores, _NEG)
-
-    m_prev = m_ref[0, 0]                          # (C, G)
-    l_prev = l_ref[0, 0]
-    acc_prev = acc_ref[0, 0]                      # (C, G, D)
-
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
-    p = jnp.exp(scores - m_new[..., None]) \
-        * valid[:, None, :].astype(jnp.float32)
-    corr = jnp.exp(m_prev - m_new)
-
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_ref[0, 0] = acc_prev * corr[..., None] + \
-        jnp.einsum("cgs,sd->cgd", p, v)
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        for hd in range(kh):
+            o_ref[0, hd] = (acc_scr[hd] / jnp.maximum(l_scr[hd], 1e-30)
+                            ).astype(o_ref.dtype)
 
 
 def decode_attention_chunk_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
@@ -310,7 +137,8 @@ def decode_attention_chunk_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
                                         valid: jnp.ndarray, *,
                                         scale_k: jnp.ndarray | None = None,
                                         scale_v: jnp.ndarray | None = None,
-                                        interpret: bool = True) -> jnp.ndarray:
+                                        interpret: bool = False
+                                        ) -> jnp.ndarray:
     """q: (B, C, H, D) — a chunk of C query tokens per slot; pool_k/v:
     (P, page, K, D); block: (B, n_pages) int32; valid: (B, C, n_pages * page)
     bool — per-slot positional AND intra-chunk causal mask (query i of slot b
@@ -323,54 +151,53 @@ def decode_attention_chunk_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
     docstring); dequant fuses into the gather.  Returns (B, C, H, D)
     attention output (fp32 accumulation)."""
     b, c, h, d = q.shape
-    page, kh = pool_k.shape[1], pool_k.shape[2]
+    n_phys, page, kh = pool_k.shape[:3]
     npg = block.shape[1]
     g = _check_heads(h, kh)
-    qg = q.reshape(b, c, kh, g, d).transpose(0, 2, 1, 3, 4)  # (B, KH, C, G, D)
+    r = c * g                                       # query rows per kv head
+    # head-major query rows: row c*G + g of head k is query token c, head
+    # k*G + g (the GQA grouping _sdpa uses)
+    qr = q.reshape(b, c, kh, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, kh, r, d)
     mask = valid.astype(jnp.int32).reshape(b, c, npg, page)
+    mask = jnp.broadcast_to(mask.transpose(0, 2, 1, 3)[:, :, :, None, :],
+                            (b, npg, c, g, page)).reshape(b, npg, r, page)
     quant = scale_k is not None
 
-    body = _chunk_paged_kernel_quant if quant else _chunk_paged_kernel
-    kernel = functools.partial(body, scale=1.0 / math.sqrt(d))
-    page_spec = pl.BlockSpec((1, page, 1, d),
-                             lambda bi, ki, si, blk: (blk[bi, si], 0, ki, 0))
-    scale_spec = pl.BlockSpec((1, 1),
-                              lambda bi, ki, si, blk: (blk[bi, si], ki))
-    in_specs = [
-        pl.BlockSpec((1, 1, c, g, d),
-                     lambda bi, ki, si, blk: (bi, ki, 0, 0, 0)),
-        page_spec,
-        page_spec,
-        *([scale_spec, scale_spec] if quant else []),
-        pl.BlockSpec((1, c, 1, page),
-                     lambda bi, ki, si, blk: (bi, 0, si, 0)),
-    ]
+    page_spec = pl.BlockSpec((1, page, kh, d),
+                             lambda bi, j, blk: (blk[bi, j], 0, 0, 0))
+    scale_spec = pl.BlockSpec((1, 1, kh),
+                              lambda bi, j, blk: (blk[bi, j], 0, 0))
+    row_spec = pl.BlockSpec((1, kh, r, d), lambda bi, j, blk: (bi, 0, 0, 0))
+    in_specs = [row_spec, page_spec, page_spec,
+                *([scale_spec, scale_spec] if quant else []),
+                pl.BlockSpec((1, 1, r, page),
+                             lambda bi, j, blk: (bi, j, 0, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kh, npg),
+        grid=(b, npg),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, c, g, d),
-                         lambda bi, ki, si, blk: (bi, ki, 0, 0, 0)),
-            pl.BlockSpec((1, 1, c, g), lambda bi, ki, si, blk: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, c, g), lambda bi, ki, si, blk: (bi, ki, 0, 0)),
-        ],
+        out_specs=row_spec,
+        scratch_shapes=[pltpu.VMEM((kh, r, 1), jnp.float32),
+                        pltpu.VMEM((kh, r, 1), jnp.float32),
+                        pltpu.VMEM((kh, r, d), jnp.float32)],
     )
-    operands = (block, qg, pool_k, pool_v) + \
-        ((scale_k, scale_v) if quant else ()) + (mask,)
-    acc, m, l = pl.pallas_call(
+    scales = ()
+    if quant:
+        scales = (scale_k.reshape(n_phys, 1, kh),
+                  scale_v.reshape(n_phys, 1, kh))
+    kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(d),
+                               kh=kh, quant=quant)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kh, c, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, c, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, c, g), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((b, kh, r, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
-
-    out = acc / jnp.maximum(l, 1e-30)[..., None]            # (B, KH, C, G, D)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, c, h, d).astype(q.dtype)
+    )(block, qr, pool_k, pool_v, *scales, mask)
+    return out.reshape(b, kh, c, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, c, h, d)
 
 
 def decode_attention_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
@@ -378,57 +205,77 @@ def decode_attention_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
                                   valid: jnp.ndarray, *,
                                   scale_k: jnp.ndarray | None = None,
                                   scale_v: jnp.ndarray | None = None,
-                                  interpret: bool = True) -> jnp.ndarray:
+                                  interpret: bool = False) -> jnp.ndarray:
     """q: (B, 1, H, D); pool_k/v: (P, page, K, D); block: (B, n_pages) int32;
     valid: (B, n_pages * page) bool (per-slot positional mask); scale_k/v
-    (P, K) fp32 mark the pools int8-quantized (see module docstring) — the
-    per-page-per-head scale rides the same block-table index_map and dequant
-    fuses into the gather.
+    (P, K) fp32 mark the pools int8-quantized.  The C = 1 case of
+    :func:`decode_attention_chunk_paged_pallas`.
 
     Returns (B, 1, H, D) attention output (fp32 accumulation)."""
-    b, _, h, d = q.shape
-    page, kh = pool_k.shape[1], pool_k.shape[2]
-    npg = block.shape[1]
-    g = _check_heads(h, kh)
-    qg = q.reshape(b, kh, g, d)
-    mask = valid.astype(jnp.int32).reshape(b, npg, page)
-    quant = scale_k is not None
+    return decode_attention_chunk_paged_pallas(
+        q, pool_k, pool_v, block, valid[:, None, :], scale_k=scale_k,
+        scale_v=scale_v, interpret=interpret)
 
-    body = _paged_kernel_quant if quant else _paged_kernel
-    kernel = functools.partial(body, scale=1.0 / math.sqrt(d))
-    page_spec = pl.BlockSpec((1, page, 1, d),
-                             lambda bi, ki, si, blk: (blk[bi, si], 0, ki, 0))
-    scale_spec = pl.BlockSpec((1, 1),
-                              lambda bi, ki, si, blk: (blk[bi, si], ki))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda bi, ki, si, blk: (bi, ki, 0, 0)),
-        page_spec,
-        page_spec,
-        *([scale_spec, scale_spec] if quant else []),
-        pl.BlockSpec((1, 1, page), lambda bi, ki, si, blk: (bi, si, 0)),
-    ]
+
+def decode_attention_pallas(q: jnp.ndarray, cache_k: jnp.ndarray,
+                            cache_v: jnp.ndarray, valid: jnp.ndarray,
+                            *, block_s: int = 512, interpret: bool = False
+                            ) -> jnp.ndarray:
+    """Contiguous-cache decode.  q: (B, 1, H, D); cache_k/v: (B, S, K, D);
+    valid: (S,) bool.  The cache is viewed as a pool of ``block_s``-token
+    pages under an identity block table and read by the paged kernel.
+
+    Returns (B, 1, H, D) attention output (fp32 accumulation)."""
+    b, s = cache_k.shape[:2]
+    # largest divisor of S not exceeding the requested tile
+    block_s = math.gcd(s, block_s)
+    nsb = s // block_s
+    pool_shape = (b * nsb, block_s) + cache_k.shape[2:]
+    table = jnp.arange(b * nsb, dtype=jnp.int32).reshape(b, nsb)
+    return decode_attention_paged_pallas(
+        q, cache_k.reshape(pool_shape), cache_v.reshape(pool_shape), table,
+        jnp.broadcast_to(valid[None, :], (b, s)), interpret=interpret)
+
+
+def copy_pages_pallas(pool: jnp.ndarray, src_of: jnp.ndarray, *,
+                      interpret: bool = False) -> jnp.ndarray:
+    """Copy-on-write page duplication over a physical page pool.
+
+    pool: (L, P, ...) — (L, P, page, K, D) pages or (L, P, K) scale rows;
+    src_of: (P,) int32 per-page SOURCE map — identity everywhere except the
+    COW destinations, which name the page they clone.  The map is a
+    scalar-prefetch operand (the same idiom as the paged attention kernel's
+    block table): grid step (l, p) DMAs physical page ``src_of[p]`` into VMEM
+    and writes it back out as page ``p``, so every page is written exactly
+    once (identity pages stream through unchanged — no aliasing or
+    output-revisiting hazards).  Scale rows are viewed as (L, P, 1, K) so the
+    block's trailing dims are whole.
+
+    Admission schedules at most one COW per admitted request, so on TPU the
+    non-identity traffic is a handful of pages; the identity passthrough is
+    the price of a single well-formed grid.  Returns the updated pool.
+    """
+    shape = pool.shape
+    if pool.ndim == 3:
+        pool = pool.reshape(shape[:2] + (1, shape[2]))
+    l, p = pool.shape[:2]
+    blk = (1, 1) + pool.shape[2:]
+    tail = (0,) * (len(blk) - 2)
+
+    def kernel(src_ref, in_ref, out_ref):
+        del src_ref          # consumed by the index_map, not the body
+        out_ref[...] = in_ref[...]
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kh, npg),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, ki, si, blk: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda bi, ki, si, blk: (bi, ki, 0)),
-            pl.BlockSpec((1, 1, g), lambda bi, ki, si, blk: (bi, ki, 0)),
-        ],
+        grid=(l, p),
+        in_specs=[pl.BlockSpec(blk, lambda li, pi, src: (li, src[pi]) + tail)],
+        out_specs=pl.BlockSpec(blk, lambda li, pi, src: (li, pi) + tail),
     )
-    operands = (block, qg, pool_k, pool_v) + \
-        ((scale_k, scale_v) if quant else ()) + (mask,)
-    acc, m, l = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kh, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, g), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         interpret=interpret,
-    )(*operands)
-
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(b, 1, h, d).astype(q.dtype)
+    )(src_of, pool)
+    return out.reshape(shape)

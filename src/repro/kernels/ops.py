@@ -1,9 +1,14 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret=True`` everywhere in this environment (CPU container; TPU is the
-target).  On a real TPU deployment flip ``INTERPRET`` to False — kernels are
-written against the TPU lowering (BlockSpec VMEM tiling, sequential last grid
-dim, output revisiting).
+The kernels are written for the TPU (BlockSpec VMEM tiling, scalar-prefetched
+block tables, a sequential last grid dim).  Whether they run compiled or
+through the Pallas interpreter follows from the backend, not from a flag:
+:func:`interpret` is False on the TPU and True on the CPU backend, where the
+interpreter validates the kernel bodies (tests, small smoke runs).  Any
+other backend raises — a kernel is never interpreted on an accelerator, and
+never quietly replaced by the jnp path (``use_kernel=False`` selects that).
+``tests/test_tpu_compile.py`` compiles every kernel of the serving tick for
+a v5e at qwen2-1.5b / zamba2-2.7b widths.
 """
 from __future__ import annotations
 
@@ -17,7 +22,16 @@ from repro.kernels import decode_attention as _da
 from repro.kernels import hi_gate as _hg
 from repro.kernels import ssd_scan as _ssd
 
-INTERPRET = True    # CPU container: validate kernel bodies via interpreter
+
+def interpret() -> bool:
+    """True on the CPU backend (Pallas interpreter), False on the TPU
+    (Mosaic-compiled kernels); evaluated at trace time, never at import."""
+    backend = jax.default_backend()
+    if backend in ("cpu", "tpu"):
+        return backend == "cpu"
+    raise NotImplementedError(
+        f"the Pallas kernels target the TPU and cannot run on backend "
+        f"{backend!r}; serve with use_kernel=False")
 
 
 @functools.partial(jax.jit, static_argnames=("metric",))
@@ -29,7 +43,7 @@ def hi_gate(logits: jnp.ndarray, theta, metric: str = "max_prob"
     serving engine's online policy moves it every batch, and a static theta
     would force a recompile per update.
     """
-    return _hg.hi_gate_pallas(logits, theta, metric, interpret=INTERPRET)
+    return _hg.hi_gate_pallas(logits, theta, metric, interpret=interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("block_s",))
@@ -38,7 +52,7 @@ def decode_attention(q: jnp.ndarray, cache_k: jnp.ndarray,
                      block_s: int = 512) -> jnp.ndarray:
     """Flash decode attention.  q: (B,1,H,D); cache: (B,S,K,D); valid: (S,)."""
     return _da.decode_attention_pallas(q, cache_k, cache_v, valid,
-                                       block_s=block_s, interpret=INTERPRET)
+                                       block_s=block_s, interpret=interpret())
 
 
 @jax.jit
@@ -57,7 +71,7 @@ def decode_attention_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     dequant happens in-register inside the kernel."""
     return _da.decode_attention_paged_pallas(q, pool_k, pool_v, block, valid,
                                              scale_k=scale_k, scale_v=scale_v,
-                                             interpret=INTERPRET)
+                                             interpret=interpret())
 
 
 @jax.jit
@@ -77,7 +91,7 @@ def decode_attention_chunk_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     return _da.decode_attention_chunk_paged_pallas(q, pool_k, pool_v, block,
                                                    valid, scale_k=scale_k,
                                                    scale_v=scale_v,
-                                                   interpret=INTERPRET)
+                                                   interpret=interpret())
 
 
 @jax.jit
@@ -94,7 +108,7 @@ def copy_pages(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray
     scalar-prefetched — see ``decode_attention.copy_pages_pallas``."""
     p = pool.shape[1]
     src_of = jnp.arange(p, dtype=jnp.int32).at[dst].set(src)
-    return _da.copy_pages_pallas(pool, src_of, interpret=INTERPRET)
+    return _da.copy_pages_pallas(pool, src_of, interpret=interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -123,7 +137,7 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
     Cc = C.reshape(b, nc, chunk, n)
 
     y_diag, S, g, eacs = _ssd.ssd_chunk_pallas(xc, dtc, A, Bc, Cc,
-                                               interpret=INTERPRET)
+                                               interpret=interpret())
 
     # inter-chunk linear recurrence (tiny: nc steps over (b,h,p,n))
     def step(hprev, xs):

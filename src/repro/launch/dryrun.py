@@ -74,7 +74,7 @@ def run_case(arch: str, shape_name: str, *, multi_pod: bool = False,
         fn, args, in_sh, out_sh = case_specs.make_case(cfg, shape, mesh,
                                                        variant)
         donate = case_specs.donate_for(shape)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                           donate_argnums=donate).lower(*args)
         t_lower = time.perf_counter() - t0

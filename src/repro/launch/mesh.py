@@ -10,7 +10,16 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes, devices) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: GSPMD propagates shardings from the
+    jit boundaries and ``with_sharding_constraint`` hints, which is what the
+    sharding rules here are written for (the installed JAX defaults to
+    Explicit axes, under which those hints raise)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -24,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"need {need} devices for mesh {shape}, have {len(devices)}; "
             "run under launch/dryrun.py which forces 512 host devices")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
 
 
 def make_debug_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -51,5 +60,4 @@ def make_serving_mesh(data: int = 1, model: int = 1) -> Mesh:
             "XLA_FLAGS=--xla_force_host_platform_device_count=N before the "
             "first jax import (tests/conftest.py does this under "
             "REPRO_MULTI_DEVICE=1)")
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[:need])
+    return _auto_mesh((data, model), ("data", "model"), devices[:need])

@@ -1,59 +1,59 @@
-"""Serving driver: batched requests through the HI cascade.
+"""Serving launcher: requests through the HI cascade's continuous-batching
+path (``HIEngine.serve_stream``).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
-      --requests 32 --batch 8 --theta 0.6
+      --requests 32 --slots 8 --theta 0.6
 
-``--no-reduced`` runs the full assigned config (TPU-sized; not CPU-friendly).
+``--no-reduced`` runs the full published config with bf16 params (sized for
+one TPU v5e chip; not CPU-friendly).
 """
 from __future__ import annotations
 
 import argparse
 import time
+from collections import Counter
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import HIConfig
 from repro.configs.registry import ARCHS
-from repro.serving.batcher import Batcher, Request
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.model_zoo import PAGED_FAMILIES
+from repro.serving.batcher import Request
 from repro.serving.engine import build_engine
 
+BUCKETS = (16, 32)
 
-def run(arch: str, *, reduced: bool = True, requests: int = 32, batch: int = 8,
-        theta: float = 0.6, capacity_factor: float = 0.5, seed: int = 0,
-        max_new_tokens: int = 8, metric: str = "max_prob",
-        legacy: bool = False):
+
+def run(arch: str, *, reduced: bool = True, requests: int = 32,
+        slots: int = 8, theta: float = 0.6, seed: int = 0,
+        max_new_tokens: int = 8, metric: str = "max_prob"):
     cfg = ARCHS[arch]
     if reduced:
         cfg = cfg.reduced()
-    if cfg.family in ("encdec", "vlm"):
-        raise SystemExit(f"serve driver covers decoder-only text families; "
-                         f"{cfg.family} is exercised via dryrun + smoke tests")
-    hi = HIConfig(theta=theta, capacity_factor=capacity_factor, metric=metric)
-    engine = build_engine(cfg, hi, max_new_tokens=max_new_tokens, cache_len=64)
-    serve = engine.serve_legacy if legacy else engine.serve
+    if cfg.family not in PAGED_FAMILIES:
+        raise SystemExit(f"the serve launcher covers the paged decoder "
+                         f"families; {cfg.family} is exercised via dryrun + "
+                         "smoke tests")
+    hi = HIConfig(theta=theta, metric=metric)
+    dtype = jnp.float32 if reduced else jnp.bfloat16
+    engine = build_engine(cfg, hi, dtype=dtype, max_new_tokens=max_new_tokens)
 
     rng = np.random.default_rng(seed)
-    batcher = Batcher(batch_size=batch, buckets=(16, 32))
-    for i in range(requests):
-        plen = int(rng.integers(4, 16))
-        batcher.submit(Request(i, rng.integers(
-            0, cfg.vocab_size, plen).astype(np.int32)))
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, int(
+        rng.integers(4, BUCKETS[-1]))).astype(np.int32))
+        for i in range(requests)]
 
-    t0 = time.time()
-    served = 0
-    while batcher.queue:
-        b = batcher.next_batch()
-        out = serve(b.tokens)
-        served += int((b.request_ids >= 0).sum())
-        print(f"batch: offloaded {int(out['offloaded'].sum())}/{len(b.tokens)} "
-              f"mean_conf {out['confidence'].mean():.3f}")
-    dt = time.time() - t0
+    t0 = time.perf_counter()
+    out = engine.serve_stream(reqs, buckets=BUCKETS, num_slots=slots)
+    dt = time.perf_counter() - t0
+    statuses = Counter(str(r["status"]) for r in out.values())
     s = engine.summary()
-    print(f"served {served} requests in {dt:.1f}s "
-          f"({served / max(dt, 1e-9):.1f} req/s) | offload_frac "
-          f"{s['offload_frac']:.2%} drop_frac {s['drop_frac']:.2%} | "
-          f"cascade time {s['serve_time']:.2f}s, {int(s['compiles'])} "
-          f"compiled shapes")
+    print(f"served {len(out)} requests in {dt:.1f}s (compile included) | "
+          f"statuses {dict(statuses)} | offload_frac {s['offload_frac']:.2%} "
+          f"| {int(s['stream_compiles'])} compiled tick, "
+          f"{int(s['stream_ticks'])} ticks")
     return s
 
 
@@ -64,20 +64,16 @@ def main():
                     default=True,
                     help="smoke-scale config (disable with --no-reduced)")
     ap.add_argument("--requests", type=int, default=32)
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--theta", type=float, default=0.6)
-    ap.add_argument("--capacity-factor", type=float, default=0.5)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--metric", default="max_prob",
                     choices=["max_prob", "margin", "entropy"])
-    ap.add_argument("--legacy", action="store_true",
-                    help="use the pre-batched-prefill reference path")
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.arch, reduced=args.reduced, requests=args.requests,
-        batch=args.batch, theta=args.theta,
-        capacity_factor=args.capacity_factor,
-        max_new_tokens=args.max_new_tokens, metric=args.metric,
-        legacy=args.legacy)
+        slots=args.slots, theta=args.theta,
+        max_new_tokens=args.max_new_tokens, metric=args.metric)
 
 
 if __name__ == "__main__":

@@ -87,7 +87,7 @@ def lower_tier_split(cfg: ModelConfig, shape: ShapeConfig, *,
         def step(params, token, cache, _mcfg=mcfg):
             return model_zoo.decode_step(params, _mcfg, token, cache)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = jax.jit(step, in_shardings=(p_sh, t_sh, c_sh),
                                out_shardings=(t_sh, c_sh),
                                donate_argnums=(2,)).lower(

@@ -149,7 +149,6 @@ def moe_ffn(lp: Params, cfg: ModelConfig, x: jnp.ndarray,
 
 def moe_ffn_sharded(lp: Params, cfg: ModelConfig, x: jnp.ndarray,
                     capacity_factor: float, mesh) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -235,12 +234,12 @@ def moe_ffn_sharded(lp: Params, cfg: ModelConfig, x: jnp.ndarray,
     d_ax = data_axes if data_axes else None
     wi_spec = P("model", None, d_ax)          # F-sharded storage (both paths)
     wo_spec = P("model", d_ax, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(data_axes, None, None), P(None, None),
                   wi_spec, wi_spec, wo_spec),
         out_specs=(P(data_axes, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, lp["router"], lp["experts"]["wi"], lp["experts"]["wg"],
               lp["experts"]["wo"])
 

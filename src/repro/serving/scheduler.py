@@ -953,7 +953,6 @@ class ContinuousScheduler:
         params and KV pages over ``model``, and thread the donated
         double-buffered escalation staging buffer through the same single
         executable.  Still ONE compile, ONE host fetch per tick."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.sharding import specs as sh
@@ -995,12 +994,12 @@ class ContinuousScheduler:
             return (jax.tree.map(lambda a: a[None], out),
                     jax.tree.map(lambda a: a[None], pool))
 
-        # check_rep=False: the body is replicated over the (unused) model
+        # check_vma=False: the body is replicated over the (unused) model
         # axis; replication checking can't see that through the squeezes
-        s_sharded = shard_map(s_body, mesh=mesh,
-                              in_specs=(P(), P(), P("data"), P("data")),
-                              out_specs=(P("data"), P("data")),
-                              check_rep=False)
+        s_sharded = jax.shard_map(s_body, mesh=mesh,
+                                  in_specs=(P(), P(), P("data"), P("data")),
+                                  out_specs=(P("data"), P("data")),
+                                  check_vma=False)
 
         def tick(s_params, l_params, theta, s_in, l_in, s_pool, l_pool,
                  stage):

@@ -3,22 +3,23 @@
 GSPMD left to itself shards the residual stream's d_model over `model` and
 replicates batch (observed: +80GB/dev on granite train_4k from replicated
 logits/scores).  Production frameworks pin activation layouts at layer
-boundaries; these helpers do that, keyed off the ambient `with mesh:` context
-so model code stays mesh-agnostic and tests on 1 CPU device are unaffected.
+boundaries; these helpers do that, keyed off the ambient mesh set by
+``with jax.set_mesh(mesh):`` so model code stays mesh-agnostic and tests on 1
+CPU device are unaffected.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-
-from jax._src import mesh as _mesh_lib
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 
-def current_mesh() -> Optional[Mesh]:
-    env = _mesh_lib.thread_resources.env
-    m = env.physical_mesh
+def current_mesh() -> Optional[AbstractMesh]:
+    """The mesh of the enclosing ``jax.set_mesh`` context (valid inside jit),
+    or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 

@@ -25,7 +25,7 @@ _SCRIPT = textwrap.dedent("""
     nodrop = float(cfg.num_experts / cfg.experts_per_token)
     y_local, aux_local = jax.jit(
         lambda lp, x: moe.moe_ffn(lp, cfg, x, nodrop))(lp, x)
-    with mesh:
+    with jax.set_mesh(mesh):
         y_shard, aux_shard = jax.jit(
             lambda lp, x: moe.moe_ffn_sharded(lp, cfg, x, nodrop, mesh))(lp, x)
     np.testing.assert_allclose(np.asarray(y_local), np.asarray(y_shard),

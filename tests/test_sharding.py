@@ -87,7 +87,7 @@ def test_one_device_mesh_train_step(key):
         "tokens": jax.random.randint(key, (4, 16), 0, cfg.vocab_size),
         "labels": jax.random.randint(key, (4, 16), 0, cfg.vocab_size),
     }
-    with mesh:
+    with jax.set_mesh(mesh):
         p_sh = sh.param_shardings(params, mesh, fsdp=True)
         step = jax.jit(trainer.make_train_step(cfg, tcfg),
                        in_shardings=(p_sh, None, None))
