@@ -156,9 +156,11 @@ is exact only up to routing-drop determinism — with the generous decode-path
 """
 from __future__ import annotations
 
+import re
 import time
 import warnings
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Dict, List, Optional
@@ -178,17 +180,40 @@ from repro.serving.faults import (NO_FAULTS, CircuitBreaker, Escalation,
 from repro.serving.kv_pool import AdmitPlan, KVPool
 from repro.serving.telemetry import SchedCounters, StatsView, Telemetry
 
+# The lanes of one tier's tick, each under ``jax.named_scope(<tier>)`` then
+# ``jax.named_scope(<lane>)``: the compiled tick's instructions carry
+# ``.../<tier>/<lane>/...`` in their ``op_name`` metadata, which
+# :meth:`ContinuousScheduler.lane_of_ops` reads back.  ``cow`` copy-on-write
+# page copies; ``admit`` the admission prefill with its gate, sampling and
+# first-token scatter; ``prefix`` the prefix-cache snapshot save/restore;
+# ``chunk`` the chunk-prefill lane; ``decode`` the fused decode (or draft)
+# scan; ``verify`` the speculative verify chunk (L only).
+LANES = ("cow", "admit", "prefix", "chunk", "decode", "verify")
+TIERS = ("S", "L")
+_OP_NAME = re.compile(
+    r'^\s*(?:ROOT )?%?([^\s=]+) = .*metadata=\{[^}]*op_name="([^"]*)"')
 
-def _tier_tick_fn(cfg: ModelConfig, metric: str, use_kernel: bool,
-                  decode_block: int, sharing: bool, chunk: int = 0,
-                  role: str = "plain"):
+
+@contextmanager
+def _lane(tier: str, lane: str):
+    with jax.named_scope(tier), jax.named_scope(lane):
+        yield
+
+
+def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
+                  use_kernel: bool, decode_block: int, sharing: bool,
+                  chunk: int = 0, role: str = "plain"):
     """Device-side per-tier tick: COW copies + batched cond-prefill +
     prefix-cache save/restore + chunk-prefill lane + K fused draft/decode
-    steps (or, for ``role == "spec_l"``, the fused verify chunk).
+    steps (or, for ``role == "spec_l"``, the fused verify chunk) of the
+    ``tier`` ("S" or "L"), each lane under its ``<tier>/<lane>`` scope
+    (:data:`LANES`).
 
     ``chunk``/``role`` are BUILD-time switches: with ``chunk == 0`` and
     ``role == "plain"`` the traced graph is exactly the PR-2/3 tick, which
     is what keeps greedy outputs bitwise stable with the new lanes off."""
+
+    lane = partial(_lane, tier)
 
     def conf_of(logits, theta):
         if use_kernel:
@@ -207,12 +232,13 @@ def _tier_tick_fn(cfg: ModelConfig, metric: str, use_kernel: bool,
             # appends must see the private copies.  Skipped at runtime on the
             # (common) no-COW tick — the kernel path in particular streams
             # the whole page pool, which would tax every steady-state tick.
-            core = jax.lax.cond(
-                tin["any_cow"],
-                lambda c: model_zoo.cow_pages(cfg, c, tin["cow_src"],
-                                              tin["cow_dst"],
-                                              use_kernel=use_kernel),
-                lambda c: c, core)
+            with lane("cow"):
+                core = jax.lax.cond(
+                    tin["any_cow"],
+                    lambda c: model_zoo.cow_pages(cfg, c, tin["cow_src"],
+                                                  tin["cow_dst"],
+                                                  use_kernel=use_kernel),
+                    lambda c: c, core)
 
         def admit(core):
             return model_zoo.prefill_paged(
@@ -226,29 +252,34 @@ def _tier_tick_fn(cfg: ModelConfig, metric: str, use_kernel: bool,
 
         # skipped when nothing is admitted — or (sharing) when every
         # admission this tick is a full-prefix restore
-        logits0, core = jax.lax.cond(tin["any_prefill"], admit, skip, core)
+        with lane("admit"):
+            logits0, core = jax.lax.cond(tin["any_prefill"], admit, skip,
+                                         core)
         prefix = None
         if sharing:
-            prefix = pool["prefix"]
-            # full restores read their admission logits + recurrent state
-            # from the PRE-SAVE prefix cache: a restore's entry was filled in
-            # an earlier tick, and reading before this tick's saves keeps a
-            # same-tick eviction that recycles the restore's row (LRU under
-            # row pressure) from corrupting the restored state
-            logits0 = jnp.where(tin["restore_mask"][:, None],
-                                prefix["logits"][tin["restore_row"]], logits0)
-            core = model_zoo.snapshot_restore(cfg, core, prefix,
-                                              tin["restore_row"],
-                                              tin["restore_slot"])
-            # computing admissions persist their logits + recurrent state
-            # into their reserved rows (sentinel rows drop); computing slots
-            # are disjoint from restored slots, so the gather below is
-            # unaffected by the restore scatter above
-            prefix = dict(prefix, logits=prefix["logits"].at[
-                tin["save_row"]].set(logits0, mode="drop"))
-            prefix = model_zoo.snapshot_save(cfg, core, prefix,
-                                             tin["save_row"],
-                                             tin["admit_slot"])
+            with lane("prefix"):
+                prefix = pool["prefix"]
+                # full restores read their admission logits + recurrent
+                # state from the PRE-SAVE prefix cache: a restore's entry
+                # was filled in an earlier tick, and reading before this
+                # tick's saves keeps a same-tick eviction that recycles the
+                # restore's row (LRU under row pressure) from corrupting the
+                # restored state
+                logits0 = jnp.where(tin["restore_mask"][:, None],
+                                    prefix["logits"][tin["restore_row"]],
+                                    logits0)
+                core = model_zoo.snapshot_restore(cfg, core, prefix,
+                                                  tin["restore_row"],
+                                                  tin["restore_slot"])
+                # computing admissions persist their logits + recurrent
+                # state into their reserved rows (sentinel rows drop);
+                # computing slots are disjoint from restored slots, so the
+                # gather below is unaffected by the restore scatter above
+                prefix = dict(prefix, logits=prefix["logits"].at[
+                    tin["save_row"]].set(logits0, mode="drop"))
+                prefix = model_zoo.snapshot_save(cfg, core, prefix,
+                                                 tin["save_row"],
+                                                 tin["admit_slot"])
         return logits0, core, prefix
 
     def chunk_lane(params, theta, tin, core):
@@ -288,20 +319,23 @@ def _tier_tick_fn(cfg: ModelConfig, metric: str, use_kernel: bool,
 
     def tick(params, theta, tin, pool, draft=None):
         logits0, core, prefix = admit_and_prefix(params, tin, pool)
-        conf0 = conf_of(logits0, theta)
-        keys0 = sampler.request_keys(tin["admit_seed"], 0)
-        tok0 = sampler.sample(keys0, logits0, tin["admit_temp"])  # (A,)
-
-        # admitted slots decode their own first tokens in the same tick;
-        # padded admission rows carry an out-of-range slot -> dropped
-        last0 = tin["last_tok"].at[tin["admit_slot"]].set(tok0, mode="drop")
+        with lane("admit"):
+            conf0 = conf_of(logits0, theta)
+            keys0 = sampler.request_keys(tin["admit_seed"], 0)
+            tok0 = sampler.sample(keys0, logits0, tin["admit_temp"])  # (A,)
+            # admitted slots decode their own first tokens in the same
+            # tick; padded admission rows carry an out-of-range slot ->
+            # dropped
+            last0 = tin["last_tok"].at[tin["admit_slot"]].set(tok0,
+                                                              mode="drop")
         out = {"admit_tok": tok0, "admit_conf": conf0}
         if chunk:
-            tok_c, conf_c, core = chunk_lane(params, theta, tin, core)
-            n_slots = tin["last_tok"].shape[0]
-            fin_slot = jnp.where(tin["chunk_fin"], tin["chunk_slot"],
-                                 n_slots)
-            last0 = last0.at[fin_slot].set(tok_c, mode="drop")
+            with lane("chunk"):
+                tok_c, conf_c, core = chunk_lane(params, theta, tin, core)
+                n_slots = tin["last_tok"].shape[0]
+                fin_slot = jnp.where(tin["chunk_fin"], tin["chunk_slot"],
+                                     n_slots)
+                last0 = last0.at[fin_slot].set(tok_c, mode="drop")
             out["chunk_tok"] = tok_c
             out["chunk_conf"] = conf_c
         block = tin["block"]
@@ -350,8 +384,9 @@ def _tier_tick_fn(cfg: ModelConfig, metric: str, use_kernel: bool,
                         jnp.zeros((b,), jnp.int32),
                         jnp.zeros((b, k), bool), core)
 
-            (out_toks, out_confs, keep, accept, esc, n_emit, match,
-             core) = jax.lax.cond(tin["any_live"], verify, v_idle, core)
+            with lane("verify"):
+                (out_toks, out_confs, keep, accept, esc, n_emit, match,
+                 core) = jax.lax.cond(tin["any_live"], verify, v_idle, core)
             out.update({"toks": out_toks, "confs": out_confs, "keep": keep,
                         "accept": accept, "esc": esc, "n_emit": n_emit,
                         # verify-lane ground truth for the gate audit: raw
@@ -401,8 +436,9 @@ def _tier_tick_fn(cfg: ModelConfig, metric: str, use_kernel: bool,
             return (jnp.zeros((decode_block, b), jnp.int32),
                     jnp.zeros((decode_block, b), jnp.float32), staged, core)
 
-        toks, confs, staged, core = jax.lax.cond(tin["any_live"], decode,
-                                                 idle, core)
+        with lane("decode"):
+            toks, confs, staged, core = jax.lax.cond(tin["any_live"],
+                                                     decode, idle, core)
         out.update({"toks": toks, "confs": confs})       # toks (K, B)
         out_pool = {"core": core, "prefix": prefix} if sharing \
             else {"core": core}
@@ -749,16 +785,23 @@ class ContinuousScheduler:
     Enabled, the collector records
 
     * a span tree per request: ``queued → admitted → prefill_chunk[i] →
-      decode_block[j] → escalate_attempt[k] → l_verify → terminal`` with the
-      terminal ``status``, TTFT, TPOT, queue-wait ticks, and retry counts
+      decode_block[j] → l_queued ⊃ escalate_attempt[k] → l_verify →
+      terminal`` with the terminal ``status``, TTFT, TPOT, queue-wait
+      ticks, and retry counts
       (terminal hooks sit exactly where records reach their FINAL status:
       ``_finish_s`` un-escalated, ``_finish_l``, ``_finish_spec``,
       ``_degrade``, ``_drop_expired``, ``_reject``);
-    * per-tick wall-time buckets (``fault_tick`` — fault machinery + slot
+    * per-tick wall-time phases (``fault_tick`` — fault machinery + slot
       admission, ``build_operands``, ``dispatch``, ``host_fetch``,
-      ``postprocess``) plus pool / breaker / queue gauges sampled once per
-      tick from host state the scheduler already holds (``KVPool.gauges``,
-      breaker ``state_id``, queue depths) — no extra device traffic.
+      ``postprocess``, ``observe``), each also a ``sched.<phase>``
+      profiler annotation inside the tick's ``sched.tick`` step, plus pool /
+      breaker / queue gauges sampled once per tick from host state the
+      scheduler already holds (``KVPool.gauges``, breaker ``state_id``,
+      queue depths) — no extra device traffic.
+
+    The compiled tick's lanes run under ``<tier>/<lane>`` named scopes
+    (:data:`LANES`); :meth:`lane_of_ops` maps its instructions, and so the
+    profiler's device events, to them.
 
     ``serving/trace_export.py`` renders the collector as Perfetto-loadable
     Chrome ``trace_event`` JSON (slot tracks per tier, S→L flow arrows).
@@ -893,10 +936,10 @@ class ContinuousScheduler:
 
         s_role = "spec_s" if speculative else "plain"
         l_role = "spec_l" if speculative else "plain"
-        s_tick = _tier_tick_fn(s_tier.cfg, hi.metric, use_kernel,
+        s_tick = _tier_tick_fn("S", s_tier.cfg, hi.metric, use_kernel,
                                self.decode_block, self.srt.sharing,
                                chunk=self.chunk, role=s_role)
-        l_tick = _tier_tick_fn(l_tier.cfg, hi.metric, use_kernel,
+        l_tick = _tier_tick_fn("L", l_tier.cfg, hi.metric, use_kernel,
                                self.decode_block, self.lrt.sharing,
                                chunk=self.chunk, role=l_role)
 
@@ -910,10 +953,11 @@ class ContinuousScheduler:
                 # roll the S tier back to the accepted boundary: recurrent
                 # state via the draft's per-step snapshots; attention state
                 # is positional (the host rewinds pos)
-                sel = model_zoo.select_stage(s_cfg, s_ext["staged"],
-                                             l_out["keep"])
-                core = model_zoo.restore_stage(s_cfg, s_pool["core"], sel,
-                                               s_in["draft_live"])
+                with _lane("L", "verify"):
+                    sel = model_zoo.select_stage(s_cfg, s_ext["staged"],
+                                                 l_out["keep"])
+                    core = model_zoo.restore_stage(s_cfg, s_pool["core"],
+                                                   sel, s_in["draft_live"])
                 s_pool = dict(s_pool, core=core)
                 return {"s": s_out, "l": l_out}, s_pool, l_pool
         else:
@@ -944,6 +988,25 @@ class ContinuousScheduler:
         """Replica 0's runtime — THE runtime on the single-device path (the
         historical attribute; mesh-unaware callers and tests keep working)."""
         return self.srts[0]
+
+    def lane_of_ops(self) -> Dict[str, str]:
+        """Instruction name (without ``%``) -> lane (``"L/decode"``, ...) of
+        every instruction of the compiled tick whose ``op_name`` metadata
+        lies under a lane scope (:data:`LANES`).  The profiler's device
+        events carry the same instruction names, so this map attributes
+        device time to lanes; instructions XLA adds without metadata (its
+        buffer copies, say) belong to no lane."""
+        out: Dict[str, str] = {}
+        for line in self._exec.as_text().splitlines():
+            m = _OP_NAME.match(line)
+            if m is None:
+                continue
+            parts = m.group(2).split("/")
+            for tier, lane in zip(parts, parts[1:]):
+                if tier in TIERS and lane in LANES:
+                    out[m.group(1)] = f"{tier}/{lane}"
+                    break
+        return out
 
     def _build_mesh_exec(self, mesh, s_tick, l_tick, spec, s_in0, l_in0
                          ) -> None:
@@ -1008,12 +1071,13 @@ class ContinuousScheduler:
             # so XLA schedules the transfer alongside the S lanes — the
             # S->L hop never sits on the critical path.  The admit lane
             # reads the OTHER half: rows staged LAST tick.
-            wix = l_in["stage_wix"]
-            buf = jax.lax.dynamic_update_slice(
-                stage["buf"], l_in["stage_tokens"][None], (wix, 0, 0))
-            read = jax.lax.dynamic_slice(
-                buf, (1 - wix, 0, 0), (1,) + buf.shape[1:])[0]
-            l_in = dict(l_in, admit_tokens=read[l_in["stage_row"]])
+            with _lane("L", "admit"):
+                wix = l_in["stage_wix"]
+                buf = jax.lax.dynamic_update_slice(
+                    stage["buf"], l_in["stage_tokens"][None], (wix, 0, 0))
+                read = jax.lax.dynamic_slice(
+                    buf, (1 - wix, 0, 0), (1,) + buf.shape[1:])[0]
+                l_in = dict(l_in, admit_tokens=read[l_in["stage_row"]])
             s_out, s_pool = s_sharded(s_params, theta, s_in, s_pool)
             l_out, l_pool = l_tick(l_params, theta, l_in, l_pool)
             return ({"s": s_out, "l": l_out}, s_pool, l_pool, {"buf": buf})
@@ -1125,7 +1189,7 @@ class ContinuousScheduler:
         s_in = self.srt.tick_inputs(self._admit_s_max)
         l_in = self.lrt.tick_inputs(self._admit_s_max)
         if tel is not None:
-            tel.mark("build_operands")
+            tel.phase("dispatch")
         with warnings.catch_warnings():
             warnings.filterwarnings("ignore", message=".*[Dd]onat")
             out, s_pool, l_pool = \
@@ -1135,10 +1199,10 @@ class ContinuousScheduler:
         self.srt.store_pool(s_pool)
         self.lrt.store_pool(l_pool)
         if tel is not None:
-            tel.mark("dispatch")
+            tel.phase("host_fetch")
         host = engine_mod._host_fetch(out)   # the tick's single sync
         if tel is not None:
-            tel.mark("host_fetch")
+            tel.phase("postprocess")
         self.counters.ticks += 1
         return host
 
@@ -1181,7 +1245,7 @@ class ContinuousScheduler:
              "stage_wix": np.asarray(self._stage_wix, np.int32)},
             self._ns_rep)
         if tel is not None:
-            tel.mark("transfer_overlap")
+            tel.phase("build_operands")
         # raw numpy leaves stacked host-side, then ONE sharded transfer per
         # tree — per-leaf jnp.stack + reshard dominated the tick wall time
         s_raw = [rt.tick_inputs(self._admit_s_max, raw=True)
@@ -1197,7 +1261,7 @@ class ContinuousScheduler:
         l_in.update(stage_in)
         theta_j = jax.device_put(np.asarray(theta_j), self._ns_rep)
         if tel is not None:
-            tel.mark("build_operands")
+            tel.phase("dispatch")
         with warnings.catch_warnings():
             warnings.filterwarnings("ignore", message=".*[Dd]onat")
             out, s_pool, l_pool, stage = self._exec(
@@ -1208,10 +1272,10 @@ class ContinuousScheduler:
         self._stage = stage
         self._staged = self._staged_next   # write half becomes readable
         if tel is not None:
-            tel.mark("dispatch")
+            tel.phase("host_fetch")
         host = engine_mod._host_fetch(out)   # the tick's single sync
         if tel is not None:
-            tel.mark("host_fetch")
+            tel.phase("postprocess")
         self.counters.ticks += 1
         return host
 
@@ -1240,9 +1304,10 @@ class ContinuousScheduler:
     def _observe_tick(self, l_queue_len: int = 0) -> None:
         """End-of-tick observability fan-out: telemetry gauges (audit
         aggregates merged in — they become Chrome counter tracks), SLO
-        watchdog evaluation, flight-recorder snapshot + dump triggers.  All
-        host-side over state the tick already produced; with every collector
-        ``None`` this is one branch."""
+        watchdog evaluation, flight-recorder snapshot + dump triggers, then
+        the telemetry tick's end (all of this is its ``observe`` phase).
+        All host-side over state the tick already produced; with every
+        collector ``None`` this is one branch."""
         tel, aud, wd, fr = self.tel, self.aud, self.wd, self.fr
         if tel is None and wd is None and fr is None:
             return
@@ -1250,25 +1315,31 @@ class ContinuousScheduler:
         gauges = self._gauges(l_queue_len)
         if aud is not None:
             gauges.update(aud.gauge_values())
-        if tel is not None:
-            tel.end_tick(gauges)
         breaches = [] if wd is None else \
             wd.evaluate(tick, tel=tel, audit=aud, gauges=gauges)
         if tel is not None:
             for b in breaches:
                 tel.instant(f"slo_breach:{b['kind']}", **b)
-        if fr is None:
-            return
+        if fr is not None:
+            self._record_flight(tick, gauges, breaches)
+        if tel is not None:
+            tel.end_tick(gauges)
+
+    def _record_flight(self, tick: int, gauges: Dict[str, float],
+                       breaches: List[Dict[str, Any]]) -> None:
+        tel, fr = self.tel, self.fr
         # snapshot fields are deterministic functions of the request trace +
         # fault schedule; serve_time is wall clock and would break the
         # byte-identical dump guarantee
         counters = {k: v for k, v in self.stats.items() if k != "serve_time"}
         snap: Dict[str, Any] = {"tick": tick, "gauges": gauges,
                                 "counters": counters}
-        if fr.include_timings and tel is not None and tel.ticks:
+        if fr.include_timings and tel is not None \
+                and tel.current_tick is not None:
+            # the phases up to ``observe``, which this snapshot is part of
             snap["phase_seconds"] = {
                 p: round(t1 - t0, 9)
-                for p, t0, t1 in tel.ticks[-1].segments}
+                for p, t0, t1 in tel.current_tick.segments}
         fr.record(snap)
         for b in breaches:
             fr.trigger(f"slo_breach:{b['kind']}", tick, b)
@@ -1316,15 +1387,15 @@ class ContinuousScheduler:
 
         if self.speculative:
             while len(queue) or self.srt.busy:
-                if tel is not None:
-                    tel.begin_tick(self.counters.ticks)
+                if tel is not None:   # admission bookkeeping bucket
+                    tel.begin_tick(self.counters.ticks, "fault_tick")
                 self._try_admit_spec(queue, results)
                 if tel is not None:
-                    tel.mark("fault_tick")   # admission bookkeeping bucket
+                    tel.phase("build_operands")
                 host = self._dispatch(theta_j)
                 self._absorb_spec(host, results)
                 if tel is not None:
-                    tel.mark("postprocess")
+                    tel.phase("observe")
                 self._observe_tick()
             return results
 
@@ -1345,8 +1416,8 @@ class ContinuousScheduler:
         l_queue: deque = deque()
         while (len(queue) or l_queue or any(rt.busy for rt in self.srts)
                or self.lrt.busy or self._link.pending):
-            if tel is not None:
-                tel.begin_tick(self.counters.ticks)
+            if tel is not None:   # fault machinery + admission
+                tel.begin_tick(self.counters.ticks, "fault_tick")
             cur = self.counters.ticks - self._tick0
             state = self._breaker.state_at(cur)
             if state == CircuitBreaker.OPEN:
@@ -1383,10 +1454,13 @@ class ContinuousScheduler:
                             and self._probe is None:
                         self._probe = esc.rid
             if tel is not None:
-                tel.mark("fault_tick")   # fault machinery + admission
+                tel.phase("build_operands" if self._mesh is None
+                          else "transfer_overlap")
             s_busy = any(rt.busy for rt in self.srts)
             if not (len(queue) or l_queue or s_busy or self.lrt.busy
                     or self._link.pending):
+                if tel is not None:
+                    tel.drop_tick()    # nothing dispatched
                 break                  # everything left resolved host-side
             if (s_busy or self.lrt.busy
                     or any(rt.admitted for rt in self.srts)
@@ -1436,7 +1510,7 @@ class ContinuousScheduler:
                                         {"error": str(e)})
                     raise
             if tel is not None:
-                tel.mark("postprocess")
+                tel.phase("observe")
             self._observe_tick(len(l_queue))
 
         self.counters.esc_lost += self._link.lost
@@ -1875,6 +1949,7 @@ class ContinuousScheduler:
         self._esc_meta[rid] = esc
         self._link.send(esc, cur)
         if self.tel is not None:
+            self.tel.req_escalated(rid)
             self.tel.req_esc_send(rid, -1, esc.attempt)
 
     def _finish_l(self, rec: _Active, results: Dict) -> None:

@@ -27,7 +27,7 @@ two can never diverge (test-asserted).
 reads as the tree::
 
     queued -> admitted -> prefill_chunk[i] -> decode_block[j]
-           -> escalate_attempt[k] -> l_verify -> terminal
+           -> l_queued (escalate_attempt[k] ...) -> l_verify -> terminal
 
 with the terminal status (``ok`` / ``degraded_local`` / ``dropped`` /
 ``rejected``), TTFT, TPOT, queue-wait ticks, and retry counts attached.
@@ -42,24 +42,39 @@ Span kinds:
   block index, ``steps``);
 * ``escalate_attempt``— one span per S->L transport attempt, send to
   arrival/failure (args: ``k`` = attempt, ``outcome``);
+* ``l_queued``        — the wait for the L tier: from the escalation's
+  first send to the start of the tick that admits it to an L slot, across
+  every transport attempt (its ``escalate_attempt`` children), or to its
+  terminal if no L slot takes it (args: ``outcome``);
 * ``l_verify``        — L-tier residency for the escalation (admission to
   finish/abort); in speculative mode, one per escalated verify block;
 * ``terminal``        — zero-length marker carrying the final status.
 
 Span timestamps are ``time.monotonic()`` seconds (the clock the scheduler
-already uses for ``submit_time``/TTFT); device work inside a tick is
-attributed to the tick's wall bracket — the host cannot see finer without a
-second sync, which telemetry refuses to add by design.
+already uses for ``submit_time``/TTFT); a request's device work inside a
+tick is attributed to the tick's wall bracket.  Which lane of the compiled
+tick the device spent its time in is the profiler trace's to say: the
+tick's lanes run under named scopes, and
+``ContinuousScheduler.lane_of_ops`` maps the trace's device operations to
+them.
 
 **3. Tick-phase timing + gauges** (:class:`TickRecord`).  Each scheduler
-tick is decomposed host-side into wall-time buckets:
+tick is decomposed host-side into contiguous wall-time phases, so every
+host microsecond between ``begin_tick`` and ``end_tick`` belongs to one:
 
 * ``fault_tick``     — breaker/transport/drop bookkeeping + slot admission;
 * ``build_operands`` — numpy operand assembly (``tick_inputs``);
 * ``dispatch``       — executable call (submit; XLA may run async);
 * ``host_fetch``     — the tick's single device->host sync (device time
   surfaces here on async backends);
-* ``postprocess``    — token absorb, finish/escalation bookkeeping.
+* ``postprocess``    — token absorb, finish/escalation bookkeeping;
+* ``observe``        — gauges, SLO watchdog, flight recorder.
+
+While a collector is installed each tick is also a
+``jax.profiler.StepTraceAnnotation("sched.tick")`` and each phase a
+``jax.profiler.TraceAnnotation("sched.<phase>")`` of the same extent, so a
+profiler trace holds the host phases on its own clock beside the device's
+operations.  Outside a trace they record nothing.
 
 plus per-tick pool gauges sampled from host state the scheduler already
 holds: free pages, total refcounts, prefix-index size, COW copies, breaker
@@ -122,10 +137,10 @@ Exporters
   instant markers, audit aggregates as counter tracks), loadable in
   Perfetto.
 
-``benchmarks/bench_serving.py --trace-out`` wires it to traffic and reports
-the overhead (budget: <2% req/s when enabled, 0 when disabled — gated in CI
-by ``--telemetry-smoke``; the audit stream has the same budget, gated by
-``--audit-smoke``).
+``benchmarks/bench_serving.py --trace-out`` wires it to traffic; its
+``--telemetry-smoke`` and ``--audit-smoke`` CI gates compare CPU wall
+clock with the collector on and off, which says nothing of the cost on the
+chip (``PERF.md`` records that).
 """
 from __future__ import annotations
 
@@ -135,13 +150,15 @@ from collections.abc import MutableMapping
 from dataclasses import dataclass, field, fields
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax import profiler
+
 # Tick-phase wall-time buckets, in intra-tick order.  ``transfer_overlap``
 # only accumulates in mesh mode: it brackets the escalation staging-buffer
 # operand build + device copy dispatched at tick top, i.e. the S->L transfer
 # work that the sharded executable overlaps with the same tick's S-side
 # prefill/decode (bench_serving --mesh-smoke asserts it is nonzero).
 PHASES = ("fault_tick", "transfer_overlap", "build_operands", "dispatch",
-          "host_fetch", "postprocess")
+          "host_fetch", "postprocess", "observe")
 
 _now = time.monotonic
 
@@ -444,7 +461,10 @@ class Telemetry:
         # as Chrome ``i`` markers on the scheduler track by trace_export
         self.events: List[Tuple[float, str, Dict[str, Any]]] = []
         self._tick: Optional[TickRecord] = None
-        self._mark_t = 0.0
+        # the running phase: (name, start, its profiler annotation), and the
+        # tick's step annotation
+        self._phase: Optional[Tuple[str, float, Any]] = None
+        self._step: Any = None
         # (rid, kind) -> open span awaiting its close
         self._open: Dict[Tuple[int, str], Span] = {}
         # per-(rid, kind) occurrence counters for the [i]/[j]/[k] indices
@@ -452,29 +472,60 @@ class Telemetry:
 
     # -- tick lifecycle -----------------------------------------------------
 
-    def begin_tick(self, index: int) -> None:
+    def begin_tick(self, index: int, phase: str) -> None:
+        """Open tick ``index`` with its first phase (a tick left open by a
+        run that raised is dropped)."""
+        self.drop_tick()
+        self._step = profiler.StepTraceAnnotation("sched.tick",
+                                                  step_num=index)
+        self._step.__enter__()
         t = _now()
         self._tick = TickRecord(index=index, t0=t)
-        self._mark_t = t
+        self._open_phase(phase, t)
 
-    def mark(self, phase: str) -> None:
-        """Close the wall segment since the previous mark under ``phase``."""
+    def phase(self, name: str) -> None:
+        """End the running phase and start ``name``."""
         t = _now()
-        tick = self._tick
-        if tick is not None:
-            tick.segments.append((phase, self._mark_t, t))
-        self.phase_time[phase] = self.phase_time.get(phase, 0.0) \
-            + (t - self._mark_t)
-        self._mark_t = t
+        self._close_phase(t)
+        self._open_phase(name, t)
+
+    def _open_phase(self, name: str, t: float) -> None:
+        ann = profiler.TraceAnnotation(f"sched.{name}")
+        ann.__enter__()
+        self._phase = (name, t, ann)
+
+    def _close_phase(self, t: float) -> None:
+        name, t0, ann = self._phase
+        ann.__exit__(None, None, None)
+        self._phase = None
+        self._tick.segments.append((name, t0, t))
+        self.phase_time[name] = self.phase_time.get(name, 0.0) + (t - t0)
 
     def end_tick(self, gauges: Dict[str, float]) -> None:
         tick = self._tick
         if tick is None:
             return
         tick.t1 = _now()
+        self._close_phase(tick.t1)
+        self._step.__exit__(None, None, None)
+        self._step = None
         tick.gauges = gauges
         self.ticks.append(tick)
         self._tick = None
+
+    def drop_tick(self) -> None:
+        """Close the annotations of a tick that dispatched nothing, and keep
+        no record of it."""
+        if self._tick is None:
+            return
+        self._phase[2].__exit__(None, None, None)
+        self._step.__exit__(None, None, None)
+        self._tick, self._phase, self._step = None, None, None
+
+    @property
+    def current_tick(self) -> Optional[TickRecord]:
+        """The tick in flight, its finished phases in ``segments``."""
+        return self._tick
 
     def instant(self, name: str, **args: Any) -> None:
         """Record a scheduler-level instant event (e.g. an SLO watchdog
@@ -530,6 +581,10 @@ class Telemetry:
                      start: int = 0) -> None:
         tr = self._trace(rid, submit_t)
         t0, t1 = self.tick_bracket
+        if tier == "L":
+            sp = self.span_close(rid, "l_queued", outcome="admitted")
+            if sp is not None:
+                sp.t1 = t0            # the wait ends where the L tick begins
         # mesh mode names the S replicas "S0".."S{R-1}"; every S-side tier
         # label starts the queued span, the exact "L" label opens l_verify
         if tier.startswith("S") and not any(s.kind == "queued"
@@ -553,6 +608,10 @@ class Telemetry:
         self.span_point(rid, "decode_block", tier, slot,
                         j=self._next_idx(rid, f"{tier}:decode_block"),
                         steps=steps)
+
+    def req_escalated(self, rid: int) -> None:
+        """The S answer escalates: the wait for an L slot starts."""
+        self.span_open(rid, "l_queued", "L", -1)
 
     def req_esc_send(self, rid: int, slot: int, attempt: int) -> None:
         self.span_open(rid, "escalate_attempt", "S", slot, k=attempt)
@@ -582,6 +641,7 @@ class Telemetry:
         tr = self._trace(rid)
         t = _now()
         self.req_esc_end(rid, "gave_up")
+        self.span_close(rid, "l_queued", outcome=record.get("status", ""))
         self.req_l_release(rid, record.get("status", ""))
         tr.status = str(record.get("status", "ok"))
         tr.ttft = float(record.get("ttft", math.nan))
@@ -603,26 +663,6 @@ class Telemetry:
                              {"status": tr.status}))
 
     # -- exporters ----------------------------------------------------------
-
-    def request_records(self) -> List[Dict[str, Any]]:
-        """Structured per-request records (the span-tree face), alongside —
-        never replacing — the scheduler's result records."""
-        out = []
-        for rid in sorted(self.traces):
-            tr = self.traces[rid]
-            out.append({
-                "request_id": rid,
-                "status": tr.status,
-                "ttft": tr.ttft,
-                "tpot": tr.tpot,
-                "n_tokens": tr.n_tokens,
-                "queue_wait_ticks": tr.queue_wait_ticks,
-                "escalation_retries": tr.escalation_retries,
-                "spans": [{"kind": s.kind, "tier": s.tier, "slot": s.slot,
-                           "t0": s.t0, "t1": s.t1, **s.args}
-                          for s in tr.spans],
-            })
-        return out
 
     def histogram_summary(self) -> Dict[str, Dict[str, float]]:
         return {name: h.summary() for name, h in self.hists.items()}

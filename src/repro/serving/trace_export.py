@@ -11,8 +11,8 @@ Trace Event Format consumed by Perfetto (https://ui.perfetto.dev) and
 * **pid 1 — S tier / pid 2 — L tier**: one thread (tid = slot) per serving
   slot, carrying that slot's request spans (``admitted``,
   ``prefill_chunk[i]``, ``decode_block[j]``, ``l_verify``); queue-resident
-  spans (``queued``, ``escalate_attempt[k]``) live on a dedicated
-  ``queue``/``transport`` track;
+  spans (``queued``, ``escalate_attempt[k]``, and ``l_queued`` on the L
+  tier) live on dedicated ``queue``/``transport`` tracks;
 * escalations are drawn as **flow events** (``ph: "s"`` at the S-side
   ``escalate_attempt`` start, ``ph: "f"`` binding to the enclosing slice at
   the L-side ``l_verify`` start, ``id`` = request id) so Perfetto renders
@@ -86,6 +86,7 @@ def chrome_trace(tel) -> Dict[str, Any]:
     meta(2, None, "process_name", "L tier")
     meta(1, _QUEUE_TID, "thread_name", "admission queue")
     meta(1, _QUEUE_TID + 1, "thread_name", "escalation transport")
+    meta(2, _QUEUE_TID, "thread_name", "L admission queue")
     # mesh replicas beyond S0 get their own process lanes, named up front
     # from the tier labels actually present in the trace
     named_pids = {0, 1, 2}

@@ -1,6 +1,7 @@
 """Continuous-batching scheduler: drain-equivalence on mixed-length traffic,
 one compiled executable across buckets, the per-tick single-sync guarantee,
-and per-request temperature / max-new-tokens / EOS semantics."""
+per-request temperature / max-new-tokens / EOS semantics, and the lane
+scopes of the compiled tick."""
 import numpy as np
 import pytest
 
@@ -139,3 +140,41 @@ def test_stream_slot_count_smaller_than_traffic():
     assert all(rec["offloaded"] and rec["served_remote"]
                for rec in out.values())
     assert eng.stats["offloaded"] == 5
+
+
+@pytest.mark.parametrize("build, lanes", [
+    ({}, {"admit", "decode"}),
+    ({"prefix_sharing": True, "chunk_prefill": True},
+     {"cow", "admit", "prefix", "chunk", "decode"}),
+    ({"speculative": True}, {"admit", "decode"}),
+    ({"mesh": (1, 1)}, {"admit", "decode"}),
+], ids=["plain", "sharing_chunk", "speculative", "mesh_1x1"])
+def test_lane_of_ops_names_every_cond_and_while(build, lanes):
+    """Every ``conditional`` and ``while`` of the compiled tick lies in
+    exactly one lane scope, and each tier's lanes that this build traces
+    appear in the map (the speculative L tier verifies, it does not
+    decode)."""
+    import re
+
+    from repro.launch.mesh import make_serving_mesh
+    from repro.serving.scheduler import ContinuousScheduler
+
+    cfg = ARCHS["qwen2-1.5b"].reduced()
+    eng = build_engine(cfg, HIConfig(theta=0.6, capacity_factor=1.0),
+                       max_new_tokens=STEPS, cache_len=32)
+    kw = dict(build)
+    if "mesh" in kw:
+        kw["mesh"] = make_serving_mesh(*kw["mesh"])
+    sched = ContinuousScheduler(eng.s, eng.l, eng.hi, max_prompt_len=16,
+                                max_new_tokens=STEPS, num_slots=2, l_slots=2,
+                                page_size=8, decode_block=2, **kw)
+    text = sched._exec.as_text()
+    loops = re.findall(r"^\s*(?:ROOT )?%?(\S+) = \S+.*? (?:conditional|while)\(",
+                       text, re.M)
+    lane_of = sched.lane_of_ops()
+    assert loops and all(name in lane_of for name in loops), \
+        [n for n in loops if n not in lane_of]
+    want = {f"{t}/{lane}" for t in ("S", "L") for lane in lanes}
+    if build.get("speculative"):
+        want = (want - {"L/decode"}) | {"L/verify"}
+    assert set(lane_of.values()) == want

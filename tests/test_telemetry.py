@@ -12,7 +12,10 @@
 * engine and scheduler counter views can NEVER diverge (the engine reads
   the scheduler's typed counters live instead of copy-and-zeroing);
 * histogram bucketing/quantiles, the Prometheus snapshot, the StatsView
-  dict API, and the Chrome trace structure.
+  dict API, and the Chrome trace structure;
+* the tick phases tile each tick and appear in a ``jax.profiler`` trace as
+  ``sched.<phase>`` annotations inside ``sched.tick`` steps, and the
+  ``l_queued`` span times each escalation's wait for an L slot.
 """
 import json
 import math
@@ -79,10 +82,8 @@ def test_spans_for_exactly_the_terminating_requests():
             assert "escalate_attempt" in kinds and "l_verify" in kinds
         # every span closed: no NaN end times survive termination
         assert all(math.isfinite(s.t1) for s in tr.spans)
-    # structured records mirror the traces
-    recs = {r["request_id"]: r for r in tel.request_records()}
-    assert set(recs) == set(res)
-    assert all(recs[r]["status"] == res[r]["status"] for r in res)
+    # the traces mirror the result records
+    assert all(tel.traces[r].status == res[r]["status"] for r in res)
 
 
 def test_enabled_output_token_identical_to_disabled():
@@ -277,3 +278,72 @@ def test_chrome_trace_structure(tmp_path):
     assert any(e["ph"] == "C" for e in ev)
     # timestamps are relative: nothing starts before 0
     assert min(e["ts"] for e in ev if "ts" in e) >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# the profiler trace's host phases, and the wait for the L tier
+# ---------------------------------------------------------------------------
+
+def test_profiler_trace_holds_each_phase_and_tick(tmp_path):
+    """Under ``jax.profiler`` every recorded tick segment is one
+    ``sched.<phase>`` host event (``observe`` among them) and every tick one
+    ``sched.tick`` step; the segments tile each tick."""
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _eng()
+    tel = Telemetry()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.serve_stream(_requests(dropper=False), telemetry=tel, **KW)
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    names = [ev.name for p in pd.planes if p.name.startswith("/host:")
+             for line in p.lines for ev in line.events
+             if ev.name.startswith("sched.")]
+    segs = [name for tk in tel.ticks for name, _, _ in tk.segments]
+    assert names.count("sched.tick") == len(tel.ticks) > 0
+    assert sorted(n for n in names if n != "sched.tick") == \
+        sorted(f"sched.{p}" for p in segs)
+    assert "observe" in segs
+    for tk in tel.ticks:
+        assert tk.segments[0][1] == tk.t0 and tk.segments[-1][2] == tk.t1
+        assert [s[0] for s in tk.segments][-2:] == ["postprocess", "observe"]
+        assert all(a[2] == b[1] for a, b in zip(tk.segments, tk.segments[1:]))
+
+
+def test_l_queued_spans_the_wait_for_an_l_slot():
+    """Every request that crossed to the L tier has one ``l_queued`` span,
+    from its escalation to the start of its L ``admitted`` span, over its
+    transport attempts; requests answered locally have none."""
+    eng = _eng()
+    sched = ContinuousScheduler(
+        eng.s, eng.l, eng.hi, max_prompt_len=16, max_new_tokens=STEPS,
+        num_slots=3, l_slots=2, page_size=8)
+
+    def serve(theta, tel=None):
+        queue = AdmissionQueue(buckets=(8, 16))
+        for r in _requests(dropper=False):
+            queue.submit(r)
+        sched.set_telemetry(tel)
+        return sched.run(queue, theta=theta)
+
+    # theta at the median S confidence: half the requests escalate
+    theta = float(np.median([r["confidence"] for r in serve(-1.0).values()]))
+    tel = Telemetry()
+    res = serve(theta, tel)
+    remote = {r for r, rec in res.items() if rec["served_remote"]}
+    assert remote and remote != set(res)
+    for rid, rec in res.items():
+        spans = tel.traces[rid].spans
+        waits = [s for s in spans if s.kind == "l_queued"]
+        if rid not in remote:
+            assert not waits and not rec["offloaded"]
+            continue
+        (wait,) = waits
+        l_admit = next(s for s in spans
+                       if s.kind == "admitted" and s.tier == "L")
+        assert wait.t1 == l_admit.t0 and wait.args["outcome"] == "admitted"
+        first = next(s for s in spans if s.kind == "escalate_attempt")
+        assert wait.t0 <= first.t0 < wait.t1
