@@ -329,47 +329,28 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
             lp, cfg, L.rmsnorm(lp["ln"], x, cfg.norm_eps), st, cw)
         return x + y, (st, cw)
 
-    quant = "ks" in cache
-
     def outer(carry, xs):
-        x = carry
-        if quant:
-            gp, st_g, cw_g, pk, pv, sk, sv = xs
-        else:
-            gp, st_g, cw_g, pk, pv = xs
+        x, kv = carry
+        gp, st_g, cw_g, gi = xs
         x, (st_g, cw_g) = lax.scan(inner, x, (gp, st_g, cw_g))
-        if quant:
-            a, pk, pv, sk, sv = L.attention_decode_paged(
-                sp["attn"], L.rmsnorm(sp["ln1"], x, cfg.norm_eps), pk, pv,
-                block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                use_kernel=use_kernel, write_block=write_block,
-                scale_k=sk, scale_v=sv)
-        else:
-            a, pk, pv = L.attention_decode_paged(
-                sp["attn"], L.rmsnorm(sp["ln1"], x, cfg.norm_eps), pk, pv,
-                block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                use_kernel=use_kernel, write_block=write_block)
+        a, kv = L.attention_decode_paged(
+            sp["attn"], L.rmsnorm(sp["ln1"], x, cfg.norm_eps), kv, gi,
+            block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
+            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+            use_kernel=use_kernel, write_block=write_block)
         x = x + a
         x = x + L.swiglu(sp["mlp"], L.rmsnorm(sp["ln2"], x, cfg.norm_eps))
-        return x, ((st_g, cw_g, pk, pv, sk, sv) if quant
-                   else (st_g, cw_g, pk, pv))
+        return (x, kv), (st_g, cw_g)
 
-    if quant:
-        h, (ns, ncw, nk, nv, nsk, nsv) = lax.scan(
-            outer, h, (params["layers"], cache["state"], cache["conv"],
-                       cache["kp"], cache["vp"], cache["ks"], cache["vs"]))
-    else:
-        h, (ns, ncw, nk, nv) = lax.scan(
-            outer, h, (params["layers"], cache["state"], cache["conv"],
-                       cache["kp"], cache["vp"]))
+    # the shared block's stacked KV pools ride in the carry (updated in
+    # place); the per-slot recurrent state is sliced in and stacked out
+    (h, kv), (ns, ncw) = lax.scan(
+        outer, (h, L.paged_kv(cache)),
+        (params["layers"], cache["state"], cache["conv"],
+         jnp.arange(cache["kp"].shape[0])))
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
-    new_cache = {"state": ns, "conv": ncw, "kp": nk, "vp": nv}
-    if quant:
-        new_cache["ks"], new_cache["vs"] = nsk, nsv
-    return logits, new_cache
+    return logits, {"state": ns, "conv": ncw, **kv}
 
 
 def forward_chunk_paged(params: Params, cfg: ModelConfig,

@@ -299,28 +299,38 @@ def attention_decode(params: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
     return out, cache_k, cache_v
 
 
-def attention_decode_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
-                           pool_v: jnp.ndarray, block: jnp.ndarray,
+def paged_kv(cache: Params) -> Params:
+    """The stacked KV pools of a paged cache: ``kp``/``vp`` (L, P, page, K,
+    Dh), plus the int8 pools' ``ks``/``vs`` (L, P, K) fp32 scales."""
+    return {k: cache[k] for k in ("kp", "vp", "ks", "vs") if k in cache}
+
+
+def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
+                           layer: jnp.ndarray, block: jnp.ndarray,
                            pos: jnp.ndarray, *, num_heads: int, num_kv: int,
                            head_dim: int, rope_theta: float,
                            window: Optional[jnp.ndarray] = None,
                            use_kernel: bool = False,
-                           write_block: Optional[jnp.ndarray] = None,
-                           scale_k: Optional[jnp.ndarray] = None,
-                           scale_v: Optional[jnp.ndarray] = None):
-    """One-token decode against a PAGED KV pool (one layer's slice of it).
+                           write_block: Optional[jnp.ndarray] = None
+                           ) -> Tuple[jnp.ndarray, Params]:
+    """One-token decode of layer ``layer`` against a tier's STACKED paged KV
+    pools, updated in place.
 
-    x: (B, 1, D); pool_k/v: (P, page, K, Dh) — ONE physical allocation shared
-    by every slot; block: (B, n_pages) int32 block table mapping each slot's
-    logical pages to physical ones (0 = the null/trash page); pos: (B,) int32
-    per-slot positions (continuous batching: slots decode at DIFFERENT
-    positions, unlike the contiguous cache's single scalar).
+    x: (B, 1, D); kv: :func:`paged_kv` of the cache — ``kp``/``vp`` (L, P,
+    page, K, Dh), every layer's pages in ONE physical allocation shared by
+    every slot; layer: int32 scalar index into their leading axis; block:
+    (B, n_pages) int32 block table mapping each slot's logical pages to
+    physical ones (0 = the null/trash page); pos: (B,) int32 per-slot
+    positions (continuous batching: slots decode at DIFFERENT positions,
+    unlike the contiguous cache's single scalar).
 
-    The new token's K/V is scattered into page ``block[b, pos_b // page]`` at
-    offset ``pos_b % page``; reads gather every slot's pages back through the
-    table (or stream them inside the Pallas kernel when ``use_kernel``).
-    Masking is positional (``kpos <= pos_b``) so stale page contents are never
-    observable.
+    The new token's K/V is scattered into ``[layer, block[b, pos_b // page],
+    pos_b % page]``; reads gather every slot's pages back through the table
+    straight from the stacked pools (or stream the layer's pages inside the
+    Pallas kernel when ``use_kernel``).  Masking is positional (``kpos <=
+    pos_b``) so stale page contents are never observable.  Callers carry
+    ``kv`` through their layer scan: with no layer sliced out and none
+    stacked back, XLA updates the donated pools in place.
 
     ``write_block`` (defaults to ``block``): the table used for the APPEND
     only.  With prefix sharing, pages aliased by several slots are read-only
@@ -329,19 +339,19 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
     violated exclusivity invariant drops the write instead of corrupting a
     co-resident request's cache.
 
-    ``scale_k/v`` (P, K) fp32 mark the pools int8-quantized (per-page-
-    per-head symmetric scales): the append quantizes through a monotone
-    running-max page scale (see :func:`quant_append_page`) and the gather
-    dequantizes — fused into the Pallas kernel under ``use_kernel``.
+    ``ks``/``vs`` in ``kv`` mark the pools int8-quantized (per-page-per-head
+    symmetric scales): the append quantizes through a monotone running-max
+    page scale (see :func:`quant_append_page`) and the gather dequantizes —
+    fused into the Pallas kernel under ``use_kernel``.
 
-    Returns (out (B,1,D), pool_k, pool_v) — plus (scale_k, scale_v) when
-    quantized.
+    Returns (out (B,1,D), kv).
     """
     b = x.shape[0]
-    page = pool_k.shape[1]
+    pool_k, pool_v = kv["kp"], kv["vp"]
+    scale_k, scale_v = kv.get("ks"), kv.get("vs")
+    page = pool_k.shape[2]
     n_pages = block.shape[1]
     s_tot = n_pages * page
-    quant = scale_k is not None
     q, k, v = _qkv(params, x, num_heads, num_kv, head_dim)
     if rope_theta > 0:
         pq = pos[:, None]                    # (B, 1) absolute positions
@@ -353,30 +363,37 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
     off = pos % page
     # duplicate (page 0) targets from idle slots race benignly: the null page
     # is never covered by any slot's positional mask
-    if quant:
-        pool_k, scale_k = quant_append_page(pool_k, scale_k, pg, off, k[:, 0])
-        pool_v, scale_v = quant_append_page(pool_v, scale_v, pg, off, v[:, 0])
+    if scale_k is not None:
+        pool_k, scale_k = quant_append_page(pool_k, scale_k, pg, off, k[:, 0],
+                                            layer=layer)
+        pool_v, scale_v = quant_append_page(pool_v, scale_v, pg, off, v[:, 0],
+                                            layer=layer)
+        kv = {"kp": pool_k, "vp": pool_v, "ks": scale_k, "vs": scale_v}
     else:
-        pool_k = pool_k.at[pg, off].set(k[:, 0].astype(pool_k.dtype),
-                                        mode="drop")
-        pool_v = pool_v.at[pg, off].set(v[:, 0].astype(pool_v.dtype),
-                                        mode="drop")
+        pool_k = pool_k.at[layer, pg, off].set(k[:, 0].astype(pool_k.dtype),
+                                               mode="drop")
+        pool_v = pool_v.at[layer, pg, off].set(v[:, 0].astype(pool_v.dtype),
+                                               mode="drop")
+        kv = {"kp": pool_k, "vp": pool_v}
     kpos = jnp.arange(s_tot)[None, :]        # logical key positions per slot
     valid = kpos <= pos[:, None]
     if window is not None:
         valid = valid & (pos[:, None] - kpos < window)
     if use_kernel:
+        # the kernel streams one layer's pool: pass it that layer's slice
         from repro.kernels import ops as kops
-        out = kops.decode_attention_paged(q, pool_k, pool_v, block, valid,
-                                          scale_k, scale_v)
+        sk, sv = (None, None) if scale_k is None else \
+            (scale_k[layer], scale_v[layer])
+        out = kops.decode_attention_paged(q, pool_k[layer], pool_v[layer],
+                                          block, valid, sk, sv)
     else:
-        kk = dequant_gather(pool_k, scale_k, block, num_kv, head_dim)
-        vv = dequant_gather(pool_v, scale_v, block, num_kv, head_dim)
+        kk = dequant_gather(pool_k, scale_k, block, num_kv, head_dim,
+                            layer=layer)
+        vv = dequant_gather(pool_v, scale_v, block, num_kv, head_dim,
+                            layer=layer)
         out = _sdpa(q, kk, vv, valid[:, None, :])
     out = out.reshape(b, 1, num_heads * head_dim) @ params["wo"]
-    if quant:
-        return out, pool_k, pool_v, scale_k, scale_v
-    return out, pool_k, pool_v
+    return out, kv
 
 
 def attention_chunk_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
@@ -479,47 +496,55 @@ def _safe_scale(s: jnp.ndarray) -> jnp.ndarray:
 
 
 def quant_append_page(pool: jnp.ndarray, scale: jnp.ndarray, pg: jnp.ndarray,
-                      off: jnp.ndarray, val: jnp.ndarray
+                      off: jnp.ndarray, val: jnp.ndarray,
+                      layer: Optional[jnp.ndarray] = None
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Append token K/V into an int8 pool at (pg, off) under a monotone
     per-page-per-head running-max scale.
 
-    pool: (P, page, K, Dh) int8; scale: (P, K) fp32; pg/off: (...,) int32
-    (token -> physical page / in-page offset); val: (..., K, Dh).  Pages
-    whose scale grows are requantized to the new grid (duplicate pg entries
-    write identical bytes — ratio and gathered content agree — so chunked
-    appends race benignly, same as the null-page discipline).
+    pool: (P, page, K, Dh) int8; scale: (P, K) fp32 — or, with ``layer``, a
+    stacked (L, P, page, K, Dh) pool and (L, P, K) scales updated at that
+    layer in place; pg/off: (...,) int32 (token -> physical page / in-page
+    offset); val: (..., K, Dh).  Pages whose scale grows are requantized to
+    the new grid (duplicate pg entries write identical bytes — ratio and
+    gathered content agree — so chunked appends race benignly, same as the
+    null-page discipline).
     """
+    lead = () if layer is None else (layer,)
     v32 = val.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(v32), axis=-1)                   # (..., K)
     # offset 0 == first write into a freshly allocated page: reset the
     # recycled page's stale scale so it cannot poison this page's precision
     # (off != 0 tokens harmlessly re-zero the never-dequant-read null page)
-    scale = scale.at[jnp.where(off == 0, pg, 0)].set(0.0, mode="drop")
-    old = scale[pg]                                           # (..., K)
-    scale = scale.at[pg].max(absmax / _QMAX, mode="drop")
-    new = scale[pg]
+    scale = scale.at[lead + (jnp.where(off == 0, pg, 0),)].set(0.0,
+                                                               mode="drop")
+    old = scale[lead + (pg,)]                                 # (..., K)
+    scale = scale.at[lead + (pg,)].max(absmax / _QMAX, mode="drop")
+    new = scale[lead + (pg,)]
     ratio = jnp.clip(_safe_scale(old) / _safe_scale(new), 0.0, 1.0)
-    repack = jnp.round(pool[pg].astype(jnp.float32)
+    repack = jnp.round(pool[lead + (pg,)].astype(jnp.float32)
                        * ratio[..., None, :, None])
-    pool = pool.at[pg].set(repack.astype(pool.dtype), mode="drop")
+    pool = pool.at[lead + (pg,)].set(repack.astype(pool.dtype), mode="drop")
     q = jnp.clip(jnp.round(v32 / _safe_scale(new)[..., None]), -_QMAX, _QMAX)
-    pool = pool.at[pg, off].set(q.astype(pool.dtype), mode="drop")
+    pool = pool.at[lead + (pg, off)].set(q.astype(pool.dtype), mode="drop")
     return pool, scale
 
 
 def dequant_gather(pool: jnp.ndarray, scale: Optional[jnp.ndarray],
-                   block: jnp.ndarray, num_kv: int, head_dim: int
-                   ) -> jnp.ndarray:
+                   block: jnp.ndarray, num_kv: int, head_dim: int,
+                   layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Gather a batch's pages as (B, n_pages * page, K, Dh), dequantizing
     through the per-page scales when given (None = full-precision pool:
-    bitwise the plain gather)."""
+    bitwise the plain gather).  With ``layer`` the pool (and scales) are
+    stacked over layers and the pages come from that layer in one gather,
+    with no layer slice in between."""
     b, npg = block.shape
-    gathered = pool[block]                       # (B, npg, page, K, Dh)
+    lead = () if layer is None else (layer,)
+    gathered = pool[lead + (block,)]             # (B, npg, page, K, Dh)
     if scale is not None:
         gathered = gathered.astype(jnp.float32) \
-            * scale[block][:, :, None, :, None]
-    return gathered.reshape(b, npg * pool.shape[1], num_kv, head_dim)
+            * scale[lead + (block,)][:, :, None, :, None]
+    return gathered.reshape(b, npg * pool.shape[-3], num_kv, head_dim)
 
 
 def quant_dequant_pages(kv: jnp.ndarray, page: int) -> jnp.ndarray:

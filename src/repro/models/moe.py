@@ -470,25 +470,15 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
     page = cache["kp"].shape[2]
     s_tot = block.shape[1] * page
     win = jnp.asarray(s_tot, jnp.int32)
-    quant = "ks" in cache
 
     def body(carry, xs):
-        x = carry
-        if quant:
-            lp, pk, pv, sk, sv = xs
-            a, pk, pv, sk, sv = L.attention_decode_paged(
-                lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), pk, pv,
-                block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                window=win, use_kernel=use_kernel, write_block=write_block,
-                scale_k=sk, scale_v=sv)
-        else:
-            lp, pk, pv = xs
-            a, pk, pv = L.attention_decode_paged(
-                lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), pk, pv,
-                block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                window=win, use_kernel=use_kernel, write_block=write_block)
+        x, kv = carry
+        lp, li = xs
+        a, kv = L.attention_decode_paged(
+            lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), kv, li,
+            block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
+            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+            window=win, use_kernel=use_kernel, write_block=write_block)
         x = x + a
         xn = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
         y, _ = moe_ffn_auto(lp, cfg, xn, capacity_factor)
@@ -496,20 +486,14 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
             y = y + L.swiglu(lp["shared"], xn)
         if "dense" in lp:
             y = y + L.swiglu(lp["dense"], xn)
-        return x + y, ((pk, pv, sk, sv) if quant else (pk, pv))
+        return (x + y, kv), None
 
-    if quant:
-        h, (nk, nv, nsk, nsv) = lax.scan(
-            body, h, (params["layers"], cache["kp"], cache["vp"],
-                      cache["ks"], cache["vs"]))
-    else:
-        h, (nk, nv) = lax.scan(body, h, (params["layers"], cache["kp"],
-                                         cache["vp"]))
+    (h, kv), _ = lax.scan(body, (h, L.paged_kv(cache)),
+                          (params["layers"],
+                           jnp.arange(cache["kp"].shape[0])))
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
-    if quant:
-        return logits, {"kp": nk, "vp": nv, "ks": nsk, "vs": nsv}
-    return logits, {"kp": nk, "vp": nv}
+    return logits, {**cache, **kv}
 
 
 def forward_chunk_paged(params: Params, cfg: ModelConfig,

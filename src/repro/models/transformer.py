@@ -448,49 +448,32 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
 
     token: (B, 1); pos: (B,) int32; block: (B, n_pages) int32; write_block:
     the append-side table with shared (read-only) pages masked to the null
-    page — see ``layers.attention_decode_paged``.
+    page — see ``layers.attention_decode_paged``.  The stacked pools ride in
+    the layer scan's carry, so each layer updates them in place.
     Returns (logits (B, V) fp32, cache)."""
     h = params["embed"][token]
     page = cache["kp"].shape[2]
     s_tot = block.shape[1] * page
     windows = layer_windows(cfg, s_tot)
-    quant = "ks" in cache
 
     def body(carry, xs):
-        x = carry
-        if quant:
-            lp, pk, pv, sk, sv, win = xs
-            a, pk, pv, sk, sv = L.attention_decode_paged(
-                lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), pk, pv,
-                block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                window=win, use_kernel=use_kernel, write_block=write_block,
-                scale_k=sk, scale_v=sv)
-            x = x + a
-            m = L.swiglu(lp["mlp"], L.rmsnorm(lp["ln2"], x, cfg.norm_eps))
-            return x + m, (pk, pv, sk, sv)
-        lp, pk, pv, win = xs
-        a, pk, pv = L.attention_decode_paged(
-            lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), pk, pv,
+        x, kv = carry
+        lp, win, li = xs
+        a, kv = L.attention_decode_paged(
+            lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), kv, li,
             block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
             window=win, use_kernel=use_kernel, write_block=write_block)
         x = x + a
         m = L.swiglu(lp["mlp"], L.rmsnorm(lp["ln2"], x, cfg.norm_eps))
-        return x + m, (pk, pv)
+        return (x + m, kv), None
 
-    if quant:
-        h, (nk, nv, nsk, nsv) = lax.scan(
-            body, h, (params["layers"], cache["kp"], cache["vp"],
-                      cache["ks"], cache["vs"], windows))
-    else:
-        h, (nk, nv) = lax.scan(body, h, (params["layers"], cache["kp"],
-                                         cache["vp"], windows))
+    (h, kv), _ = lax.scan(body, (h, L.paged_kv(cache)),
+                          (params["layers"], windows,
+                           jnp.arange(cache["kp"].shape[0])))
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
-    if quant:
-        return logits, {"kp": nk, "vp": nv, "ks": nsk, "vs": nsv}
-    return logits, {"kp": nk, "vp": nv}
+    return logits, {**cache, **kv}
 
 
 def forward_chunk_paged(params: Params, cfg: ModelConfig,
