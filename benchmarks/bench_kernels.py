@@ -43,8 +43,8 @@ def run() -> None:
     x = jnp.asarray(rng.normal(size=(b, l, hh, p)), jnp.float32)
     dt = jnp.asarray(rng.random((b, l, hh)), jnp.float32) * 0.5
     A = -jnp.asarray(rng.random(hh), jnp.float32) - 0.2
-    B = jnp.asarray(rng.normal(size=(b, l, n)), jnp.float32)
-    C = jnp.asarray(rng.normal(size=(b, l, n)), jnp.float32)
+    B = jnp.asarray(rng.normal(size=(b, l, 1, n)), jnp.float32)
+    C = jnp.asarray(rng.normal(size=(b, l, 1, n)), jnp.float32)
     us_k = time_us(lambda: ops.ssd(x, dt, A, B, C, chunk=128))
     ref_jit = jax.jit(lambda *a: ref.ssd_ref(*a, chunk=128))
     us_r = time_us(lambda: ref_jit(x, dt, A, B, C))

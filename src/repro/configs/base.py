@@ -53,9 +53,12 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_chunk: int = 128
     ssm_conv_width: int = 4
-    ssm_ngroups: int = 1
+    ssm_ngroups: int = 1               # B/C groups; head j reads group j // (H/G)
+    ssm_dt_min: float = 0.0            # floor on softplus(dt) (zamba2: time_step_min)
     # -- hybrid (zamba2) ------------------------------------------------------
-    shared_attn_every: int = 0         # insert the shared attn block every k layers
+    hybrid_layer_ids: tuple = ()       # layers that first apply a shared block
+    num_mem_blocks: int = 0            # shared blocks, taken in turn by the ids
+    adapter_rank: int = 0              # per-application LoRA on the MLP's gate_up
     # -- encoder-decoder (whisper) --------------------------------------------
     encoder_layers: int = 0
     num_audio_frames: int = 1500       # stubbed conv/mel frontend output length
@@ -125,7 +128,9 @@ class ModelConfig:
             changes.update(ssm_state=min(self.ssm_state, 16), ssm_head_dim=16,
                            ssm_chunk=32)
         if self.family == HYBRID:
-            changes.update(shared_attn_every=2)
+            changes.update(head_dim=2 * d_model // nh,
+                           adapter_rank=min(self.adapter_rank, 8),
+                           hybrid_layer_ids=_scale_ids(self, num_layers))
         if self.family == ENCDEC:
             changes.update(encoder_layers=2, num_audio_frames=16)
         if self.family == VLM:
@@ -153,9 +158,22 @@ class ModelConfig:
         if self.num_experts:
             changes.update(num_experts=max(4, self.num_experts // scale),
                            d_ff_expert=max(64, self.d_ff_expert // scale))
+        if self.family == HYBRID:
+            # head_dim and the groups stay; the ids keep their positions
+            # in the shallower stack, so each shared block is still applied
+            changes.update(hybrid_layer_ids=_scale_ids(
+                self, changes["num_layers"]))
         if self.family == ENCDEC:
             changes.update(encoder_layers=max(2, self.encoder_layers // scale))
         return dataclasses.replace(self, **changes)
+
+
+def _scale_ids(cfg: ModelConfig, num_layers: int) -> tuple:
+    """The hybrid layer ids at the same relative depths of a stack of
+    ``num_layers`` (distinct, so no application is merged away unless two
+    land on one layer)."""
+    return tuple(sorted({i * num_layers // cfg.num_layers
+                         for i in cfg.hybrid_layer_ids}))
 
 
 @dataclass(frozen=True)

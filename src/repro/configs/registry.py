@@ -10,6 +10,7 @@ from repro.configs import (
     whisper_large_v3,
     granite_3_2b,
     zamba2_2p7b,
+    zamba2_7b,
     gemma3_1b,
     llava_next_34b,
     arctic_480b,
@@ -25,6 +26,7 @@ ARCHS: Dict[str, ModelConfig] = {
         whisper_large_v3.CONFIG,
         granite_3_2b.CONFIG,
         zamba2_2p7b.CONFIG,
+        zamba2_7b.CONFIG,
         gemma3_1b.CONFIG,
         llava_next_34b.CONFIG,
         arctic_480b.CONFIG,

@@ -12,7 +12,7 @@ K/V index_maps dereference it: grid step (b, j) DMAs physical page
 ``block[b, j]`` as a whole ``(1, page, K, D)`` tile — every KV head of the
 page in one copy — and the body loops over the K heads.  Whole trailing
 dims keep every block shape legal for the TPU's (8, 128) tiling at any
-head count and head_dim (qwen2's K=2/D=128, zamba2's K=32/D=80).  Per step
+head count and head_dim (qwen2's K=2/D=128, zamba2-7b's K=32/D=224).  Per step
 the kernel holds:
 
   q     (1, K, C*G, D)      the slot's query rows, head-major   [VMEM]

@@ -8,7 +8,7 @@ interpreter validates the kernel bodies (tests, small smoke runs).  Any
 other backend raises — a kernel is never interpreted on an accelerator, and
 never quietly replaced by the jnp path (``use_kernel=False`` selects that).
 ``tests/test_tpu_compile.py`` compiles every kernel of the serving tick for
-a v5e at qwen2-1.5b / zamba2-2.7b widths.
+a v5e at qwen2-1.5b / zamba2-7b widths.
 """
 from __future__ import annotations
 
@@ -116,25 +116,27 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
         C: jnp.ndarray, chunk: int = 128) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Chunked SSD: Pallas intra-chunk kernel + jnp inter-chunk recurrence.
 
-    x: (b, l, h, p); dt: (b, l, h); A: (h,); B/C: (b, l, n).
+    x: (b, l, h, p); dt: (b, l, h); A: (h,); B/C: (b, l, G, n), head j
+    reading group j // (h / G).
     Returns (y (b,l,h,p), final_state (b,h,p,n))."""
     b, l, h, p = x.shape
-    n = B.shape[-1]
+    ng, n = B.shape[2:]
     chunk = min(chunk, l)
     orig_l = l
     if l % chunk:
         pad = chunk - l % chunk
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0), (0, 0)))
         l += pad
     nc = l // chunk
 
     xc = x.reshape(b, nc, chunk, h, p)
     dtc = dt.reshape(b, nc, chunk, h)
-    Bc = B.reshape(b, nc, chunk, n)
-    Cc = C.reshape(b, nc, chunk, n)
+    # groups ahead of the chunk's rows: the kernel reads one (Q, N) tile each
+    Bc = B.reshape(b, nc, chunk, ng, n).transpose(0, 1, 3, 2, 4)
+    Cc = C.reshape(b, nc, chunk, ng, n).transpose(0, 1, 3, 2, 4)
 
     y_diag, S, g, eacs = _ssd.ssd_chunk_pallas(xc, dtc, A, Bc, Cc,
                                                interpret=interpret())
@@ -149,6 +151,7 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
         step, h0, (g.transpose(1, 0, 2), S.transpose(1, 0, 2, 3, 4)))
     h_prevs = h_prevs.transpose(1, 0, 2, 3, 4)          # (b, nc, h, p, n)
 
-    y_off = jnp.einsum("bcih,bcin,bchpn->bcihp", eacs, Cc, h_prevs)
+    y_off = jnp.einsum("bcih,bchin,bchpn->bcihp", eacs,
+                       jnp.repeat(Cc, h // ng, axis=2), h_prevs)
     y = (y_diag + y_off).reshape(b, l, h, p)[:, :orig_l]
     return y.astype(x.dtype), hT

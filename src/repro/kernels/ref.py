@@ -47,9 +47,9 @@ def ssd_naive_ref(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         y, hnew = mamba2.ssd_recurrent_step(hprev, x_t, dt_t, A, B_t, C_t)
         return hnew, y
 
-    h0 = jnp.zeros((b, h, p, n), jnp.float32)
+    h0 = jnp.zeros((b, p, n, h), jnp.float32)     # the decode cache's layout
     _, ys = jax.lax.scan(step, h0, (x.transpose(1, 0, 2, 3),
                                     dt.transpose(1, 0, 2),
-                                    B.transpose(1, 0, 2),
-                                    C.transpose(1, 0, 2)))
+                                    B.swapaxes(0, 1),
+                                    C.swapaxes(0, 1)))
     return ys.transpose(1, 0, 2, 3)

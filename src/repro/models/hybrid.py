@@ -1,14 +1,32 @@
-"""Zamba2-style hybrid: Mamba-2 backbone + a SHARED attention block applied
-every ``shared_attn_every`` layers [arXiv:2411.15242].
+"""Zamba2 hybrid: a Mamba-2 backbone whose layers in ``hybrid_layer_ids``
+first apply one of ``num_mem_blocks`` shared transformer blocks, taken in
+turn [arXiv:2411.15242; transformers' ``modeling_zamba2``].
 
-Structured as ``G = num_layers // shared_attn_every`` groups; each group is an
-inner scan over its mamba layers followed by the shared attention block (one
-set of weights, applied G times — Zamba2's parameter-sharing trick).  The
-outer scan carries the per-group KV-cache slots for decode.
+Hybrid layer i, application j (the j-th id), h its input, e the token
+embedding:
+
+    t = RMSNorm_2d(concat(h, e))
+    a = Attn_j%nb(t)        q/k/v 2d -> H x Dh, RoPE, scores x (Dh / 2)^-0.5
+    m = MLP_j%nb(RMSNorm_d(a))          gate_up = x W + B_j(A_j(x)), act(gate) * up
+    u = Linear_j(m)                     the block has no residual
+    h = h + Mamba_i(RMSNorm(h + u))     the residual is taken from h
+
+Every other layer is h + Mamba_i(RMSNorm(h)).  The shared blocks and the
+per-application adapters and linears are lists (no slicing of a stacked
+weight); the mamba layers are one stack walked by index, in runs that start
+at each application (:func:`_runs`), so uneven gaps between the ids cost
+nothing.  Each application has its own KV cache: the pools' leading axis is
+the application.  The per-slot recurrent state has the SSM family's layout
+(layer, slot, ...), so ``model_zoo`` runs its snapshots and chunk stages
+through ``mamba2``.
+
+Inside a tier's lane scope the mamba layers run under ``jax.named_scope
+("ssm")`` and the shared blocks, adapters and linears under ``"shared"``.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+import math
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,243 +39,266 @@ from repro.sharding import act
 
 Params = Dict[str, Any]
 
+def _runs(cfg: ModelConfig) -> List[Tuple[Optional[int], int, int]]:
+    """(application j or None, first layer, end layer): the mamba layers
+    [lo, hi) that follow application j; None for those before the first."""
+    ids = list(cfg.hybrid_layer_ids)
+    ends = ids + [cfg.num_layers]
+    runs = [(None, 0, ends[0])] if ends[0] > 0 else []
+    return runs + [(j, lo, ends[j + 1]) for j, lo in enumerate(ids)]
 
-def _num_groups(cfg: ModelConfig) -> int:
-    k = cfg.shared_attn_every
-    if cfg.num_layers % k:
-        raise ValueError(f"num_layers {cfg.num_layers} must divide by "
-                         f"shared_attn_every {k}")
-    return cfg.num_layers // k
+
+# Zamba2 scales scores by (head_dim / 2) ** -0.5: sqrt(2) over the attention
+# path's 1 / sqrt(head_dim), applied to q (``layers.rope_q``)
+Q_SCALE = math.sqrt(2.0)
 
 
 def init_model(rng, cfg: ModelConfig, dtype=jnp.float32) -> Params:
-    ks = jax.random.split(rng, 5)
-    layer_rngs = jax.random.split(ks[0], cfg.num_layers)
-    stacked = jax.vmap(lambda r: mamba2.init_mamba_block(r, cfg, dtype))(layer_rngs)
-    g, k = _num_groups(cfg), cfg.shared_attn_every
-    # reshape the layer stack to (G, k, ...) for the nested scan
-    stacked = jax.tree.map(lambda a: a.reshape(g, k, *a.shape[1:]), stacked)
-    shared = {
-        "ln1": L.rmsnorm_init(cfg.d_model, dtype),
-        "attn": L.attention_init(ks[1], cfg.d_model, cfg.num_heads,
-                                 cfg.num_kv_heads, cfg.resolved_head_dim,
-                                 dtype=dtype),
-        "ln2": L.rmsnorm_init(cfg.d_model, dtype),
-        "mlp": L.swiglu_init(ks[2], cfg.d_model, cfg.d_ff, dtype),
-    }
+    """Mamba layers as the SSM family's; the shared blocks, adapters and
+    linears normal with std 1 / sqrt(fan_in), unit gain at every width
+    (Zamba2's initializer_range 0.02 is about that at its widths)."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    hq = cfg.num_heads * cfg.resolved_head_dim
+    hkv = cfg.num_kv_heads * cfg.resolved_head_dim
+    n_apps = len(cfg.hybrid_layer_ids)
+    k_mamba, k_embed, k_head, k_rest = jax.random.split(rng, 4)
+    stacked = jax.vmap(lambda k: mamba2.init_mamba_block(k, cfg, dtype))(
+        jax.random.split(k_mamba, cfg.num_layers))
+    ks = iter(jax.random.split(k_rest, 6 * cfg.num_mem_blocks + 3 * n_apps))
+
+    def normal(fan_in, fan_out):
+        return L.dense_init(next(ks), fan_in, fan_out, dtype)
+
+    def block():
+        din = 2 * d                  # the block attends over concat(h, e)
+        return {"ln1": L.rmsnorm_init(din, dtype),
+                "attn": {"wq": normal(din, hq), "wk": normal(din, hkv),
+                         "wv": normal(din, hkv), "wo": normal(hq, d)},
+                "ln2": L.rmsnorm_init(d, dtype),
+                "mlp": {"wi": normal(d, 2 * f), "wo": normal(f, d)}}
+
     return {
-        "embed": L.embed_init(ks[3], cfg.vocab_size, cfg.d_model, dtype),
-        "layers": stacked,
-        "shared_attn": shared,
-        "final_norm": L.rmsnorm_init(cfg.d_model, dtype),
-        "lm_head": L.dense_init(ks[4], cfg.d_model, cfg.vocab_size, dtype),
+        "embed": L.embed_init(k_embed, cfg.vocab_size, d, dtype),
+        "mamba": stacked,
+        "shared": [block() for _ in range(cfg.num_mem_blocks)],
+        "apps": [{"lora_a": normal(d, r), "lora_b": normal(r, 2 * f),
+                  "linear": normal(d, d)} for _ in range(n_apps)],
+        "final_norm": L.rmsnorm_init(d, dtype),
+        "lm_head": L.dense_init(k_head, d, cfg.vocab_size, dtype),
     }
 
 
-def _shared_block(sp: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    a = L.attention_forward(sp["attn"], L.rmsnorm(sp["ln1"], x, cfg.norm_eps),
-                            num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
-                            head_dim=cfg.resolved_head_dim,
-                            rope_theta=cfg.rope_theta)
-    x = x + a
-    return x + L.swiglu(sp["mlp"], L.rmsnorm(sp["ln2"], x, cfg.norm_eps))
+# ---------------------------------------------------------------------------
+# the layer walk every pass shares
+# ---------------------------------------------------------------------------
+
+def _layer(stack: Params, i) -> Params:
+    return jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
+                        stack)
+
+
+def _shared_out(sp: Params, ap: Params, cfg: ModelConfig,
+                a: jnp.ndarray) -> jnp.ndarray:
+    """The block after attention: RMSNorm, the MLP with this application's
+    adapter on gate_up, then this application's linear."""
+    x = L.rmsnorm(sp["ln2"], a, cfg.norm_eps)
+    gu = x @ sp["mlp"]["wi"] + (x @ ap["lora_a"]) @ ap["lora_b"]
+    gate, up = jnp.split(gu, 2, axis=-1)
+    gate = jax.nn.gelu(gate, approximate=False)
+    return ((gate * up) @ sp["mlp"]["wo"]) @ ap["linear"]
+
+
+def _walk(params: Params, cfg: ModelConfig, e: jnp.ndarray, aux,
+          attend: Callable, mamba: Callable, remat: bool = False):
+    """Run the layers over hidden states starting at the embedding ``e``.
+
+    ``attend(attn_params, j, t, aux) -> (a, aux)``: application j's attention
+    over the normed block input t, output projection included.
+    ``mamba(lp, i, x, aux) -> (y, aux)``: mamba layer i over its normed
+    input.  ``aux`` carries what the pass updates (caches), in place."""
+    h = e
+    for j, lo, hi in _runs(cfg):
+        u = jnp.zeros_like(h)
+        if j is not None:
+            with jax.named_scope("shared"):
+                sp = params["shared"][j % cfg.num_mem_blocks]
+                t = L.rmsnorm(sp["ln1"], jnp.concatenate([h, e], axis=-1),
+                              cfg.norm_eps)
+                a, aux = attend(sp["attn"], j, t, aux)
+                u = _shared_out(sp, params["apps"][j], cfg, a)
+
+        def body(i, c):
+            h, u, aux = c
+            lp = _layer(params["mamba"], i)
+            y, aux = mamba(lp, i, L.rmsnorm(lp["ln"], h + u, cfg.norm_eps), aux)
+            # u enters the run's first layer only
+            return act.shard_hidden(h + y), jnp.zeros_like(u), aux
+
+        if remat:
+            body = jax.checkpoint(body, prevent_cse=False)
+        with jax.named_scope("ssm"):
+            h, _, aux = lax.fori_loop(lo, hi, body, (h, u, aux))
+    return h, aux
+
+
+def _logits(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
+    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return (h @ params["lm_head"]).astype(jnp.float32)
+
+
+def _qkv_rope(ap: Params, cfg: ModelConfig, t: jnp.ndarray, pos):
+    hd = cfg.resolved_head_dim
+    q, k, v = L._qkv(ap, t, cfg.num_heads, cfg.num_kv_heads, hd)
+    q = L.rope_q(q, pos, cfg.rope_theta, Q_SCALE)
+    if cfg.rope_theta > 0:
+        k = L.apply_rope(k, pos, cfg.rope_theta)
+    return q, k, v
+
+
+def _causal_attend(ap: Params, cfg: ModelConfig, q, k, v) -> jnp.ndarray:
+    b, s = q.shape[:2]
+    o = L._sdpa(q, k, v, L.causal_window_mask(s, s))
+    return o.reshape(b, s, cfg.num_heads * cfg.resolved_head_dim) @ ap["wo"]
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
             remat: bool = False, use_kernel: bool = False,
             last_only: bool = False) -> jnp.ndarray:
-    h = params["embed"][tokens]
-    sp = params["shared_attn"]
+    e = act.shard_hidden(params["embed"][tokens])
+    pos = jnp.arange(tokens.shape[1])
 
-    def inner(carry, lp):
-        x = act.shard_hidden(carry)
-        y = mamba2.mamba_block(lp, cfg, L.rmsnorm(lp["ln"], x, cfg.norm_eps),
-                               use_kernel=use_kernel)
-        return x + y, None
+    def attend(ap, j, t, aux):
+        return _causal_attend(ap, cfg, *_qkv_rope(ap, cfg, t, pos)), aux
 
-    def outer(carry, group_params):
-        x = carry
-        x, _ = lax.scan(inner, x, group_params)
-        x = _shared_block(sp, cfg, x)
-        return act.shard_hidden(x), None
+    def mamba(lp, i, x, aux):
+        return mamba2.mamba_block(lp, cfg, x, use_kernel=use_kernel), aux
 
-    if remat:
-        outer = jax.checkpoint(outer, prevent_cse=False)
-    h, _ = lax.scan(outer, act.shard_hidden(h), params["layers"])
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    h, _ = _walk(params, cfg, e, None, attend, mamba, remat=remat)
     if last_only:
         h = h[:, -1:]
-    return act.shard_logits((h @ params["lm_head"]).astype(jnp.float32))
+    return act.shard_logits(_logits(params, cfg, h))
 
 
 # ---------------------------------------------------------------------------
-# decode
+# contiguous cache (the drain path)
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=jnp.bfloat16):
-    g = _num_groups(cfg)
     ssm = mamba2.init_cache(cfg, batch, seq_len, dtype)
-    kv_shape = (g, batch, seq_len, cfg.num_kv_heads, cfg.resolved_head_dim)
-    gk = _num_groups(cfg), cfg.shared_attn_every
-    ssm_state = ssm["state"].reshape(gk[0], gk[1], *ssm["state"].shape[1:])
-    ssm_conv = ssm["conv"].reshape(gk[0], gk[1], *ssm["conv"].shape[1:])
-    return {
-        "state": ssm_state, "conv": ssm_conv,
-        "k": jnp.zeros(kv_shape, dtype), "v": jnp.zeros(kv_shape, dtype),
-        "pos": jnp.zeros((), jnp.int32),
-    }
+    kv_shape = (len(cfg.hybrid_layer_ids), batch, seq_len, cfg.num_kv_heads,
+                cfg.resolved_head_dim)
+    return {"state": ssm["state"], "conv": ssm["conv"],
+            "k": jnp.zeros(kv_shape, dtype), "v": jnp.zeros(kv_shape, dtype),
+            "pos": jnp.zeros((), jnp.int32)}
+
+
+def _step_mamba(cfg: ModelConfig):
+    """A decode step's mamba layer: the slot rows' state and conv window of
+    layer i, updated in place in ``aux``'s "state" and "conv"."""
+    def mamba(lp, i, x, aux):
+        st = lax.dynamic_index_in_dim(aux["state"], i, keepdims=False)
+        cw = lax.dynamic_index_in_dim(aux["conv"], i, keepdims=False)
+        y, st, cw = mamba2.mamba_block_step(lp, cfg, x, st, cw)
+        return y, dict(aux,
+                       state=lax.dynamic_update_index_in_dim(aux["state"], st, i, 0),
+                       conv=lax.dynamic_update_index_in_dim(aux["conv"], cw, i, 0))
+    return mamba
 
 
 def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                 cache: Params) -> Tuple[jnp.ndarray, Params]:
-    h = params["embed"][token]
-    sp = params["shared_attn"]
     pos = cache["pos"]
 
-    def inner(carry, xs):
-        x = carry
-        lp, st, cw = xs
-        y, st, cw = mamba2.mamba_block_step(
-            lp, cfg, L.rmsnorm(lp["ln"], x, cfg.norm_eps), st, cw)
-        return x + y, (st, cw)
+    def attend(ap, j, t, aux):
+        a, ck, cv = L.attention_decode(
+            ap, t, aux["k"][j], aux["v"][j], pos, num_heads=cfg.num_heads,
+            num_kv=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
+            rope_theta=cfg.rope_theta, q_scale=Q_SCALE)
+        return a, dict(aux, k=aux["k"].at[j].set(ck), v=aux["v"].at[j].set(cv))
 
-    def outer(carry, xs):
-        x = carry
-        gp, st_g, cw_g, ck, cv = xs
-        x, (st_g, cw_g) = lax.scan(inner, x, (gp, st_g, cw_g))
-        a, ck, cv = L.attention_decode(sp["attn"],
-                                       L.rmsnorm(sp["ln1"], x, cfg.norm_eps),
-                                       ck, cv, pos,
-                                       num_heads=cfg.num_heads,
-                                       num_kv=cfg.num_kv_heads,
-                                       head_dim=cfg.resolved_head_dim,
-                                       rope_theta=cfg.rope_theta)
-        x = x + a
-        x = x + L.swiglu(sp["mlp"], L.rmsnorm(sp["ln2"], x, cfg.norm_eps))
-        return x, (st_g, cw_g, ck, cv)
+    aux = {k: cache[k] for k in ("state", "conv", "k", "v")}
+    h, aux = _walk(params, cfg, params["embed"][token], aux, attend,
+                   _step_mamba(cfg))
+    return _logits(params, cfg, h[:, 0]), dict(aux, pos=pos + 1)
 
-    h, (ns, ncw, nk, nv) = lax.scan(
-        outer, h, (params["layers"], cache["state"], cache["conv"],
-                   cache["k"], cache["v"]))
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"state": ns, "conv": ncw, "k": nk, "v": nv, "pos": pos + 1}
+
+def _prefill_walk(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                  aux, store_kv: Callable, state_rows, kv_dtype, conv_dtype,
+                  use_kernel: bool, length=None, quant_page: int = 0):
+    """The prompt pass shared by :func:`prefill` and :func:`prefill_paged`:
+    each application's K/V goes to ``store_kv(j, k, v, aux)``, each layer's
+    final state and conv window to rows ``state_rows`` of ``aux``'s "state"
+    and "conv" at that layer.  In-pass attention reads K/V as the cache
+    holds them: rounded to ``kv_dtype`` or, with ``quant_page`` (int8 page
+    pools), fake-quantized through the per-page grid while the raw values
+    go to ``store_kv``, whose quantize-on-write recomputes the identical
+    scales."""
+    e = act.shard_hidden(params["embed"][tokens])
+    pos = jnp.arange(tokens.shape[1])
+
+    def attend(ap, j, t, aux):
+        q, k, v = _qkv_rope(ap, cfg, t, pos)
+        aux = store_kv(j, k, v, aux)
+        if quant_page:
+            k = L.quant_dequant_pages(k, quant_page)
+            v = L.quant_dequant_pages(v, quant_page)
+        else:
+            k, v = k.astype(kv_dtype), v.astype(kv_dtype)
+        return _causal_attend(ap, cfg, q, k, v), aux
+
+    def mamba(lp, i, x, aux):
+        y, st, cw = mamba2.mamba_block_prefill(
+            lp, cfg, x, use_kernel=use_kernel, conv_dtype=conv_dtype,
+            length=length)
+        return y, dict(aux, state=aux["state"].at[i, state_rows].set(st, mode="drop"),
+                       conv=aux["conv"].at[i, state_rows].set(cw, mode="drop"))
+
+    return _walk(params, cfg, e, aux, attend, mamba)
+
+
+def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+            cache: Params, *, use_kernel: bool = False
+            ) -> Tuple[jnp.ndarray, Params]:
+    """Consume the whole (B, S) prompt in one batched pass, writing the SSM
+    states, conv windows and every application's K/V.  ``cache`` supplies
+    the buffers and is overwritten (donation-safe).
+
+    Returns (last-token logits (B, V) fp32, filled cache).
+    """
+    s = tokens.shape[1]
+
+    def store_kv(j, k, v, aux):
+        return dict(aux, k=aux["k"].at[j, :, :s].set(k.astype(aux["k"].dtype)),
+                    v=aux["v"].at[j, :, :s].set(v.astype(aux["v"].dtype)))
+
+    aux = {k: cache[k] for k in ("state", "conv", "k", "v")}
+    h, aux = _prefill_walk(params, cfg, tokens, aux, store_kv, slice(None),
+                           cache["k"].dtype, cache["conv"].dtype, use_kernel)
+    return _logits(params, cfg, h[:, -1]), dict(aux, pos=jnp.asarray(s, jnp.int32))
 
 
 # ---------------------------------------------------------------------------
 # paged cache API (continuous batching)
 # ---------------------------------------------------------------------------
 #
-# Mamba states are per-slot (constant-size, nothing to page); the shared
-# attention block's KV is a paged pool with a per-GROUP leading axis,
-# (G, P, page, K, Dh), indexed by the scheduler's single block table (one
-# physical page holds one group's K/V for a page worth of positions).
+# Mamba states are per-slot (constant-size, nothing to page); each shared
+# block application's K/V is a paged pool behind one leading axis,
+# (A, P, page, K, Dh), indexed by the scheduler's single block table (one
+# physical page holds every application's K/V for a page worth of
+# positions).
 
 
 def init_paged_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
                      page_size: int, dtype=jnp.bfloat16):
-    g, k = _num_groups(cfg), cfg.shared_attn_every
-    h, p, n = cfg.ssm_nheads, cfg.ssm_head_dim, cfg.ssm_state
-    conv_dim = cfg.ssm_d_inner + 2 * n
-    kv_shape = (g, num_pages, page_size, cfg.num_kv_heads,
-                cfg.resolved_head_dim)
-    quant = jnp.dtype(dtype) == jnp.int8
-    # recurrent state stays full-precision under int8 KV quantization (it is
-    # per-slot and constant-size — paging/quantizing it buys nothing)
-    conv_dtype = jnp.bfloat16 if quant else dtype
-    cache = {
-        "state": jnp.zeros((g, k, num_slots, h, p, n), jnp.float32),
-        "conv": jnp.zeros((g, k, num_slots, cfg.ssm_conv_width - 1, conv_dim),
-                          conv_dtype),
-        "kp": jnp.zeros(kv_shape, dtype), "vp": jnp.zeros(kv_shape, dtype),
-    }
-    if quant:
-        sshape = (g, num_pages, cfg.num_kv_heads)
-        cache["ks"] = jnp.zeros(sshape, jnp.float32)
-        cache["vs"] = jnp.zeros(sshape, jnp.float32)
-    return cache
-
-
-def _prefill_outer(params: Params, cfg: ModelConfig, s: int, b: int,
-                   kv_dtype, conv_dtype, use_kernel: bool, length, store_kv,
-                   page: int = 0, quant: bool = False):
-    """The per-group prefill scan body shared by :func:`prefill` (contiguous
-    cache) and :func:`prefill_paged` (page pool).  ``store_kv(kv, k, v)``
-    writes the group's shared-attention K/V into whichever layout the caller
-    scans through; everything else is identical between the two paths.
-
-    ``quant`` (int8 page pool): in-pass attention sees K/V fake-quantized
-    through the per-page int8 grid while RAW values flow to ``store_kv``,
-    whose quantize-on-write recomputes the identical scales."""
-    sp = params["shared_attn"]
-    hd = cfg.resolved_head_dim
-    pos = jnp.arange(s)
-
-    def inner(carry, lp):
-        x = carry
-        y, st, cw = mamba2.mamba_block_prefill(
-            lp, cfg, L.rmsnorm(lp["ln"], x, cfg.norm_eps),
-            use_kernel=use_kernel, conv_dtype=conv_dtype, length=length)
-        return x + y, (st, cw)
-
-    def outer(carry, xs):
-        x = carry
-        gp, kv = xs
-        x, (st_g, cw_g) = lax.scan(inner, x, gp)
-        xn = L.rmsnorm(sp["ln1"], x, cfg.norm_eps)
-        q, k, v = L._qkv(sp["attn"], xn, cfg.num_heads, cfg.num_kv_heads, hd)
-        if cfg.rope_theta > 0:
-            q = L.apply_rope(q, pos, cfg.rope_theta)
-            k = L.apply_rope(k, pos, cfg.rope_theta)
-        if quant:
-            k_raw, v_raw = k, v
-            k = L.quant_dequant_pages(k, page)
-            v = L.quant_dequant_pages(v, page)
-        else:
-            k = k.astype(kv_dtype)
-            v = v.astype(kv_dtype)
-        a = L._sdpa(q, k, v, L.causal_window_mask(s, s))
-        x = x + a.reshape(b, s, cfg.num_heads * hd) @ sp["attn"]["wo"]
-        x = x + L.swiglu(sp["mlp"], L.rmsnorm(sp["ln2"], x, cfg.norm_eps))
-        stored = store_kv(kv, k_raw, v_raw) if quant else store_kv(kv, k, v)
-        return act.shard_hidden(x), (st_g, cw_g, stored)
-
-    return outer
-
-
-def init_prefix_cache(cfg: ModelConfig, entries: int, dtype=jnp.bfloat16):
-    """Full-prompt snapshot rows for the recurrent half of the hybrid: the
-    per-group mamba states + conv windows at the prompt boundary.  The
-    shared-attention K/V needs no snapshot — its prompt pages are retained
-    by the pool's prefix index and aliased on restore."""
-    g, k = _num_groups(cfg), cfg.shared_attn_every
-    h, p, n = cfg.ssm_nheads, cfg.ssm_head_dim, cfg.ssm_state
-    conv_dim = cfg.ssm_d_inner + 2 * n
+    cache = mamba2.init_paged_cache(cfg, num_slots, num_pages, page_size, dtype)
+    kv_shape = (len(cfg.hybrid_layer_ids), num_pages, page_size,
+                cfg.num_kv_heads, cfg.resolved_head_dim)
+    cache.update(kp=jnp.zeros(kv_shape, dtype), vp=jnp.zeros(kv_shape, dtype))
     if jnp.dtype(dtype) == jnp.int8:
-        dtype = jnp.bfloat16
-    return {
-        "state": jnp.zeros((g, k, entries, h, p, n), jnp.float32),
-        "conv": jnp.zeros((g, k, entries, cfg.ssm_conv_width - 1, conv_dim),
-                          dtype),
-    }
-
-
-def snapshot_save(cfg: ModelConfig, cache: Params, prefix: Params,
-                  rows: jnp.ndarray, slots: jnp.ndarray) -> Params:
-    return dict(prefix,
-                state=prefix["state"].at[:, :, rows].set(
-                    cache["state"][:, :, slots], mode="drop"),
-                conv=prefix["conv"].at[:, :, rows].set(
-                    cache["conv"][:, :, slots], mode="drop"))
-
-
-def snapshot_restore(cfg: ModelConfig, cache: Params, prefix: Params,
-                     rows: jnp.ndarray, slots: jnp.ndarray) -> Params:
-    return dict(cache,
-                state=cache["state"].at[:, :, slots].set(
-                    prefix["state"][:, :, rows], mode="drop"),
-                conv=cache["conv"].at[:, :, slots].set(
-                    prefix["conv"][:, :, rows], mode="drop"))
+        sshape = kv_shape[:2] + (cfg.num_kv_heads,)
+        cache.update(ks=jnp.zeros(sshape, jnp.float32),
+                     vs=jnp.zeros(sshape, jnp.float32))
+    return cache
 
 
 def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -265,92 +306,56 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   block_rows: jnp.ndarray, cache: Params, *,
                   use_kernel: bool = False,
                   start=None) -> Tuple[jnp.ndarray, Params]:
-    """Prefill a batch of admitted requests: per-group SSM states/conv
-    windows land in slots ``slots``; shared-attention K/V lands in each
-    slot's pages.  The group math is EXACTLY :func:`prefill`'s (shared
-    ``_prefill_outer``); only the K/V store differs.
+    """Prefill a batch of admitted requests: per-layer SSM states and conv
+    windows land in slots ``slots``; each application's K/V lands in each
+    slot's pages.
 
-    ``start``: the hybrid family shares prefixes only at whole-prompt
-    granularity (the mamba state has no mid-prompt snapshot), so per row
-    ``start`` is 0 (miss: full prefill) or bucket (restore: every page write
-    redirected to the null page — the aliased prompt pages are read-only)."""
-    h = params["embed"][tokens]
-    b, s, _ = h.shape
+    ``start``: the hybrid shares prefixes only at whole-prompt granularity
+    (the mamba state has no mid-prompt snapshot), so per row ``start`` is 0
+    (miss: full prefill) or bucket (restore: every page write redirected to
+    the null page — the aliased prompt pages are read-only)."""
+    s = tokens.shape[1]
     page = cache["kp"].shape[2]
     npg = s // page
     quant = "ks" in cache
     wrows = (block_rows[:, :npg] if start is None
              else L.suffix_write_rows(block_rows, start, npg, page))
 
-    if quant:
-        def store_kv(kv, k, v):
-            pk, pv, sk, sv = kv
-            pk, sk = L.quant_scatter_prefill_pages(pk, sk, k, wrows)
-            pv, sv = L.quant_scatter_prefill_pages(pv, sv, v, wrows)
-            return (pk, pv, sk, sv)
-        kv0 = (cache["kp"], cache["vp"], cache["ks"], cache["vs"])
-    else:
-        def store_kv(kv, k, v):
-            pk, pv = kv
-            return (L.scatter_prefill_pages(pk, k, wrows),
-                    L.scatter_prefill_pages(pv, v, wrows))
-        kv0 = (cache["kp"], cache["vp"])
+    def store_kv(j, k, v, aux):
+        if quant:
+            kp, ks = L.quant_scatter_prefill_pages(aux["kp"], aux["ks"], k, wrows, j)
+            vp, vs = L.quant_scatter_prefill_pages(aux["vp"], aux["vs"], v, wrows, j)
+            return dict(aux, kp=kp, vp=vp, ks=ks, vs=vs)
+        return dict(aux, kp=L.scatter_prefill_pages(aux["kp"], k, wrows, j),
+                    vp=L.scatter_prefill_pages(aux["vp"], v, wrows, j))
 
-    outer = _prefill_outer(params, cfg, s, b, cache["kp"].dtype,
-                           cache["conv"].dtype, use_kernel, lengths, store_kv,
-                           page=page, quant=quant)
-    h, (ns, ncw, nkv) = lax.scan(
-        outer, act.shard_hidden(h), (params["layers"], kv0))
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    h, cache = _prefill_walk(params, cfg, tokens, dict(cache), store_kv,
+                             slots, cache["kp"].dtype, cache["conv"].dtype,
+                             use_kernel, length=lengths,
+                             quant_page=page if quant else 0)
     h = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)
-    logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
-    new_cache = {
-        "state": cache["state"].at[:, :, slots].set(ns, mode="drop"),
-        "conv": cache["conv"].at[:, :, slots].set(ncw, mode="drop"),
-        "kp": nkv[0], "vp": nkv[1],
-    }
-    if quant:
-        new_cache["ks"], new_cache["vs"] = nkv[2], nkv[3]
-    return logits, new_cache
+    return _logits(params, cfg, h[:, 0]), cache
 
 
 def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                       pos: jnp.ndarray, block: jnp.ndarray, cache: Params, *,
                       use_kernel: bool = False,
                       write_block=None) -> Tuple[jnp.ndarray, Params]:
-    """One decode step for all slots at per-slot positions."""
-    h = params["embed"][token]
-    sp = params["shared_attn"]
+    """One decode step for all slots at per-slot positions.  The stacked KV
+    pools and the per-slot recurrent state ride in the layer walk's carry
+    and are updated in place."""
 
-    def inner(carry, xs):
-        x = carry
-        lp, st, cw = xs
-        y, st, cw = mamba2.mamba_block_step(
-            lp, cfg, L.rmsnorm(lp["ln"], x, cfg.norm_eps), st, cw)
-        return x + y, (st, cw)
-
-    def outer(carry, xs):
-        x, kv = carry
-        gp, st_g, cw_g, gi = xs
-        x, (st_g, cw_g) = lax.scan(inner, x, (gp, st_g, cw_g))
+    def attend(ap, j, t, aux):
         a, kv = L.attention_decode_paged(
-            sp["attn"], L.rmsnorm(sp["ln1"], x, cfg.norm_eps), kv, gi,
-            block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
-            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-            use_kernel=use_kernel, write_block=write_block)
-        x = x + a
-        x = x + L.swiglu(sp["mlp"], L.rmsnorm(sp["ln2"], x, cfg.norm_eps))
-        return (x, kv), (st_g, cw_g)
+            ap, t, L.paged_kv(aux), j, block, pos, num_heads=cfg.num_heads,
+            num_kv=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
+            rope_theta=cfg.rope_theta, use_kernel=use_kernel,
+            write_block=write_block, q_scale=Q_SCALE)
+        return a, dict(aux, **kv)
 
-    # the shared block's stacked KV pools ride in the carry (updated in
-    # place); the per-slot recurrent state is sliced in and stacked out
-    (h, kv), (ns, ncw) = lax.scan(
-        outer, (h, L.paged_kv(cache)),
-        (params["layers"], cache["state"], cache["conv"],
-         jnp.arange(cache["kp"].shape[0])))
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"state": ns, "conv": ncw, **kv}
+    h, cache = _walk(params, cfg, params["embed"][token], dict(cache), attend,
+                     _step_mamba(cfg))
+    return _logits(params, cfg, h[:, 0]), cache
 
 
 def forward_chunk_paged(params: Params, cfg: ModelConfig,
@@ -362,74 +367,18 @@ def forward_chunk_paged(params: Params, cfg: ModelConfig,
     (bitwise identical to C sequential ``decode_step_paged`` calls — the
     mamba half is inherently sequential) emitting per-step {state, conv}
     CHUNK-BOUNDARY SNAPSHOTS so a chunk can be rolled back to any intra-chunk
-    position; the shared-attention K/V lands in the pages positionally (a
+    position; the shared blocks' K/V lands in the pages positionally (a
     rollback just rewinds the host position).  Token i of slot b writes at
     ``pos[b] + i`` through ``write_block``.  Returns (logits (B, C, V) fp32,
     cache, staged)."""
 
-    def step(carry, xs):
-        cache = carry
+    def step(cache, xs):
         tok, j = xs
         logits, cache = decode_step_paged(params, cfg, tok[:, None], pos + j,
                                           block, cache, use_kernel=use_kernel,
                                           write_block=write_block)
-        return cache, (logits, {"state": cache["state"],
-                                "conv": cache["conv"]})
+        return cache, (logits, mamba2.chunk_stage(cfg, cache))
 
     c = tokens.shape[1]
-    cache, (logits, staged) = lax.scan(step, cache,
-                                       (tokens.T, jnp.arange(c)))
+    cache, (logits, staged) = lax.scan(step, cache, (tokens.T, jnp.arange(c)))
     return logits.transpose(1, 0, 2), cache, staged
-
-
-def chunk_stage(cfg: ModelConfig, cache: Params) -> dict:
-    """Rollback-able recurrent slice (slot axis 2: leaves (G, K, B, ...))."""
-    return {"state": cache["state"], "conv": cache["conv"]}
-
-
-def restore_stage(cfg: ModelConfig, cache: Params, stage: dict,
-                  mask: jnp.ndarray) -> Params:
-    return dict(cache,
-                state=jnp.where(mask[None, None, :, None, None, None],
-                                stage["state"], cache["state"]),
-                conv=jnp.where(mask[None, None, :, None, None],
-                               stage["conv"], cache["conv"]))
-
-
-def select_stage(cfg: ModelConfig, staged: dict, keep: jnp.ndarray) -> dict:
-    """staged leaves (C, G, K, B, ...) -> snapshot after ``keep`` inputs."""
-    idx = jnp.maximum(keep - 1, 0)
-
-    def sel(a):
-        i = idx.reshape((1, 1, 1, -1) + (1,) * (a.ndim - 4))
-        return jnp.take_along_axis(a, i, axis=0)[0]
-
-    return {"state": sel(staged["state"]), "conv": sel(staged["conv"])}
-
-
-def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-            cache: Params, *, use_kernel: bool = False
-            ) -> Tuple[jnp.ndarray, Params]:
-    """Consume the whole (B, S) prompt in one batched pass, writing the SSM
-    states, conv windows, and the per-group shared-attention KV slots.
-    ``cache`` supplies the buffers and is overwritten (donation-safe).
-
-    Returns (last-token logits (B, V) fp32, filled cache).
-    """
-    h = params["embed"][tokens]
-    b, s, _ = h.shape
-
-    def store_kv(kv, k, v):
-        ck, cv = kv
-        return (lax.dynamic_update_slice(ck, k, (0, 0, 0, 0)),
-                lax.dynamic_update_slice(cv, v, (0, 0, 0, 0)))
-
-    outer = _prefill_outer(params, cfg, s, b, cache["k"].dtype,
-                           cache["conv"].dtype, use_kernel, None, store_kv)
-    h, (ns, ncw, (nk, nv)) = lax.scan(
-        outer, act.shard_hidden(h), (params["layers"],
-                                     (cache["k"], cache["v"])))
-    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = (h[:, -1, :] @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"state": ns, "conv": ncw, "k": nk, "v": nv,
-                    "pos": jnp.asarray(s, jnp.int32)}

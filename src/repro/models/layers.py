@@ -83,6 +83,20 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     return out.astype(x.dtype)
 
 
+def rope_q(q: jnp.ndarray, positions: jnp.ndarray, theta: float,
+           q_scale: float = 1.0) -> jnp.ndarray:
+    """RoPE on queries, then ``q_scale`` in the same float32 pass (one
+    rounding to q's dtype): attention divides scores by sqrt(head_dim), so a
+    model whose published score scale differs (zamba2's (head_dim / 2) **
+    -0.5) passes the ratio here.  ``q_scale`` 1 is exactly the plain path."""
+    if q_scale == 1.0:
+        return apply_rope(q, positions, theta) if theta > 0 else q
+    q32 = q.astype(jnp.float32)
+    if theta > 0:
+        q32 = apply_rope(q32, positions, theta)
+    return (q32 * q_scale).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # attention
 # ---------------------------------------------------------------------------
@@ -261,16 +275,18 @@ def attention_decode(params: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
                      cache_v: jnp.ndarray, pos: jnp.ndarray, *, num_heads: int,
                      num_kv: int, head_dim: int, rope_theta: float,
                      window: Optional[jnp.ndarray] = None,
-                     use_kernel: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                     use_kernel: bool = False, q_scale: float = 1.0
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One-token decode.  x: (B, 1, D); cache_k/v: (B, S, K, Dh); pos: scalar.
+    ``q_scale``: see :func:`rope_q`.
 
     Returns (out (B,1,D), new_cache_k, new_cache_v).
     """
     b = x.shape[0]
     q, k, v = _qkv(params, x, num_heads, num_kv, head_dim)
+    p1 = jnp.full((1,), pos, dtype=jnp.int32)
+    q = rope_q(q, p1, rope_theta, q_scale)
     if rope_theta > 0:
-        p1 = jnp.full((1,), pos, dtype=jnp.int32)
-        q = apply_rope(q, p1, rope_theta)
         k = apply_rope(k, p1, rope_theta)
     cache_k = lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),
                                        (0, pos, 0, 0))
@@ -311,7 +327,8 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
                            head_dim: int, rope_theta: float,
                            window: Optional[jnp.ndarray] = None,
                            use_kernel: bool = False,
-                           write_block: Optional[jnp.ndarray] = None
+                           write_block: Optional[jnp.ndarray] = None,
+                           q_scale: float = 1.0
                            ) -> Tuple[jnp.ndarray, Params]:
     """One-token decode of layer ``layer`` against a tier's STACKED paged KV
     pools, updated in place.
@@ -342,7 +359,8 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
     ``ks``/``vs`` in ``kv`` mark the pools int8-quantized (per-page-per-head
     symmetric scales): the append quantizes through a monotone running-max
     page scale (see :func:`quant_append_page`) and the gather dequantizes —
-    fused into the Pallas kernel under ``use_kernel``.
+    fused into the Pallas kernel under ``use_kernel``.  ``q_scale``: see
+    :func:`rope_q`.
 
     Returns (out (B,1,D), kv).
     """
@@ -353,9 +371,9 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
     n_pages = block.shape[1]
     s_tot = n_pages * page
     q, k, v = _qkv(params, x, num_heads, num_kv, head_dim)
+    pq = pos[:, None]                        # (B, 1) absolute positions
+    q = rope_q(q, pq, rope_theta, q_scale)
     if rope_theta > 0:
-        pq = pos[:, None]                    # (B, 1) absolute positions
-        q = apply_rope(q, pq, rope_theta)
         k = apply_rope(k, pq, rope_theta)
     rows = jnp.arange(b)
     wb = block if write_block is None else write_block
@@ -560,15 +578,17 @@ def quant_dequant_pages(kv: jnp.ndarray, page: int) -> jnp.ndarray:
 
 
 def quant_scatter_prefill_pages(pool: jnp.ndarray, scale: jnp.ndarray,
-                                seq_kv: jnp.ndarray, block_rows: jnp.ndarray
+                                seq_kv: jnp.ndarray, block_rows: jnp.ndarray,
+                                layer: Optional[int] = None
                                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Quantized :func:`scatter_prefill_pages`: write whole prefill pages
     int8 with freshly computed per-page-per-head scales (SET semantics —
     prefill owns the page, recycled scales are overwritten).  seq_kv carries
     the RAW (pre-quantization) values; rows redirected to the null page
     (padding / shared-prefix suffixing) drop both page and scale writes
-    there harmlessly."""
-    page = pool.shape[1]
+    there harmlessly.  ``layer``: as for :func:`scatter_prefill_pages`."""
+    lead = () if layer is None else (layer,)
+    page = pool.shape[-3]
     a, s = seq_kv.shape[:2]
     paged = seq_kv.astype(jnp.float32).reshape(a, s // page, page,
                                                *seq_kv.shape[2:])
@@ -576,8 +596,8 @@ def quant_scatter_prefill_pages(pool: jnp.ndarray, scale: jnp.ndarray,
     sc = jnp.max(jnp.abs(paged), axis=(2, 4)) / _QMAX         # (A, npg, K)
     q = jnp.clip(jnp.round(paged / _safe_scale(sc)[:, :, None, :, None]),
                  -_QMAX, _QMAX)
-    pool = pool.at[rows].set(q.astype(pool.dtype), mode="drop")
-    scale = scale.at[rows].set(sc, mode="drop")
+    pool = pool.at[lead + (rows,)].set(q.astype(pool.dtype), mode="drop")
+    scale = scale.at[lead + (rows,)].set(sc, mode="drop")
     return pool, scale
 
 
@@ -592,19 +612,23 @@ def cow_copy_scales(scale: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray
 
 
 def scatter_prefill_pages(pool: jnp.ndarray, seq_kv: jnp.ndarray,
-                          block_rows: jnp.ndarray) -> jnp.ndarray:
+                          block_rows: jnp.ndarray,
+                          layer: Optional[int] = None) -> jnp.ndarray:
     """Write a batch of sequences' prefill K (or V) into their pages.
 
-    pool: (P, page, K, Dh); seq_kv: (A, S, K, Dh) with S % page == 0;
-    block_rows: (A, n_pages) — only the first S // page entries of each row
-    are written.  Rows belonging to masked/padded admissions point at the
-    null page 0; their (raced, garbage) writes land there harmlessly.
+    pool: (P, page, K, Dh) — or, with ``layer``, a stacked (L, P, page, K,
+    Dh) pool written at that layer in place; seq_kv: (A, S, K, Dh) with
+    S % page == 0; block_rows: (A, n_pages) — only the first S // page
+    entries of each row are written.  Rows belonging to masked/padded
+    admissions point at the null page 0; their (raced, garbage) writes land
+    there harmlessly.
     """
-    page = pool.shape[1]
+    lead = () if layer is None else (layer,)
+    page = pool.shape[-3]
     a, s = seq_kv.shape[:2]
     paged = seq_kv.reshape(a, s // page, page, *seq_kv.shape[2:])
-    return pool.at[block_rows[:, : s // page]].set(paged.astype(pool.dtype),
-                                                   mode="drop")
+    return pool.at[lead + (block_rows[:, : s // page],)].set(
+        paged.astype(pool.dtype), mode="drop")
 
 
 def suffix_write_rows(block_rows: jnp.ndarray, start: jnp.ndarray,

@@ -35,12 +35,14 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     x:  (b, l, h, p)   per-head inputs
     dt: (b, l, h)      positive step sizes (softplus already applied)
     A:  (h,)           negative decay rates
-    B:  (b, l, n)      input projections (single group)
-    C:  (b, l, n)      output projections (single group)
+    B:  (b, l, G, n)   input projections of G groups; head j reads group
+                       j // (h / G)
+    C:  (b, l, G, n)   output projections, grouped alike
     Returns (y (b,l,h,p), final_state (b,h,p,n)).
     """
     b, l, h, p = x.shape
-    n = B.shape[-1]
+    g, n = B.shape[-2:]
+    hg = h // g
     chunk = min(chunk, l)
     orig_l = l
     if l % chunk:
@@ -48,33 +50,35 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         pad = chunk - l % chunk
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0), (0, 0)))
         l = l + pad
     nc = l // chunk
 
     f32 = jnp.float32
-    a = (dt.astype(f32) * A.astype(f32)).reshape(b, nc, chunk, h)        # log-decay
-    dx = (x.astype(f32) * dt.astype(f32)[..., None]).reshape(b, nc, chunk, h, p)
-    Bc = B.astype(f32).reshape(b, nc, chunk, n)
-    Cc = C.astype(f32).reshape(b, nc, chunk, n)
+    # heads split as (group, head in group) so each reads its group's B/C
+    a = (dt.astype(f32) * A.astype(f32)).reshape(b, nc, chunk, g, hg)    # log-decay
+    dx = (x.astype(f32) * dt.astype(f32)[..., None]).reshape(b, nc, chunk, g, hg, p)
+    Bc = B.astype(f32).reshape(b, nc, chunk, g, n)
+    Cc = C.astype(f32).reshape(b, nc, chunk, g, n)
 
-    a_cs = jnp.cumsum(a, axis=2)                                         # (b,nc,q,h)
+    a_cs = jnp.cumsum(a, axis=2)                                         # (b,nc,q,g,hg)
 
     # --- intra-chunk (diagonal blocks): attention-like with decay mask
     # L[i, j] = exp(a_cs[i] - a_cs[j]) for i >= j else 0
-    decay = jnp.exp(a_cs[:, :, :, None, :] - a_cs[:, :, None, :, :])     # (b,nc,i,j,h)
+    decay = jnp.exp(a_cs[:, :, :, None] - a_cs[:, :, None, :])           # (b,nc,i,j,g,hg)
     tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.where(tri[None, None, :, :, None], decay, 0.0)
-    cb = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)                           # (b,nc,i,j)
-    y_diag = jnp.einsum("bcij,bcijh,bcjhp->bcihp", cb, decay, dx)
+    decay = jnp.where(tri[None, None, :, :, None, None], decay, 0.0)
+    cb = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc)                        # (b,nc,g,i,j)
+    y_diag = jnp.einsum("bcgij,bcijgh,bcjghp->bcighp", cb, decay, dx)
 
     # --- chunk summary states: S_c = sum_j exp(a_end - a_cs[j]) dx_j B_j^T
-    decay_to_end = jnp.exp(a_cs[:, :, -1:, :] - a_cs)                    # (b,nc,q,h)
-    S = jnp.einsum("bcjh,bcjhp,bcjn->bchpn", decay_to_end, dx, Bc)
+    decay_to_end = jnp.exp(a_cs[:, :, -1:] - a_cs)                       # (b,nc,q,g,hg)
+    S = jnp.einsum("bcjgh,bcjghp,bcjgn->bcghpn", decay_to_end, dx, Bc)
+    S = S.reshape(b, nc, h, p, n)
 
     # --- inter-chunk recurrence
-    g = jnp.exp(a_cs[:, :, -1, :])                                       # (b,nc,h)
+    gd = jnp.exp(a_cs[:, :, -1]).reshape(b, nc, h)                       # (b,nc,h)
     h0 = jnp.zeros((b, h, p, n), f32) if init_state is None \
         else init_state.astype(f32)
 
@@ -83,11 +87,11 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         hnew = g_c[:, :, None, None] * hprev + S_c
         return hnew, hprev
 
-    hT, h_prevs = lax.scan(step, h0, (g.transpose(1, 0, 2), S.transpose(1, 0, 2, 3, 4)))
-    h_prevs = h_prevs.transpose(1, 0, 2, 3, 4)                           # (b,nc,h,p,n)
+    hT, h_prevs = lax.scan(step, h0, (gd.transpose(1, 0, 2), S.transpose(1, 0, 2, 3, 4)))
+    h_prevs = h_prevs.transpose(1, 0, 2, 3, 4).reshape(b, nc, g, hg, p, n)
 
     # --- inter-chunk contribution: y_off[i] = exp(a_cs[i]) C_i . h_prev
-    y_off = jnp.einsum("bcih,bcin,bchpn->bcihp",
+    y_off = jnp.einsum("bcigh,bcign,bcghpn->bcighp",
                        jnp.exp(a_cs), Cc, h_prevs)
 
     y = (y_diag + y_off).reshape(b, l, h, p)[:, :orig_l]
@@ -99,16 +103,28 @@ def ssd_recurrent_step(state: jnp.ndarray, x_t: jnp.ndarray, dt_t: jnp.ndarray,
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One decode step.
 
-    state: (b, h, p, n); x_t: (b, h, p); dt_t: (b, h); B_t/C_t: (b, n).
-    Returns (y_t (b, h, p), new_state).
+    state: (b, p, n, h), heads minor (see :func:`state_to_cache`); x_t:
+    (b, h, p); dt_t: (b, h); B_t/C_t: (b, G, n).
+    Returns (y_t (b, h, p), new_state (b, p, n, h)).  Elementwise products
+    and a reduction over n, in the state's own layout.
     """
     f32 = jnp.float32
+    rep = x_t.shape[1] // B_t.shape[1]
+    Bh = jnp.repeat(B_t.astype(f32), rep, axis=1).swapaxes(1, 2)         # (b,n,h)
+    Ch = jnp.repeat(C_t.astype(f32), rep, axis=1).swapaxes(1, 2)
     da = jnp.exp(dt_t.astype(f32) * A.astype(f32))                       # (b,h)
-    dx = x_t.astype(f32) * dt_t.astype(f32)[..., None]                   # (b,h,p)
-    upd = jnp.einsum("bhp,bn->bhpn", dx, B_t.astype(f32))
-    new_state = da[:, :, None, None] * state.astype(f32) + upd
-    y = jnp.einsum("bhpn,bn->bhp", new_state, C_t.astype(f32))
-    return y.astype(x_t.dtype), new_state
+    dx = (x_t.astype(f32) * dt_t.astype(f32)[..., None]).swapaxes(1, 2)  # (b,p,h)
+    new_state = (da[:, None, None, :] * state.astype(f32)
+                 + dx[:, :, None, :] * Bh[:, None, :, :])
+    y = jnp.sum(new_state * Ch[:, None, :, :], axis=2)                   # (b,p,h)
+    return y.swapaxes(1, 2).astype(x_t.dtype), new_state
+
+
+def state_to_cache(state: jnp.ndarray) -> jnp.ndarray:
+    """(..., h, p, n) -> the cache's (..., p, n, h): heads minor, the layout
+    the decode step's elementwise update keeps (lane-dense at 112 heads,
+    where n = 64 minor would pad to twice its bytes)."""
+    return jnp.moveaxis(state, -3, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -140,16 +156,25 @@ def causal_conv_step(window: jnp.ndarray, x_t: jnp.ndarray, w: jnp.ndarray,
 # block init / forward
 # ---------------------------------------------------------------------------
 
+def conv_dim(cfg: ModelConfig) -> int:
+    """Channels of the causal conv: x, then the G groups' B and C."""
+    return cfg.ssm_d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state
+
+
 def init_mamba_block(rng, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     d, di = cfg.d_model, cfg.ssm_d_inner
-    n, h, W = cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_conv_width
-    conv_dim = di + 2 * n
+    h, W = cfg.ssm_nheads, cfg.ssm_conv_width
     k1, k2, k3 = jax.random.split(rng, 3)
+    w_in = L.dense_init(k1, d, di + conv_dim(cfg) + h, dtype)
     return {
         "ln": L.rmsnorm_init(d, dtype),
-        "in_proj": L.dense_init(k1, d, 2 * di + 2 * n + h, dtype),
-        "conv_w": (jax.random.normal(k2, (W, conv_dim)) * 0.1).astype(dtype),
-        "conv_b": jnp.zeros((conv_dim,), dtype),
+        # the input projection in three matrices, [z | xBC | dt]: each of
+        # the first two has a 128-multiple width at real sizes, so the
+        # device keeps its rows in one layout for every lane
+        "in_z": w_in[:, :di], "in_xbc": w_in[:, di:di + conv_dim(cfg)],
+        "in_dt": w_in[:, di + conv_dim(cfg):],
+        "conv_w": (jax.random.normal(k2, (W, conv_dim(cfg))) * 0.1).astype(dtype),
+        "conv_b": jnp.zeros((conv_dim(cfg),), dtype),
         "A_log": jnp.zeros((h,), jnp.float32),          # A = -exp(A_log) = -1
         "D": jnp.ones((h,), jnp.float32),
         "dt_bias": jnp.zeros((h,), jnp.float32),
@@ -158,36 +183,59 @@ def init_mamba_block(rng, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     }
 
 
-def _split_proj(cfg: ModelConfig, zxbcdt: jnp.ndarray):
-    di, n, h = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_nheads
-    z = zxbcdt[..., :di]
-    xbc = zxbcdt[..., di:2 * di + 2 * n]
-    dt = zxbcdt[..., 2 * di + 2 * n:]
-    return z, xbc, dt
+def _in_proj(lp: Params, x: jnp.ndarray):
+    """x -> (gate z, conv input xBC, dt)."""
+    return x @ lp["in_z"], x @ lp["in_xbc"], x @ lp["in_dt"]
+
+
+def _ssm_inputs(lp: Params, cfg: ModelConfig, xbc: jnp.ndarray,
+                dt: jnp.ndarray):
+    """Post-conv xBC and raw dt -> x (..., H, P), B and C (..., G, N), and
+    dt (..., H) after softplus and the configured floor."""
+    di, n, g = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_ngroups
+    lead = xbc.shape[:-1]
+    xs = xbc[..., :di].reshape(*lead, cfg.ssm_nheads, cfg.ssm_head_dim)
+    B = xbc[..., di:di + g * n].reshape(*lead, g, n)
+    C = xbc[..., di + g * n:].reshape(*lead, g, n)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    if cfg.ssm_dt_min:
+        dt = jnp.maximum(dt, cfg.ssm_dt_min)
+    return xs, B, C, dt
+
+
+def _gated_out(lp: Params, cfg: ModelConfig, y: jnp.ndarray,
+               z: jnp.ndarray) -> jnp.ndarray:
+    """RMSNorm of y * silu(z) over each group's d_inner / G channels, then
+    the output projection."""
+    v = y * jax.nn.silu(z)
+    lead, di = v.shape[:-1], v.shape[-1]
+    g = cfg.ssm_ngroups
+    v32 = v.astype(jnp.float32).reshape(*lead, g, di // g)
+    v32 = v32 * lax.rsqrt(jnp.mean(jnp.square(v32), axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    v = (v32.reshape(*lead, di)
+         * lp["norm"]["scale"].astype(jnp.float32)).astype(y.dtype)
+    return v @ lp["out_proj"]
+
+
+def _ssd(cfg: ModelConfig, xs, dt, A, B, C, use_kernel: bool):
+    if use_kernel:
+        from repro.kernels import ops as kops
+        return kops.ssd(xs, dt, A, B, C, chunk=cfg.ssm_chunk)
+    return ssd_chunked(xs, dt, A, B, C, chunk=cfg.ssm_chunk)
 
 
 def mamba_block(lp: Params, cfg: ModelConfig, x: jnp.ndarray,
                 use_kernel: bool = False) -> jnp.ndarray:
     """Full-sequence mamba2 block (pre-norm residual applied by caller)."""
     b, l, _ = x.shape
-    di, n, h, p = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_head_dim
-    zxbcdt = x @ lp["in_proj"]
-    z, xbc, dt = _split_proj(cfg, zxbcdt)
+    z, xbc, dt = _in_proj(lp, x)
     xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp["conv_b"]))
-    xs = xbc[..., :di].reshape(b, l, h, p)
-    B = xbc[..., di:di + n]
-    C = xbc[..., di + n:]
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    xs, B, C, dt = _ssm_inputs(lp, cfg, xbc, dt)
     A = -jnp.exp(lp["A_log"])
-    if use_kernel:
-        from repro.kernels import ops as kops
-        y, _ = kops.ssd(xs, dt, A, B, C, chunk=cfg.ssm_chunk)
-    else:
-        y, _ = ssd_chunked(xs, dt, A, B, C, chunk=cfg.ssm_chunk)
+    y, _ = _ssd(cfg, xs, dt, A, B, C, use_kernel)
     y = y + lp["D"].astype(y.dtype)[None, None, :, None] * xs
-    y = y.reshape(b, l, di)
-    y = L.rmsnorm(lp["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return y @ lp["out_proj"]
+    return _gated_out(lp, cfg, y.reshape(b, l, cfg.ssm_d_inner), z)
 
 
 def mamba_block_prefill(lp: Params, cfg: ModelConfig, x: jnp.ndarray, *,
@@ -196,8 +244,8 @@ def mamba_block_prefill(lp: Params, cfg: ModelConfig, x: jnp.ndarray, *,
                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Full-sequence mamba2 block that also emits the decode-cache entries.
 
-    Returns (y (B, L, D), final SSM state (B, H, P, N) fp32, conv window
-    (B, W-1, conv_dim)).  The conv window holds the last W-1 *raw*
+    Returns (y (B, L, D), final SSM state (B, P, N, H) fp32 in the cache's
+    layout, conv window (B, W-1, conv_dim)).  The conv window holds the last W-1 *raw*
     (pre-activation) conv inputs, zero-padded on the left for short prompts —
     exactly the state :func:`causal_conv_step` would have accumulated.
 
@@ -209,10 +257,8 @@ def mamba_block_prefill(lp: Params, cfg: ModelConfig, x: jnp.ndarray, *,
     returned y rows are only valid below their lengths.
     """
     b, l, _ = x.shape
-    di, n, h, p = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_head_dim
     W = cfg.ssm_conv_width
-    zxbcdt = x @ lp["in_proj"]
-    z, xbc, dt = _split_proj(cfg, zxbcdt)
+    z, xbc, dt = _in_proj(lp, x)
     if length is None:
         win = jnp.pad(xbc, ((0, 0), (W - 1, 0), (0, 0)))[:, l:, :].astype(conv_dtype)
     else:
@@ -222,10 +268,7 @@ def mamba_block_prefill(lp: Params, cfg: ModelConfig, x: jnp.ndarray, *,
         win = (jnp.take_along_axis(xbc, jnp.clip(idx, 0, l - 1)[:, :, None],
                                    axis=1) * live).astype(conv_dtype)
     xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp["conv_b"]))
-    xs = xbc[..., :di].reshape(b, l, h, p)
-    B = xbc[..., di:di + n]
-    C = xbc[..., di + n:]
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    xs, B, C, dt = _ssm_inputs(lp, cfg, xbc, dt)
     if length is not None:
         # dt = 0 AFTER softplus: pad steps decay by exp(0) = 1 and inject
         # dt * x = 0, so the state at the end equals the state at the row's
@@ -233,15 +276,10 @@ def mamba_block_prefill(lp: Params, cfg: ModelConfig, x: jnp.ndarray, *,
         dt = jnp.where(jnp.arange(l)[None, :, None] < lens[:, None, None],
                        dt, 0.0)
     A = -jnp.exp(lp["A_log"])
-    if use_kernel:
-        from repro.kernels import ops as kops
-        y, state = kops.ssd(xs, dt, A, B, C, chunk=cfg.ssm_chunk)
-    else:
-        y, state = ssd_chunked(xs, dt, A, B, C, chunk=cfg.ssm_chunk)
+    y, state = _ssd(cfg, xs, dt, A, B, C, use_kernel)
     y = y + lp["D"].astype(y.dtype)[None, None, :, None] * xs
-    y = y.reshape(b, l, di)
-    y = L.rmsnorm(lp["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return y @ lp["out_proj"], state, win
+    return (_gated_out(lp, cfg, y.reshape(b, l, cfg.ssm_d_inner), z),
+            state_to_cache(state), win)
 
 
 def mamba_block_step(lp: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -249,21 +287,13 @@ def mamba_block_step(lp: Params, cfg: ModelConfig, x: jnp.ndarray,
                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One-token mamba2 step.  x: (B, 1, D)."""
     b = x.shape[0]
-    di, n, h, p = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_head_dim
-    zxbcdt = x @ lp["in_proj"]
-    z, xbc, dt = _split_proj(cfg, zxbcdt)
+    z, xbc, dt = _in_proj(lp, x)
     xbc_t, conv_win = causal_conv_step(conv_win, xbc, lp["conv_w"], lp["conv_b"])
-    xbc_t = jax.nn.silu(xbc_t)
-    xs = xbc_t[:, 0, :di].reshape(b, h, p)
-    B = xbc_t[:, 0, di:di + n]
-    C = xbc_t[:, 0, di + n:]
-    dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + lp["dt_bias"])   # (b,h)
+    xs, B, C, dt = _ssm_inputs(lp, cfg, jax.nn.silu(xbc_t[:, 0]), dt[:, 0])
     A = -jnp.exp(lp["A_log"])
     y, state = ssd_recurrent_step(state, xs, dt, A, B, C)
     y = y + lp["D"].astype(y.dtype)[None, :, None] * xs
-    y = y.reshape(b, 1, di)
-    y = L.rmsnorm(lp["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return y @ lp["out_proj"], state, conv_win
+    return _gated_out(lp, cfg, y.reshape(b, 1, cfg.ssm_d_inner), z), state, conv_win
 
 
 # ---------------------------------------------------------------------------
@@ -308,10 +338,9 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=jnp.bfloat16):
     Constant-size in seq_len (the SSM advantage for long_500k)."""
     del seq_len
     h, p, n = cfg.ssm_nheads, cfg.ssm_head_dim, cfg.ssm_state
-    conv_dim = cfg.ssm_d_inner + 2 * n
     return {
-        "state": jnp.zeros((cfg.num_layers, batch, h, p, n), jnp.float32),
-        "conv": jnp.zeros((cfg.num_layers, batch, cfg.ssm_conv_width - 1, conv_dim),
+        "state": jnp.zeros((cfg.num_layers, batch, p, n, h), jnp.float32),
+        "conv": jnp.zeros((cfg.num_layers, batch, cfg.ssm_conv_width - 1, conv_dim(cfg)),
                           dtype),
         "pos": jnp.zeros((), jnp.int32),
     }
@@ -350,16 +379,15 @@ def init_paged_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
                      page_size: int, dtype=jnp.bfloat16):
     del num_pages, page_size
     h, p, n = cfg.ssm_nheads, cfg.ssm_head_dim, cfg.ssm_state
-    conv_dim = cfg.ssm_d_inner + 2 * n
     # int8 pool dtype only quantizes *paged KV*; the SSM has none, and its
     # recurrent carry must stay full-precision, so the conv window falls back
     # to bf16 (the state is always fp32).
     if jnp.dtype(dtype) == jnp.int8:
         dtype = jnp.bfloat16
     return {
-        "state": jnp.zeros((cfg.num_layers, num_slots, h, p, n), jnp.float32),
+        "state": jnp.zeros((cfg.num_layers, num_slots, p, n, h), jnp.float32),
         "conv": jnp.zeros((cfg.num_layers, num_slots, cfg.ssm_conv_width - 1,
-                           conv_dim), dtype),
+                           conv_dim(cfg)), dtype),
     }
 
 
@@ -369,13 +397,12 @@ def init_prefix_cache(cfg: ModelConfig, entries: int, dtype=jnp.bfloat16):
     hash.  The SSM state is constant-size, so one row restores the WHOLE
     prompt — the recurrent families' equivalent of aliasing every page."""
     h, p, n = cfg.ssm_nheads, cfg.ssm_head_dim, cfg.ssm_state
-    conv_dim = cfg.ssm_d_inner + 2 * n
     if jnp.dtype(dtype) == jnp.int8:
         dtype = jnp.bfloat16
     return {
-        "state": jnp.zeros((cfg.num_layers, entries, h, p, n), jnp.float32),
+        "state": jnp.zeros((cfg.num_layers, entries, p, n, h), jnp.float32),
         "conv": jnp.zeros((cfg.num_layers, entries, cfg.ssm_conv_width - 1,
-                           conv_dim), dtype),
+                           conv_dim(cfg)), dtype),
     }
 
 

@@ -282,16 +282,10 @@ def gather_chunk_slots(cfg: ModelConfig, cache, slots: jnp.ndarray):
     so the attention families pass the cache through; the recurrent families
     gather their per-slot state rows (sentinel rows gather-clamp harmlessly —
     their writes drop on the scatter side)."""
-    if cfg.family == SSM:
+    if cfg.family in (SSM, HYBRID):
+        from repro.models import layers as L
         return {"state": cache["state"][:, slots],
-                "conv": cache["conv"][:, slots]}
-    if cfg.family == HYBRID:
-        mini = {"state": cache["state"][:, :, slots],
-                "conv": cache["conv"][:, :, slots],
-                "kp": cache["kp"], "vp": cache["vp"]}
-        if "ks" in cache:
-            mini["ks"], mini["vs"] = cache["ks"], cache["vs"]
-        return mini
+                "conv": cache["conv"][:, slots], **L.paged_kv(cache)}
     return cache
 
 
@@ -302,51 +296,37 @@ def scatter_chunk_slots(cfg: ModelConfig, cache, mini, stage_sel,
     state scatters the SELECTED boundary snapshot (``select_stage`` over the
     lane's staged outputs — exactly ``chunk_keep`` inputs absorbed) at
     ``slots`` (sentinel == num_slots drops)."""
-    if cfg.family == SSM:
-        return dict(cache,
+    if cfg.family in (SSM, HYBRID):
+        from repro.models import layers as L
+        return dict(cache, **L.paged_kv(mini),
                     state=cache["state"].at[:, slots].set(
                         stage_sel["state"], mode="drop"),
                     conv=cache["conv"].at[:, slots].set(
                         stage_sel["conv"], mode="drop"))
-    if cfg.family == HYBRID:
-        merged = dict(cache, kp=mini["kp"], vp=mini["vp"],
-                      state=cache["state"].at[:, :, slots].set(
-                          stage_sel["state"], mode="drop"),
-                      conv=cache["conv"].at[:, :, slots].set(
-                          stage_sel["conv"], mode="drop"))
-        if "ks" in mini:
-            merged["ks"], merged["vs"] = mini["ks"], mini["vs"]
-        return merged
     return mini
 
 
 def chunk_stage(cfg: ModelConfig, cache):
     """The rollback-able (recurrent) slice of a paged cache — {} for the
     attention families, whose chunk state is positional."""
-    if cfg.family == SSM:
+    if cfg.family in (SSM, HYBRID):
         return mamba2.chunk_stage(cfg, cache)
-    if cfg.family == HYBRID:
-        return hybrid.chunk_stage(cfg, cache)
     return {}
 
 
 def restore_stage(cfg: ModelConfig, cache, stage, mask: jnp.ndarray):
     """Overwrite slots where ``mask`` (B,) is True with ``stage``'s recurrent
     state (no-op for the attention families)."""
-    if cfg.family == SSM:
+    if cfg.family in (SSM, HYBRID):
         return mamba2.restore_stage(cfg, cache, stage, mask)
-    if cfg.family == HYBRID:
-        return hybrid.restore_stage(cfg, cache, stage, mask)
     return cache
 
 
 def select_stage(cfg: ModelConfig, staged, keep: jnp.ndarray):
     """Per-slot chunk-boundary snapshot after exactly ``keep`` (B,) inputs
     (staged leaves carry a leading chunk axis; {} passes through)."""
-    if cfg.family == SSM:
+    if cfg.family in (SSM, HYBRID):
         return mamba2.select_stage(cfg, staged, keep)
-    if cfg.family == HYBRID:
-        return hybrid.select_stage(cfg, staged, keep)
     return {}
 
 
@@ -368,10 +348,8 @@ def init_prefix_cache(cfg: ModelConfig, entries: int, dtype=jnp.bfloat16):
     """Device-side prefix-cache rows: (E, V) fp32 last-position logits for
     every family, plus the families' own snapshot extras."""
     base = {"logits": jnp.zeros((entries, cfg.vocab_size), jnp.float32)}
-    if cfg.family == SSM:
+    if cfg.family in (SSM, HYBRID):
         base.update(mamba2.init_prefix_cache(cfg, entries, dtype))
-    elif cfg.family == HYBRID:
-        base.update(hybrid.init_prefix_cache(cfg, entries, dtype))
     elif cfg.family not in PAGED_FAMILIES:
         raise ValueError(
             f"prefix cache not supported for family {cfg.family!r}")
@@ -383,10 +361,8 @@ def snapshot_save(cfg: ModelConfig, cache, prefix, rows: jnp.ndarray,
     """Scatter admitted slots' post-prefill recurrent state into prefix-cache
     rows (rows (A,), sentinel == entries drops).  No-op for the attention
     families — their prompt state IS the retained pages."""
-    if cfg.family == SSM:
+    if cfg.family in (SSM, HYBRID):
         return mamba2.snapshot_save(cfg, cache, prefix, rows, slots)
-    if cfg.family == HYBRID:
-        return hybrid.snapshot_save(cfg, cache, prefix, rows, slots)
     return prefix
 
 
@@ -394,10 +370,8 @@ def snapshot_restore(cfg: ModelConfig, cache, prefix, rows: jnp.ndarray,
                      slots: jnp.ndarray):
     """Scatter prefix-cache rows into restored decode slots (slots (A,),
     sentinel == num_slots drops)."""
-    if cfg.family == SSM:
+    if cfg.family in (SSM, HYBRID):
         return mamba2.snapshot_restore(cfg, cache, prefix, rows, slots)
-    if cfg.family == HYBRID:
-        return hybrid.snapshot_restore(cfg, cache, prefix, rows, slots)
     return cache
 
 

@@ -147,6 +147,7 @@ class KVPool:
         # recurrent families (SSM, and the hybrid's mamba half) have no state
         # snapshot at mid-prompt boundaries, so they share whole prompts only
         self.partial_prefix = cfg.family in (DENSE, VLM, MOE)
+        self.recurrent = not self.partial_prefix
         self.cfg = cfg
         self.num_slots = num_slots
         self.page_size = page_size
@@ -208,9 +209,17 @@ class KVPool:
         self._page_index: Dict[bytes, _PageEntry] = {}
         self._full_index: Dict[bytes, _FullEntry] = {}
         self._row_free: List[int] = list(range(prefix_entries - 1, -1, -1))
+        # recurrent state held on the device: every slot's, plus the prefix
+        # rows' full-prompt snapshots of it
+        self.state_bytes = sum(
+            int(np.prod(b.shape)) * jnp.dtype(b.dtype).itemsize
+            for bufs in (self.buffers, self.prefix_buffers or {})
+            for k, b in bufs.items() if k in ("state", "conv"))
+        # snapshots_saved / _restored: admissions planned to write a prompt's
+        # recurrent state into a prefix row, and to restore a slot from one
         self.stats: Dict[str, int] = {
             "hits": 0, "full_hits": 0, "tokens_saved": 0, "cow_copies": 0,
-            "evictions": 0}
+            "evictions": 0, "snapshots_saved": 0, "snapshots_restored": 0}
 
     # -- allocator ----------------------------------------------------------
 
@@ -230,6 +239,7 @@ class KVPool:
             # Chrome-trace exporter tracks them as counter series)
             "kv_bytes_total": self.kv_bytes_total,
             "bytes_per_slot": self.bytes_per_slot,
+            "state_bytes": self.state_bytes,
             "kv_bits": 8 if self.kv_dtype == "int8" else 16,
         }
 
@@ -495,6 +505,8 @@ class KVPool:
                 row.append(self._pop_page(slot))
             plan.start = bucket
             plan.restore_row = fe.row
+            if self.recurrent:
+                self.stats["snapshots_restored"] += 1
             self.stats["full_hits"] += 1
             self.stats["hits"] += 1
             self.stats["tokens_saved"] += bucket
@@ -518,6 +530,8 @@ class KVPool:
             if register:
                 plan.save_row = self._reserve_full_entry(
                     full_hash, row, bucket, tick)
+                if self.recurrent and plan.save_row >= 0:
+                    self.stats["snapshots_saved"] += 1
         self.block[slot, :] = 0
         self.block[slot, : len(row)] = row
         return plan

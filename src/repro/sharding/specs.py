@@ -100,8 +100,8 @@ def _trailing_spec(path: Tuple[str, ...], shape: Tuple[int, ...], mesh: Mesh,
             return spec1(None)
 
     # ---- mamba2 ---------------------------------------------------------------
-    if name == "in_proj":       # (D, 2*di+2n+h): shard the mixed output dim is
-        return spec2(d_axis, None)   # unsafe (crosses z/x/B/C); FSDP rows only
+    if name in ("in_z", "in_xbc", "in_dt"):   # (D, di | di+2Gn | h): the
+        return spec2(d_axis, None)   # conv/SSM read whole rows; FSDP rows only
     if name == "out_proj":      # (di, D): di is head-major -> TP over model
         return spec2("model", d_axis)
     if name in ("conv_w", "conv_b", "A_log", "D", "dt_bias"):

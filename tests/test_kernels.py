@@ -77,8 +77,8 @@ def test_ssd_kernel_vs_chunked_ref(b, l, h, p, n, chunk):
     x = jnp.asarray(RNG.normal(size=(b, l, h, p)), jnp.float32)
     dt = jnp.asarray(RNG.random((b, l, h)), jnp.float32) * 0.5
     A = -jnp.asarray(RNG.random(h), jnp.float32) - 0.2
-    B = jnp.asarray(RNG.normal(size=(b, l, n)), jnp.float32)
-    C = jnp.asarray(RNG.normal(size=(b, l, n)), jnp.float32)
+    B = jnp.asarray(RNG.normal(size=(b, l, 1, n)), jnp.float32)
+    C = jnp.asarray(RNG.normal(size=(b, l, 1, n)), jnp.float32)
     y_k, hT_k = ops.ssd(x, dt, A, B, C, chunk=chunk)
     y_r, hT_r = ref.ssd_ref(x, dt, A, B, C, chunk=chunk)
     np.testing.assert_allclose(y_k, y_r, rtol=3e-4, atol=3e-4)
@@ -91,11 +91,28 @@ def test_ssd_chunked_matches_naive_recurrence():
     x = jnp.asarray(RNG.normal(size=(b, l, h, p)), jnp.float32)
     dt = jnp.asarray(RNG.random((b, l, h)), jnp.float32) * 0.5
     A = -jnp.asarray(RNG.random(h), jnp.float32) - 0.2
-    B = jnp.asarray(RNG.normal(size=(b, l, n)), jnp.float32)
-    C = jnp.asarray(RNG.normal(size=(b, l, n)), jnp.float32)
+    B = jnp.asarray(RNG.normal(size=(b, l, 1, n)), jnp.float32)
+    C = jnp.asarray(RNG.normal(size=(b, l, 1, n)), jnp.float32)
     y_r, _ = ref.ssd_ref(x, dt, A, B, C, chunk=16)
     y_n = ref.ssd_naive_ref(x, dt, A, B, C)
     np.testing.assert_allclose(np.asarray(y_r), np.asarray(y_n),
+                               rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("path", ["kernel", "chunked"])
+def test_ssd_groups_match_naive_recurrence(path):
+    """Two B/C groups, head j reading group j // (h / 2): the kernel and the
+    chunked jnp path both equal the recurrence."""
+    b, l, h, p, n, g = 1, 80, 4, 8, 8, 2
+    x = jnp.asarray(RNG.normal(size=(b, l, h, p)), jnp.float32)
+    dt = jnp.asarray(RNG.random((b, l, h)), jnp.float32) * 0.5
+    A = -jnp.asarray(RNG.random(h), jnp.float32) - 0.2
+    B = jnp.asarray(RNG.normal(size=(b, l, g, n)), jnp.float32)
+    C = jnp.asarray(RNG.normal(size=(b, l, g, n)), jnp.float32)
+    ssd = ops.ssd if path == "kernel" else ref.ssd_ref
+    y, _ = ssd(x, dt, A, B, C, chunk=16)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(ref.ssd_naive_ref(x, dt, A, B, C)),
                                rtol=2e-3, atol=2e-3)
 
 
@@ -105,8 +122,8 @@ def test_ssd_chunk_independence():
     x = jnp.asarray(RNG.normal(size=(b, l, h, p)), jnp.float32)
     dt = jnp.asarray(RNG.random((b, l, h)), jnp.float32) * 0.5
     A = -jnp.asarray(RNG.random(h), jnp.float32) - 0.5
-    B = jnp.asarray(RNG.normal(size=(b, l, n)), jnp.float32)
-    C = jnp.asarray(RNG.normal(size=(b, l, n)), jnp.float32)
+    B = jnp.asarray(RNG.normal(size=(b, l, 1, n)), jnp.float32)
+    C = jnp.asarray(RNG.normal(size=(b, l, 1, n)), jnp.float32)
     y16, _ = ops.ssd(x, dt, A, B, C, chunk=16)
     y64, _ = ops.ssd(x, dt, A, B, C, chunk=64)
     np.testing.assert_allclose(y16, y64, rtol=3e-4, atol=3e-4)

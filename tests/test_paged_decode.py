@@ -1,7 +1,8 @@
 """The paged decode pass carries each tier's stacked KV pools through its
 layer scan and updates them in place: bitwise the form that sliced each
 layer's pools in as scan inputs and stacked them back out (kept below as a
-reference), for every family with KV pages and both pool dtypes, and the
+reference; for the hybrid, its layers unrolled with every pool and state
+sliced out), for every family with KV pages and both pool dtypes, and the
 compiled scheduler tick holds no pool-sized temporary for it."""
 import re
 from functools import partial
@@ -15,7 +16,7 @@ from jax import lax
 from repro.configs.base import HIConfig, HYBRID, MOE
 from repro.configs.registry import ARCHS
 from repro.models import layers as L
-from repro.models import mamba2, model_zoo as zoo
+from repro.models import hybrid, mamba2, model_zoo as zoo
 from repro.models.moe import moe_ffn_auto
 from repro.models.transformer import layer_windows
 from repro.serving.engine import build_engine
@@ -38,20 +39,11 @@ def _xs_ys_decode(params, cfg, token, pos, block, cache, write_block):
                      {k: v[None] for k, v in kv.items()}, 0, window=win)
         return x + a, {k: v[0] for k, v in kv.items()}
 
-    def mamba(x, xs):
-        lp, st, cw = xs
-        y, st, cw = mamba2.mamba_block_step(
-            lp, cfg, L.rmsnorm(lp["ln"], x, cfg.norm_eps), st, cw)
-        return x + y, (st, cw)
+    if cfg.family == HYBRID:
+        return _hybrid_unrolled_decode(params, cfg, token, pos, block, cache,
+                                       write_block)
 
     def body(x, xs):
-        if cfg.family == HYBRID:
-            gp, st, cw, kv = xs
-            x, (st, cw) = lax.scan(mamba, x, (gp, st, cw))
-            sp = params["shared_attn"]
-            x, kv = attend(sp["attn"], sp["ln1"], x, kv, None)
-            x = x + L.swiglu(sp["mlp"], L.rmsnorm(sp["ln2"], x, cfg.norm_eps))
-            return x, (st, cw, kv)
         lp, win, kv = xs
         x, kv = attend(lp["attn"], lp["ln1"], x, kv, win)
         xn = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
@@ -63,19 +55,50 @@ def _xs_ys_decode(params, cfg, token, pos, block, cache, write_block):
                 y = y + L.swiglu(lp[extra], xn)
         return x + y, kv
 
-    kv = L.paged_kv(cache)
-    if cfg.family == HYBRID:
-        xs = (params["layers"], cache["state"], cache["conv"], kv)
-    else:
-        win = (jnp.full(cache["kp"].shape[:1], s_tot, jnp.int32)
-               if cfg.family == MOE else layer_windows(cfg, s_tot))
-        xs = (params["layers"], win, kv)
-    h, ys = lax.scan(body, params["embed"][token], xs)
+    win = (jnp.full(cache["kp"].shape[:1], s_tot, jnp.int32)
+           if cfg.family == MOE else layer_windows(cfg, s_tot))
+    h, ys = lax.scan(body, params["embed"][token],
+                     (params["layers"], win, L.paged_kv(cache)))
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
-    if cfg.family == HYBRID:
-        return logits, {"state": ys[0], "conv": ys[1], **ys[2]}
     return logits, {**cache, **ys}
+
+
+def _hybrid_unrolled_decode(params, cfg, token, pos, block, cache,
+                            write_block):
+    """The hybrid's layers one by one in Python: each application's pools and
+    each layer's recurrent state sliced out, and stacked back at the end."""
+    ids = list(cfg.hybrid_layer_ids)
+    e = params["embed"][token]
+    h = e
+    kv = L.paged_kv(cache)
+    new_kv = {k: [] for k in kv}
+    states, convs = [], []
+    for i in range(cfg.num_layers):
+        u = jnp.zeros_like(h)
+        if i in ids:
+            j = ids.index(i)
+            sp = params["shared"][j % cfg.num_mem_blocks]
+            t = L.rmsnorm(sp["ln1"], jnp.concatenate([h, e], -1), cfg.norm_eps)
+            a, kvj = L.attention_decode_paged(
+                sp["attn"], t, {k: v[j][None] for k, v in kv.items()}, 0,
+                block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
+                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+                write_block=write_block, q_scale=hybrid.Q_SCALE)
+            for k, v in kvj.items():
+                new_kv[k].append(v[0])
+            u = hybrid._shared_out(sp, params["apps"][j], cfg, a)
+        lp = jax.tree.map(lambda a: a[i], params["mamba"])
+        y, st, cw = mamba2.mamba_block_step(
+            lp, cfg, L.rmsnorm(lp["ln"], h + u, cfg.norm_eps),
+            cache["state"][i], cache["conv"][i])
+        h = h + y
+        states.append(st)
+        convs.append(cw)
+    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    logits = (h[:, 0, :] @ params["lm_head"]).astype(jnp.float32)
+    return logits, {"state": jnp.stack(states), "conv": jnp.stack(convs),
+                    **{k: jnp.stack(v) for k, v in new_kv.items()}}
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])

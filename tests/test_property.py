@@ -112,8 +112,8 @@ def test_ssd_chunked_equals_recurrence(b, l, h, p, n, seed):
     x = jnp.asarray(rng.normal(size=(b, l, h, p)), jnp.float32)
     dt = jnp.asarray(rng.random((b, l, h)), jnp.float32) * 0.5
     A = -jnp.asarray(rng.random(h), jnp.float32) - 0.3
-    B = jnp.asarray(rng.normal(size=(b, l, n)), jnp.float32)
-    C = jnp.asarray(rng.normal(size=(b, l, n)), jnp.float32)
+    B = jnp.asarray(rng.normal(size=(b, l, 1, n)), jnp.float32)
+    C = jnp.asarray(rng.normal(size=(b, l, 1, n)), jnp.float32)
     y_c, _ = ref.ssd_ref(x, dt, A, B, C, chunk=16)
     y_n = ref.ssd_naive_ref(x, dt, A, B, C)
     np.testing.assert_allclose(np.asarray(y_c), np.asarray(y_n),

@@ -1,6 +1,6 @@
 """Every Pallas kernel the serving tick can call, compiled for a TPU v5e at
-real widths (qwen2-1.5b's L tier and its s_variant(4) S tier; zamba2-2.7b's
-SSD heads) with ``interpret=False``.
+real widths (qwen2-1.5b's and zamba2-7b's L tiers and their s_variant(4) S
+tiers; zamba2-7b's grouped SSD heads) with ``interpret=False``.
 
 Nothing runs: the TPU compiler compiles for a described v5e:2x2 topology,
 which refuses what the chip would refuse (block shapes off the (8, 128)
@@ -19,7 +19,10 @@ from repro.kernels import hi_gate as hg
 from repro.kernels import ssd_scan as ssd
 
 QWEN = ARCHS["qwen2-1.5b"]
-TIERS = {"L": QWEN, "S": QWEN.s_variant(4)}
+ZAMBA = ARCHS["zamba2-7b"]
+TIERS = {"L": QWEN, "S": QWEN.s_variant(4),
+         # head_dim 224 is off the 128-lane tiling; 32 K/V heads
+         "zamba2-L": ZAMBA, "zamba2-S": ZAMBA.s_variant(4)}
 SLOTS, PAGE = 8, 16
 PAGES_PER_SLOT = -(-(512 + 32) // PAGE)      # longest bucket + max_new
 POOL_PAGES = 1 + SLOTS * PAGES_PER_SLOT      # + the null page 0
@@ -74,7 +77,7 @@ def _with_scales(kernel):
     return f
 
 
-@pytest.mark.parametrize("tier", ["L", "S"])
+@pytest.mark.parametrize("tier", sorted(TIERS))
 @pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
 def test_paged_decode_compiles(one_chip, tier, pool_dtype):
@@ -125,12 +128,14 @@ def test_hi_gate_compiles(one_chip, metric, rows):
 
 
 def test_ssd_chunk_compiles_zamba2(one_chip):
-    z = ARCHS["zamba2-2.7b"]
-    h, p, n, q = z.ssm_nheads, z.ssm_head_dim, z.ssm_state, z.ssm_chunk
-    nc = 4                                      # a 512-token prompt
+    """zamba2-7b's 112 heads of 64, state 64, chunk 256, two B/C groups."""
+    z = ZAMBA
+    h, p, n, q, g = (z.ssm_nheads, z.ssm_head_dim, z.ssm_state, z.ssm_chunk,
+                     z.ssm_ngroups)
+    nc = 512 // q                               # a 512-token prompt
     hlo = _compile(lambda x, dt, a, b, c: ssd.ssd_chunk_pallas(
         x, dt, a, b, c, interpret=False),
         one_chip, ((1, nc, q, h, p), jnp.float32), ((1, nc, q, h), jnp.float32),
-        ((h,), jnp.float32), ((1, nc, q, n), jnp.float32),
-        ((1, nc, q, n), jnp.float32))
+        ((h,), jnp.float32), ((1, nc, g, q, n), jnp.float32),
+        ((1, nc, g, q, n), jnp.float32))
     assert "tpu_custom_call" in hlo
