@@ -340,17 +340,19 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                       pos: jnp.ndarray, block: jnp.ndarray, cache: Params, *,
                       use_kernel: bool = False,
-                      write_block=None) -> Tuple[jnp.ndarray, Params]:
+                      write_block=None, rows=None) -> Tuple[jnp.ndarray, Params]:
     """One decode step for all slots at per-slot positions.  The stacked KV
     pools and the per-slot recurrent state ride in the layer walk's carry
-    and are updated in place."""
+    and are updated in place; ``rows`` (see
+    ``layers.attention_decode_paged``) narrows the shared blocks' K/V
+    reads."""
 
     def attend(ap, j, t, aux):
         a, kv = L.attention_decode_paged(
             ap, t, L.paged_kv(aux), j, block, pos, num_heads=cfg.num_heads,
             num_kv=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
             rope_theta=cfg.rope_theta, use_kernel=use_kernel,
-            write_block=write_block, q_scale=Q_SCALE)
+            write_block=write_block, q_scale=Q_SCALE, rows=rows)
         return a, dict(aux, **kv)
 
     h, cache = _walk(params, cfg, params["embed"][token], dict(cache), attend,

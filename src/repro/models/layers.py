@@ -328,7 +328,7 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
                            window: Optional[jnp.ndarray] = None,
                            use_kernel: bool = False,
                            write_block: Optional[jnp.ndarray] = None,
-                           q_scale: float = 1.0
+                           q_scale: float = 1.0, rows=None
                            ) -> Tuple[jnp.ndarray, Params]:
     """One-token decode of layer ``layer`` against a tier's STACKED paged KV
     pools, updated in place.
@@ -362,6 +362,10 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
     fused into the Pallas kernel under ``use_kernel``.  ``q_scale``: see
     :func:`rope_q`.
 
+    ``rows`` = (slots (B,), branch, widths): read only the rows that
+    :func:`over_rows` picks (the scheduler's occupied slots); the append
+    stays at every row, an idle slot's going to the null page.
+
     Returns (out (B,1,D), kv).
     """
     b = x.shape[0]
@@ -375,9 +379,8 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
     q = rope_q(q, pq, rope_theta, q_scale)
     if rope_theta > 0:
         k = apply_rope(k, pq, rope_theta)
-    rows = jnp.arange(b)
     wb = block if write_block is None else write_block
-    pg = wb[rows, pos // page]               # (B,) physical page of this token
+    pg = wb[jnp.arange(b), pos // page]      # (B,) physical page of this token
     off = pos % page
     # duplicate (page 0) targets from idle slots race benignly: the null page
     # is never covered by any slot's positional mask
@@ -397,21 +400,54 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
     valid = kpos <= pos[:, None]
     if window is not None:
         valid = valid & (pos[:, None] - kpos < window)
-    if use_kernel:
-        # the kernel streams one layer's pool: pass it that layer's slice
-        from repro.kernels import ops as kops
-        sk, sv = (None, None) if scale_k is None else \
-            (scale_k[layer], scale_v[layer])
-        out = kops.decode_attention_paged(q, pool_k[layer], pool_v[layer],
-                                          block, valid, sk, sv)
-    else:
+
+    def attend(q, block, valid):
+        if use_kernel:
+            # the kernel streams one layer's pool: pass it that layer's slice
+            from repro.kernels import ops as kops
+            sk, sv = (None, None) if scale_k is None else \
+                (scale_k[layer], scale_v[layer])
+            return kops.decode_attention_paged(q, pool_k[layer],
+                                               pool_v[layer], block, valid,
+                                               sk, sv)
         kk = dequant_gather(pool_k, scale_k, block, num_kv, head_dim,
                             layer=layer)
         vv = dequant_gather(pool_v, scale_v, block, num_kv, head_dim,
                             layer=layer)
-        out = _sdpa(q, kk, vv, valid[:, None, :])
+        return _sdpa(q, kk, vv, valid[:, None, :])
+
+    out = attend(q, block, valid) if rows is None else \
+        over_rows(attend, rows, q, block, valid)
     out = out.reshape(b, 1, num_heads * head_dim) @ params["wo"]
     return out, kv
+
+
+def over_rows(fn, rows, *args):
+    """``fn(*args)`` over a subset of the batch rows, at a width chosen at
+    run time.  ``rows`` = (slots (B,) int32, branch int32 scalar, widths):
+    ``widths`` is an ascending static tuple ending at B, ``slots`` lists
+    the rows to compute first and then the sentinel B.  A ``lax.switch`` on
+    ``branch`` runs ``fn`` on the first ``widths[branch]`` entries of
+    ``slots``, gathered (a pad entry reads zeros), and scatters the results
+    back into B rows (a pad's drops; rows not listed read zeros); the last
+    branch is ``fn`` over every row.  ``fn`` only reads what it closes over:
+    the page pools enter the switch read-only, so the caller's in-place pool
+    updates stay outside it."""
+    slots, branch, widths = rows
+    b = args[0].shape[0]
+
+    def at(w):
+        if w == b:
+            return lambda a: fn(*a)
+
+        def narrow(a):
+            sel = slots[:w]
+            out = fn(*(x.at[sel].get(mode="fill", fill_value=0) for x in a))
+            return jnp.zeros((b,) + out.shape[1:], out.dtype).at[sel].set(
+                out, mode="drop")
+        return narrow
+
+    return lax.switch(branch, [at(w) for w in widths], args)
 
 
 def attention_chunk_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,

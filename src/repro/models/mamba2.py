@@ -469,11 +469,11 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                       pos: jnp.ndarray, block: jnp.ndarray, cache: Params, *,
                       use_kernel: bool = False,
-                      write_block=None) -> Tuple[jnp.ndarray, Params]:
-    """One decode step for all slots.  The recurrent update is position-free,
-    so ``pos``/``block``/``write_block`` are unused — idle slots advance
-    garbage state that admission overwrites."""
-    del pos, block, use_kernel, write_block
+                      write_block=None, rows=None) -> Tuple[jnp.ndarray, Params]:
+    """One decode step for all slots.  The recurrent update is position-free
+    and reads no pages, so ``pos``/``block``/``write_block``/``rows`` are
+    unused — idle slots advance garbage state that admission overwrites."""
+    del pos, block, use_kernel, write_block, rows
     h = params["embed"][token]
 
     def body(carry, xs):

@@ -141,9 +141,10 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, cache, *,
 # recurrent SSM state is per-slot (constant-size — nothing to page).
 # ``prefill_paged`` admits ONE right-padded request into a slot at a FIXED
 # (1, S_max) shape (logits read at ``length - 1``), and ``decode_step_paged``
-# steps ALL slots at per-slot positions — together they are shape-independent
-# of the prompt bucket, which is what lets the continuous scheduler serve
-# every bucket from a single compiled executable.
+# steps ALL slots at per-slot positions (reading K/V for the listed ones) —
+# together they are shape-independent of the prompt bucket, which is what
+# lets the continuous scheduler serve every bucket from a single compiled
+# executable.
 
 PAGED_FAMILIES = (DENSE, VLM, MOE, SSM, HYBRID)
 
@@ -200,25 +201,32 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                       pos: jnp.ndarray, block: jnp.ndarray, cache, *,
-                      use_kernel: bool = False, write_block=None):
-    """One decode step for ALL slots.  token (B, 1); pos (B,) per-slot
-    positions; block (B, n_pages) block table; write_block masks shared
-    (read-only) pages out of the append path.  Returns (logits, cache)."""
+                      use_kernel: bool = False, write_block=None,
+                      rows=None):
+    """One decode step for every slot at per-slot positions.  token (B, 1);
+    pos (B,); block (B, n_pages) block table; write_block masks shared
+    (read-only) pages out of the append path.  ``rows`` = (slots, branch,
+    widths) narrows each attention layer's page gather and attention to
+    the listed slots — the scheduler's decode lane passes its occupied
+    slots, read at the narrowest width of its ladder (see
+    ``layers.over_rows``); ``None`` reads every slot.  Returns (logits,
+    cache)."""
     if cfg.family in (DENSE, VLM):
         return transformer.decode_step_paged(params, cfg, token, pos, block,
                                              cache, use_kernel=use_kernel,
-                                             write_block=write_block)
+                                             write_block=write_block,
+                                             rows=rows)
     if cfg.family == MOE:
         return moe.decode_step_paged(params, cfg, token, pos, block, cache,
                                      use_kernel=use_kernel,
-                                     write_block=write_block)
+                                     write_block=write_block, rows=rows)
     if cfg.family == SSM:
         return mamba2.decode_step_paged(params, cfg, token, pos, block, cache,
                                         write_block=write_block)
     if cfg.family == HYBRID:
         return hybrid.decode_step_paged(params, cfg, token, pos, block, cache,
                                         use_kernel=use_kernel,
-                                        write_block=write_block)
+                                        write_block=write_block, rows=rows)
     raise ValueError(
         f"decode_step_paged not supported for family {cfg.family!r}")
 

@@ -464,8 +464,10 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                       pos: jnp.ndarray, block: jnp.ndarray, cache: Params, *,
                       capacity_factor: float = 2.0, use_kernel: bool = False,
-                      write_block=None) -> Tuple[jnp.ndarray, Params]:
-    """One decode step for all slots at per-slot positions (paged pool)."""
+                      write_block=None, rows=None
+                      ) -> Tuple[jnp.ndarray, Params]:
+    """One decode step for all slots at per-slot positions (paged pool);
+    ``rows`` as in ``layers.attention_decode_paged``."""
     h = params["embed"][token]
     page = cache["kp"].shape[2]
     s_tot = block.shape[1] * page
@@ -478,7 +480,8 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
             lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), kv, li,
             block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-            window=win, use_kernel=use_kernel, write_block=write_block)
+            window=win, use_kernel=use_kernel, write_block=write_block,
+            rows=rows)
         x = x + a
         xn = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
         y, _ = moe_ffn_auto(lp, cfg, xn, capacity_factor)

@@ -442,14 +442,15 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                       pos: jnp.ndarray, block: jnp.ndarray, cache: Params, *,
                       use_kernel: bool = False,
-                      write_block: Optional[jnp.ndarray] = None
+                      write_block: Optional[jnp.ndarray] = None, rows=None
                       ) -> Tuple[jnp.ndarray, Params]:
     """One decode step for ALL slots at per-slot positions.
 
     token: (B, 1); pos: (B,) int32; block: (B, n_pages) int32; write_block:
     the append-side table with shared (read-only) pages masked to the null
-    page — see ``layers.attention_decode_paged``.  The stacked pools ride in
-    the layer scan's carry, so each layer updates them in place.
+    page; rows: the rows whose K/V each layer reads — see
+    ``layers.attention_decode_paged``.  The stacked pools ride in the layer
+    scan's carry, so each layer updates them in place.
     Returns (logits (B, V) fp32, cache)."""
     h = params["embed"][token]
     page = cache["kp"].shape[2]
@@ -463,7 +464,8 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
             lp["attn"], L.rmsnorm(lp["ln1"], x, cfg.norm_eps), kv, li,
             block, pos, num_heads=cfg.num_heads, num_kv=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-            window=win, use_kernel=use_kernel, write_block=write_block)
+            window=win, use_kernel=use_kernel, write_block=write_block,
+            rows=rows)
         x = x + a
         m = L.swiglu(lp["mlp"], L.rmsnorm(lp["ln2"], x, cfg.norm_eps))
         return (x + m, kv), None

@@ -37,9 +37,12 @@ tier, in order:
    chunk's live token count via the lane's boundary snapshots
    (``select_stage`` / ``scatter_chunk_slots``).
 4. **Draft/decode lane**: ``decode_block`` fused decode steps for every
-   decoding slot at per-slot positions (a ``lax.scan``); in speculative mode
-   this is the S tier's DRAFT, and each step also emits a chunk-boundary
-   state snapshot so the rejected tail can be rolled back.
+   decoding slot at per-slot positions (a ``lax.scan``).  It reads K/V for
+   the tier's occupied slots only: each attention layer gathers their pages
+   at the narrowest width of a ladder (:func:`decode_widths`) that holds
+   them, picked per tick by a runtime operand.  In speculative mode this is
+   the S tier's DRAFT, and each step also emits a chunk-boundary state
+   snapshot so the rejected tail can be rolled back.
 5. **Verify lane** (speculative, L tier only): ONE batched
    ``forward_chunk_paged`` over the S tier's freshly drafted block — the
    fused S→L token cascade.  Per slot: if the minimum per-token hi_gate
@@ -156,6 +159,7 @@ is exact only up to routing-drop determinism — with the generous decode-path
 """
 from __future__ import annotations
 
+import bisect
 import re
 import time
 import warnings
@@ -200,6 +204,16 @@ def _lane(tier: str, lane: str):
         yield
 
 
+def decode_widths(num_slots: int) -> tuple:
+    """The decode lane's width ladder for a tier of ``num_slots`` slots: a
+    quarter, a half and all of them, each rounded up to a multiple of 8 (a
+    sublane tile) and capped at ``num_slots``, without duplicates.  Each
+    tick reads K/V at the narrowest width that holds the tier's occupied
+    slots."""
+    return tuple(sorted({min(8 * -(-num_slots // (8 * d)), num_slots)
+                         for d in (4, 2, 1)}))
+
+
 def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
                   use_kernel: bool, decode_block: int, sharing: bool,
                   chunk: int = 0, role: str = "plain"):
@@ -207,7 +221,9 @@ def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
     prefix-cache save/restore + chunk-prefill lane + K fused draft/decode
     steps (or, for ``role == "spec_l"``, the fused verify chunk) of the
     ``tier`` ("S" or "L"), each lane under its ``<tier>/<lane>`` scope
-    (:data:`LANES`).
+    (:data:`LANES`).  The decode lane's attention reads K/V for the
+    occupied slots only, at the width the host picked from
+    :func:`decode_widths`.
 
     ``chunk``/``role`` are BUILD-time switches: with ``chunk == 0`` and
     ``role == "plain"`` the traced graph is exactly the PR-2/3 tick, which
@@ -399,11 +415,17 @@ def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
             return out, out_pool
 
         # ---- draft / decode scan (roles "plain" and "spec_s") ------------
+        # K/V is read only for the tier's occupied slots: each layer's
+        # page gather and attention run over the first w entries of the
+        # host's ``decode_slot`` list, w the narrowest width of the ladder
+        # that holds them (every other row's step reads nothing)
+        rows = (tin["decode_slot"], tin["decode_branch"], decode_widths(b))
+
         def body(carry, kk):
             last, core = carry
             logits, core = model_zoo.decode_step_paged(
                 params, cfg, last[:, None], tin["pos"] + kk, block, core,
-                use_kernel=use_kernel, write_block=wb)
+                use_kernel=use_kernel, write_block=wb, rows=rows)
             confs_k = conf_of(logits, theta)
             keys = sampler.request_keys(tin["seeds"], tin["tok_idx"] + kk)
             toks_k = sampler.sample(keys, logits, tin["temps"])
@@ -523,6 +545,8 @@ class _TierRuntime:
         self.chunk_left = np.zeros((num_slots,), np.int32)  # 0 = decoding
         self.admitted: List[int] = []    # slots admitted THIS tick, row order
         self.plans: List[AdmitPlan] = []  # aligned admission plans
+        self.widths = decode_widths(num_slots)
+        self.decode_rows = 0             # decode lane width of the last tick
 
     @property
     def busy(self) -> int:
@@ -697,6 +721,15 @@ class _TierRuntime:
             out["any_live"] = np.asarray(self.busy > 0)
             if self.spec:
                 out["draft_live"] = occupied
+        # the decode lane's rows: occupied slots first, then the sentinel,
+        # read at widths[decode_branch]
+        live = np.flatnonzero(occupied)
+        dslot = np.full((self.num_slots,), self.num_slots, np.int32)
+        dslot[: len(live)] = live
+        k = bisect.bisect_left(self.widths, len(live))
+        self.decode_rows = self.widths[k] if out["any_live"] else 0
+        out["decode_slot"] = dslot
+        out["decode_branch"] = np.asarray(k, np.int32)
         if not self.sharing:
             out["any_prefill"] = np.asarray(bool(self.admitted))
             return self._finish_inputs(out, raw)
@@ -1287,6 +1320,7 @@ class ContinuousScheduler:
             for k, v in rt.pool.gauges().items():
                 g[f"{k}@{rt.name}"] = v
             g[f"busy_slots@{rt.name}"] = rt.busy
+            g[f"decode_rows@{rt.name}"] = rt.decode_rows
         g["l_queue_depth"] = l_queue_len
         if self._mesh is not None:
             # transfer staging buffer: rows readable this tick (occupancy of
