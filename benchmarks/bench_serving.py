@@ -32,16 +32,8 @@ the chunk lane C tokens per tick (interleaved with decode) and the batched
 admit lane shrinks to one chunk's width — time-to-first-token p50/p99 across
 the whole trace is what's reported, plus req/s.
 
-The SPECULATIVE scenario measures the fused S→L draft-verify cascade on the
-calibrated ~25%-offload mixed trace: req/s speculative ON vs OFF, the draft
-acceptance rate, and the escalated-block fraction.  NOTE the reference
-models are random-init, so the S tier's drafts rarely match the L tier's
-choices (the measured ~12% acceptance is the structural floor: the agreed
-prefix of an escalated block).  Speculation's win scales with acceptance —
-i.e. with how well S approximates L on real checkpoints — so this scenario
-is primarily the acceptance-rate instrument; req/s on random weights is the
-worst case (every block pays draft + verify).  Results land in
-``BENCH_serving.json`` so the perf trajectory is tracked PR-over-PR.
+Results land in ``BENCH_serving.json`` so the perf trajectory is tracked
+PR-over-PR.
 
 The OUTAGE scenario measures fail-local resilience (``serving/faults.py``):
 the calibrated mixed trace is replayed with an L-tier outage window sized
@@ -391,42 +383,6 @@ def _bench_long_prompt(cfg, n: int, iters: int):
                         "unchunked": float(np.percentile(ttft_off, 50) * 1e3)},
         "ttft_p99_ms": {"chunked": float(np.percentile(ttft_on, 99) * 1e3),
                         "unchunked": float(np.percentile(ttft_off, 99) * 1e3)},
-    }
-
-
-def _bench_speculative(cfg, reqs, theta: float, iters: int):
-    """Fused draft-verify cascade vs the plain scheduler on the calibrated
-    mixed trace: req/s, draft acceptance rate, escalated-block fraction."""
-    hi = HIConfig(theta=theta, capacity_factor=1.0)
-    k = MAX_NEW - 1
-
-    def measure(spec: bool):
-        eng = build_engine(cfg, hi, max_new_tokens=MAX_NEW,
-                           cache_len=CACHE_LEN)
-        kw = dict(buckets=STREAM_BUCKETS, num_slots=NUM_SLOTS,
-                  l_slots=None if spec else NUM_SLOTS // 2,
-                  page_size=PAGE_SIZE, decode_block=k, speculative=spec)
-        eng.serve_stream(reqs, **kw)
-        times = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            eng.serve_stream(reqs, **kw)
-            times.append(time.perf_counter() - t0)
-        return min(times), eng._stream[1].stats
-
-    t_off, _ = measure(False)
-    t_on, stats = measure(True)
-    return {
-        "requests": len(reqs),
-        "buckets": list(STREAM_BUCKETS),
-        "draft_block": k,
-        "theta_calibrated": theta,
-        "speculative_rps": len(reqs) / t_on,
-        "non_speculative_rps": len(reqs) / t_off,
-        "speculative_speedup": t_off / t_on,
-        "draft_accept_rate": stats["accepted"] / max(stats["drafted"], 1),
-        "escalated_block_frac": stats["escalated_blocks"]
-        / max(stats["blocks"], 1),
     }
 
 
@@ -1130,9 +1086,6 @@ def run(out_path: str = "BENCH_serving.json", smoke: bool = False,
     # -- long-prompt admission: chunked prefill on vs off -------------------
     long_prompt = _bench_long_prompt(cfg, REQUESTS, iters)
 
-    # -- fused speculative S->L cascade vs plain scheduling -----------------
-    speculative = _bench_speculative(cfg, reqs, theta, iters)
-
     # -- L-tier outage: breaker -> fail-local -> recovery -------------------
     outage = _bench_outage(cfg, reqs, iters)
 
@@ -1179,7 +1132,6 @@ def run(out_path: str = "BENCH_serving.json", smoke: bool = False,
         },
         "repeated_prefix": repeated,
         "long_prompt": long_prompt,
-        "speculative": speculative,
         "outage": outage,
         "kv_quant": kv_quant,
         "telemetry": telemetry,
@@ -1217,7 +1169,6 @@ def run(out_path: str = "BENCH_serving.json", smoke: bool = False,
             "drain": result["mixed_poisson"]["drain_rps"],
             "prefix_sharing": repeated["sharing_rps"],
             "chunked_prefill": long_prompt["chunked_rps"],
-            "speculative": speculative["speculative_rps"],
             "outage": outage["outage_rps"],
             "kv_int8": kv_quant["int8_rps"],
             "mesh": mesh["mesh_rps"],
@@ -1252,13 +1203,6 @@ def run(out_path: str = "BENCH_serving.json", smoke: bool = False,
          f"{lp['ttft_p99_ms']['chunked']:.0f} vs "
          f"{lp['ttft_p99_ms']['unchunked']:.0f}ms); "
          f"{lp['chunked_rps']:.1f} vs {lp['unchunked_rps']:.1f} req/s")
-    sp = speculative
-    emit("serving_speculative", 0.0,
-         f"{sp['speculative_rps']:.1f} req/s fused draft-verify vs "
-         f"{sp['non_speculative_rps']:.1f} plain "
-         f"({sp['speculative_speedup']:.2f}x); accept rate "
-         f"{sp['draft_accept_rate']:.2f}, escalated-block frac "
-         f"{sp['escalated_block_frac']:.2f}")
     ot = outage
     emit("serving_outage", 0.0,
          f"L outage ticks {ot['outage_window_ticks']}: "

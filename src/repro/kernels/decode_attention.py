@@ -1,8 +1,8 @@
 """decode_attention — flash-style GQA attention over a PAGED KV pool.
 
 One kernel serves every attention read of the serving tick: a block of C
-query tokens per slot (C = 1 for decode, C = chunk for the chunk lane and
-the speculative verify pass) attends over the slot's pages, which the
+query tokens per slot (C = 1 for decode, C = chunk for the chunk lane)
+attends over the slot's pages, which the
 kernel DMAs straight out of the shared physical pool through the block
 table.  The contiguous-cache decode entry point is the same kernel over an
 identity block table (the cache viewed as one page run per row).

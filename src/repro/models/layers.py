@@ -461,8 +461,7 @@ def attention_chunk_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
                           scale_v: Optional[jnp.ndarray] = None):
     """CHUNK attention against the paged KV pool: C tokens per slot at
     per-slot start positions — the multi-token generalisation of
-    :func:`attention_decode_paged` that powers the unified chunked token lane
-    (chunked prefill admission and the speculative verify pass).
+    :func:`attention_decode_paged` that powers the chunked-prefill lane.
 
     x: (B, C, D); pos: (B,) int32 — token i of slot b sits at absolute
     position ``pos[b] + i``.  All C tokens' K/V are scattered into the slot's
@@ -538,7 +537,7 @@ def attention_chunk_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
 #     rounding), and a token at page offset 0 resets the (recycled) scale
 #     first since the page has no live content yet.
 # Scale rows are indexed by physical page id exactly like pages, so COW /
-# truncate / eviction move them with ``cow_copy_scales`` alongside
+# eviction move them with ``cow_copy_scales`` alongside
 # ``cow_copy_pages`` and the refcount machinery never needs to know about
 # quantization.
 

@@ -501,9 +501,8 @@ def forward_chunk_paged(params: Params, cfg: ModelConfig,
     sequential ``decode_step_paged`` calls — and every step's {state, conv}
     is emitted as a CHUNK-BOUNDARY SNAPSHOT (``staged``, leading axis C).
     ``staged[j]`` holds the state after consuming exactly ``j + 1`` chunk
-    inputs, which is what lets the scheduler roll a slot back to ANY
-    intra-chunk boundary (speculative rejection) or commit a partial final
-    prefill chunk (``select_stage`` + ``restore_stage``).
+    inputs, which is what lets the scheduler commit a partial final prefill
+    chunk (``select_stage`` + ``restore_stage``).
 
     Returns (logits (B, C, V) fp32, cache with the FULL chunk absorbed,
     staged)."""

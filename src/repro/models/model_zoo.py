@@ -241,14 +241,13 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
 # back.  ``decode_step_paged`` is the C = 1 special case; ``prefill_paged``
 # is the everything-at-once special case (kept as the batched admission
 # fast path).  The scheduler's chunked-prefill admission feeds long prompts
-# through this lane C tokens per tick, and the fused speculative cascade
-# uses it as the L tier's draft-verify pass.
+# through this lane C tokens per tick.
 #
-# Rollback contract: the attention families' chunk state is positional
-# (rewinding the host position shadows the rejected tail), so their
-# ``staged`` is empty; the recurrent families emit per-step chunk-boundary
-# snapshots in ``staged`` and the scheduler commits the accepted boundary
-# with ``select_stage`` + ``restore_stage``.
+# Boundary contract: the attention families' chunk state is positional (a
+# partial final chunk's padding sits past the host position, shadowed by
+# the mask), so their ``staged`` is empty; the recurrent families emit
+# per-step chunk-boundary snapshots in ``staged`` and the scheduler commits
+# the chunk's live token count with ``select_stage`` + ``restore_stage``.
 
 
 def forward_chunk_paged(params: Params, cfg: ModelConfig,

@@ -491,10 +491,9 @@ def forward_chunk_paged(params: Params, cfg: ModelConfig,
 
     tokens: (B, C) int32; pos: (B,) int32 per-slot start positions (token i
     of slot b lands at ``pos[b] + i``).  Per-position logits come back for
-    every chunk token — the speculative verify pass reads all of them, a
-    prefill chunk reads only its last live position.  Attention state is
-    entirely positional (rollback = rewind the host position), so the staged
-    snapshot dict is empty.  Returns (logits (B, C, V) fp32, cache, staged).
+    every chunk token; a prefill chunk reads only its last live position.
+    Attention state is entirely positional, so the staged snapshot dict is
+    empty.  Returns (logits (B, C, V) fp32, cache, staged).
     """
     h = params["embed"][tokens]
     page = cache["kp"].shape[2]

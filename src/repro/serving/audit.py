@@ -26,16 +26,12 @@ evaluation the scheduler absorbs becomes one :class:`AuditRecord`:
 * ``decision()`` records (rid, tier, traffic class, kind, confidence,
   theta-IN-EFFECT — i.e. ``FAIL_LOCAL_THETA`` while the circuit breaker is
   open — and the offload decision).  Kinds: ``admit`` / ``chunk`` /
-  ``decode`` (per-token gate evaluations), ``block`` (a speculative draft
-  block's min-confidence escalation decision), ``request`` (the
+  ``decode`` (per-token gate evaluations) and ``request`` (the
   request-level escalation decision at S-finish, which drives the per-class
   offload rate).
-* ``outcome()`` additionally carries ground truth ``ok``: in speculative
-  mode the L-verify lane re-derives every drafted position greedily, so
-  per-position accept/reject feedback is FREE every tick (kind ``draft``);
-  in plain mode each escalation that completes remotely yields one
-  agreement sample — did the S tokens match the L tokens? (kind
-  ``l_agree``).
+* ``outcome()`` additionally carries ground truth ``ok``: each escalation
+  that completes remotely yields one agreement sample — did the S tokens
+  match the L tokens? (kind ``l_agree``).
 
 Streaming aggregates (constant memory, besides the bounded ``records``
 ring):
@@ -50,7 +46,7 @@ ring):
 * **Offload rate per traffic class** (``Request.tclass``, default ``""``).
 * **Theta-margin histogram**: linear bins of ``conf - theta`` over [-1, 1]
   — how close the traffic runs to the gate.
-* **Empirical regret vs the verify-lane oracle**: per ground-truthed
+* **Empirical regret vs the L-agreement oracle**: per ground-truthed
   decision, the gate pays ``beta`` for an offload and ``1 - ok`` for a
   local serve; the oracle (which sees ``ok``) pays ``min(beta, 1 - ok)``.
   ``regret_cost`` accumulates the difference; ``wasted_offload`` /
@@ -85,12 +81,11 @@ class AuditRecord(NamedTuple):
     rid: int
     tier: str                 # "S" / "L"
     tclass: str               # Request.tclass traffic-class tag
-    kind: str                 # admit / chunk / decode / block / request /
-    #                           draft / l_agree
+    kind: str                 # admit / chunk / decode / request / l_agree
     conf: float
     theta: float              # theta IN EFFECT (FAIL_LOCAL_THETA when open)
     offload: bool             # the gate's decision at that theta
-    ok: Optional[bool] = None  # ground truth when the verify lane ran
+    ok: Optional[bool] = None  # ground truth of an ``outcome`` record
 
 
 class ReliabilityBins:
@@ -200,15 +195,11 @@ class GateAudit:
         self.margin_counts[min(max(i, 0), self.margin_bins - 1)] += 1
 
     def decision(self, *, rid: int, tier: str, tclass: str, kind: str,
-                 conf: float, theta: float,
-                 offload: Optional[bool] = None) -> None:
+                 conf: float, theta: float) -> None:
         """One gate evaluation.  ``theta`` is the threshold IN EFFECT for
-        the decision (``FAIL_LOCAL_THETA`` while the breaker is open).
-        ``offload`` defaults to ``conf < theta``; pass it explicitly where
-        the decision is not a plain comparison (e.g. the speculative
-        request-level roll-up)."""
-        if offload is None:
-            offload = conf < theta
+        the decision (``FAIL_LOCAL_THETA`` while the breaker is open); the
+        gate offloads when ``conf < theta``."""
+        offload = conf < theta
         self.decisions += 1
         self._margin(conf, theta)
         cs = self._class(tclass)
@@ -221,9 +212,9 @@ class GateAudit:
                                         bool(offload)))
 
     def outcome(self, *, rid: int, tier: str, tclass: str, conf: float,
-                theta: float, ok: bool, kind: str = "draft") -> None:
-        """One ground-truthed decision: the verify lane (kind ``draft``) or
-        a completed escalation's S/L agreement (kind ``l_agree``).  Feeds
+                theta: float, ok: bool, kind: str = "l_agree") -> None:
+        """One ground-truthed decision: a completed escalation's S/L
+        agreement (kind ``l_agree``).  Feeds
         the reliability bins, running ECE, and the empirical-regret
         counters."""
         ok = bool(ok)
@@ -314,10 +305,10 @@ class GateAudit:
             "Gate decisions recorded by the audit stream.")
         L.append(f"hi_audit_decisions_total {self.decisions}")
         fam("hi_audit_outcomes_total", "counter",
-            "Ground-truthed decisions (verify lane / L agreement).")
+            "Ground-truthed decisions (L agreement).")
         L.append(f"hi_audit_outcomes_total {self.outcomes}")
         fam("hi_audit_regret_total", "counter",
-            "Gate mistakes vs the verify-lane oracle, by kind.")
+            "Gate mistakes vs the L-agreement oracle, by kind.")
         L.append(f'hi_audit_regret_total{{kind="wasted_offload"}} '
                  f"{self.wasted_offload}")
         L.append(f'hi_audit_regret_total{{kind="missed_local"}} '

@@ -55,8 +55,8 @@ the ``serving/scheduler.py`` + ``serving/kv_pool.py`` subsystem:
 
 So the dispatch-count model becomes: ``serve()`` = 1 program per
 (batch, bucket); ``serve_stream()`` = 1 program per TICK, 1 compiled shape
-TOTAL (prefix sharing, chunked prefill, and the speculative cascade add
-runtime operands and build-time lanes, never a shape), with greedy outputs
+TOTAL (prefix sharing and chunked prefill add runtime operands and
+build-time lanes, never a shape), with greedy outputs
 token-identical to ``serve()`` on the same bucketized traffic — sharing on
 or off, chunking on or off (asserted by tests/test_scheduler.py,
 tests/test_prefix_cache.py, tests/test_chunk_lane.py).  Because the L
@@ -64,30 +64,17 @@ tier's pool and index persist across escalations, a re-escalated prompt
 skips the L prefill entirely — the HI analogue of not redoing work the S
 tier already paid for.
 
-Chunked token lane (``chunk_prefill`` / ``speculative``)
---------------------------------------------------------
-Both PR-5 features ride ONE primitive, ``model_zoo.forward_chunk_paged`` —
-a multi-token paged pass (C tokens per slot at per-slot positions, K/V
+Chunked token lane (``chunk_prefill``)
+--------------------------------------
+Chunked prefill rides ONE primitive, ``model_zoo.forward_chunk_paged`` — a
+multi-token paged pass (C tokens per slot at per-slot positions, K/V
 through the scalar-prefetched block table, intra-chunk causal masking) that
-generalises ``prefill_paged`` / ``decode_step_paged``:
-
-* ``chunk_prefill``: prompts longer than ``chunk_size`` skip the admit lane
-  and stream through a (chunk_width, chunk_size) chunk lane, C tokens per
-  tick, interleaved with every other slot's decode — the long-prompt TTFT
-  win measured by ``bench_serving.py``'s ``long_prompt`` scenario;
-* ``speculative``: the S→L token cascade fused into the tick (greedy-only;
-  temperature raises NotImplementedError).  The S tier DRAFTS
-  ``decode_block`` tokens per slot with per-token hi_gate confidences;
-  blocks whose min confidence clears theta are accepted at S-tier cost;
-  the rest get ONE batched L verify chunk with longest-prefix acceptance,
-  and the rejected tail rolls back in-tick (recurrent boundary snapshots +
-  positional rewind, ``KVPool.truncate`` guarding the rewind).  Decisions
-  and tokens match the host-driven ``token_cascade`` oracle
-  (tests/test_speculative.py); acceptance rate and req/s are measured by
-  the ``speculative`` bench scenario.
-
-Either way the tick stays ONE AOT-compiled executable with ONE host sync —
-``stats['stream_compiles']`` == 1 with everything enabled.
+generalises ``prefill_paged`` / ``decode_step_paged``: prompts longer than
+``chunk_size`` skip the admit lane and stream through a (chunk_width,
+chunk_size) chunk lane, C tokens per tick, interleaved with every other
+slot's decode — the long-prompt TTFT win measured by ``bench_serving.py``'s
+``long_prompt`` scenario.  The tick stays ONE AOT-compiled executable with
+ONE host sync — ``stats['stream_compiles']`` == 1 with everything enabled.
 
 Observability (``serving/telemetry.py``)
 ----------------------------------------
@@ -423,8 +410,8 @@ class HIEngine:
                      admit_width: int = None, decode_block: int = 4,
                      prefix_sharing: bool = True, prefix_entries: int = None,
                      chunk_prefill: bool = False, chunk_size: int = 8,
-                     chunk_width: int = 2, speculative: bool = False,
-                     kv_dtype: str = "bf16", mesh=None, faults=None,
+                     chunk_width: int = 2, kv_dtype: str = "bf16",
+                     mesh=None, faults=None,
                      retry=None, validate: bool = False, telemetry=None,
                      audit=None, watchdog=None,
                      flight_recorder=None) -> Dict[int, Dict[str, np.ndarray]]:
@@ -459,15 +446,7 @@ class HIEngine:
         per tick, interleaved with every other slot's decode, instead of
         monopolizing the admit lane (the long-prompt TTFT win measured by
         ``bench_serving.py``); greedy outputs are token-identical with
-        chunking on or off.  ``speculative`` fuses the S→L draft-verify token
-        cascade into the tick (``serving/token_cascade.py`` semantics, one
-        program): the S tier drafts ``decode_block`` tokens per slot, blocks
-        whose minimum hi_gate confidence clears theta are accepted at S-tier
-        cost, the rest get ONE batched L verify chunk with longest-prefix
-        acceptance and an in-tick rollback of the rejected tail.
-        Speculative acceptance is GREEDY-ONLY for now — any sampling
-        temperature raises NotImplementedError (rejection sampling is future
-        work).
+        chunking on or off.
 
         ``kv_dtype`` selects the page-pool storage format for both tiers:
         ``"bf16"`` (default, bitwise-identical to the unquantized build) or
@@ -522,9 +501,9 @@ class HIEngine:
 
         ``audit`` (a ``serving.audit.GateAudit``) installs the
         decision-quality layer with the same contract: every gate decision
-        the scheduler absorbs (admit / chunk / decode / block / request)
-        is recorded with its theta-IN-EFFECT, the speculative verify lane
-        and completed escalations feed ground-truth outcomes, and the
+        the scheduler absorbs (admit / chunk / decode / request) is
+        recorded with its theta-IN-EFFECT, completed escalations feed
+        ground-truth outcomes, and the
         streaming aggregates (reliability bins, per-``tclass`` ECE +
         offload rate, theta margins, empirical regret) ride the existing
         single host fetch — zero extra syncs, token-identical outputs.
@@ -540,24 +519,11 @@ class HIEngine:
         from repro.serving.scheduler import ContinuousScheduler
 
         requests = list(requests)
-        if speculative:
-            if self.temperature > 0:
-                raise NotImplementedError(
-                    "speculative serving is greedy-only: engine temperature "
-                    f"{self.temperature} > 0 requires rejection sampling "
-                    "(future work)")
-            hot = [r.request_id for r in requests if r.temperature > 0]
-            if hot:
-                raise NotImplementedError(
-                    "speculative serving is greedy-only: requests "
-                    f"{hot} set temperature > 0, which requires rejection "
-                    "sampling (future work)")
         mesh_key = None if mesh is None else (tuple(sorted(mesh.shape.items())),
                                               id(mesh))
         key = (tuple(sorted(buckets)), num_slots, l_slots, page_size,
                admit_width, decode_block, prefix_sharing, prefix_entries,
-               chunk_prefill, chunk_size, chunk_width, speculative, kv_dtype,
-               mesh_key)
+               chunk_prefill, chunk_size, chunk_width, kv_dtype, mesh_key)
         if self._stream is None or self._stream[0] != key:
             sched = ContinuousScheduler(
                 self.s, self.l, self.hi, max_prompt_len=max(buckets),
@@ -568,8 +534,7 @@ class HIEngine:
                 prefix_sharing=prefix_sharing,
                 prefix_entries=prefix_entries,
                 chunk_prefill=chunk_prefill, chunk_size=chunk_size,
-                chunk_width=chunk_width, speculative=speculative,
-                kv_dtype=kv_dtype, mesh=mesh)
+                chunk_width=chunk_width, kv_dtype=kv_dtype, mesh=mesh)
             self._stream = (key, sched)
             self.stats["stream_compiles"] += sched.stats["compiles"]
         sched = self._stream[1]

@@ -248,7 +248,7 @@ class KVPool:
         """Slots currently holding pages — empty after a clean drain.  The
         fault-path tests assert this: every abandoned escalation (lost,
         expired, outage-aborted, or in flight when the run drains) must have
-        released its pages through ``free``/``retract``."""
+        released its pages through ``free``."""
         return sorted(self._slot_pages)
 
     def pages_needed(self, context_len: int) -> int:
@@ -321,41 +321,6 @@ class KVPool:
 
     def owned(self, slot: int) -> List[int]:
         return list(self._slot_pages.get(slot, []))
-
-    def truncate(self, slot: int, length: int) -> None:
-        """Rewind guard for the speculative rollback: verify that moving
-        ``slot``'s write position back to ``length`` can never append into a
-        page another slot can SEE.  Every page of the slot's block row from
-        the one covering position ``length`` onward must be held by exactly
-        this one slot (``slot_refs == 1``) — a co-resident alias there would
-        mean the rolled-back decode could overwrite positions another request
-        reads, so this RAISES instead of proceeding (copy-on-write
-        territory; admission guarantees the decode region is freshly
-        allocated, making this pure defense in depth).  A prefix-INDEX
-        retention on the partial prompt-tail page is fine: index readers
-        only ever alias prompt offsets, restores copy-on-write before
-        appending, and the rewound writer (length > prompt) never touches
-        prompt offsets — the same invariant the normal append path relies
-        on.  Refcounts are unchanged: the slot keeps its allocation and the
-        stale tail contents are shadowed by the positional mask, exactly
-        like the drain path's discarded overrun steps."""
-        if not 0 <= slot < self.num_slots:
-            raise ValueError(
-                f"slot {slot} out of range (0..{self.num_slots - 1})")
-        if slot not in self._slot_pages:
-            raise ValueError(f"truncate: slot {slot} holds no pages")
-        if length < 0:
-            raise ValueError(f"truncate to negative length {length}")
-        held = self._slot_pages[slot]
-        first = length // self.page_size
-        for p in self.block[slot, first:]:
-            if p == 0:
-                continue
-            if self._slot_refs[p] != 1 or p not in held:
-                raise ValueError(
-                    f"truncate would rewind slot {slot} into shared page "
-                    f"{int(p)} (slot_refs={int(self._slot_refs[p])}): "
-                    f"copy-on-write required")
 
     # -- prefix index -------------------------------------------------------
 
@@ -535,28 +500,6 @@ class KVPool:
         self.block[slot, :] = 0
         self.block[slot, : len(row)] = row
         return plan
-
-    def retract(self, slot: int, page_hashes: Optional[Sequence[bytes]],
-                full_hash: Optional[bytes], tick: int) -> None:
-        """Undo the index registrations a SAME-TICK admission made, for an
-        admission that is being rolled back before its tick ran (paired
-        speculative admission where the partner tier failed).  Registered
-        entries become visible at ``tick + 1``; any entry for this prompt
-        still pending (``ready == tick + 1``) whose pages belong to ``slot``
-        was created by this admission — its pages will now never be written,
-        so it must not survive for a later lookup to alias garbage.  Entries
-        owned by a co-admitted identical prompt (different slot) are left
-        alone: their prefill still runs.  Call BEFORE ``free(slot)``."""
-        held = set(self._slot_pages.get(slot, ()))
-        for h in page_hashes or ():
-            e = self._page_index.get(h)
-            if e is not None and e.ready == tick + 1 and e.page in held:
-                self._drop_page_entry(h)
-        if full_hash is not None:
-            fe = self._full_index.get(full_hash)
-            if fe is not None and fe.ready == tick + 1 \
-                    and set(fe.pages) <= held:
-                self._drop_full_entry(full_hash)
 
     def _reserve_full_entry(self, full_hash: bytes, row: List[int],
                             bucket: int, tick: int) -> int:

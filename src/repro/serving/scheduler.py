@@ -36,28 +36,16 @@ tier, in order:
    the same tick.  Recurrent families commit their state to exactly the
    chunk's live token count via the lane's boundary snapshots
    (``select_stage`` / ``scatter_chunk_slots``).
-4. **Draft/decode lane**: ``decode_block`` fused decode steps for every
+4. **Decode lane**: ``decode_block`` fused decode steps for every
    decoding slot at per-slot positions (a ``lax.scan``).  It reads K/V for
    the tier's occupied slots only: each attention layer gathers their pages
    at the narrowest width of a ladder (:func:`decode_widths`) that holds
-   them, picked per tick by a runtime operand.  In speculative mode this is
-   the S tier's DRAFT, and each step also emits a chunk-boundary state
-   snapshot so the rejected tail can be rolled back.
-5. **Verify lane** (speculative, L tier only): ONE batched
-   ``forward_chunk_paged`` over the S tier's freshly drafted block — the
-   fused S→L token cascade.  Per slot: if the minimum per-token hi_gate
-   confidence over the draft clears theta the whole block is ACCEPTED at
-   S-tier cost; otherwise the L logits greedily re-derive each draft
-   position, the longest matching prefix is kept, the first divergence takes
-   the L token (the "bonus" correction), and the rejected tail is rolled
-   back — recurrent state via ``select_stage`` over the draft/verify
-   snapshots, attention state by rewinding the host position, with
-   ``KVPool.truncate`` asserting the rewind can never reach a shared page.
+   them, picked per tick by a runtime operand.
 
 The single host sync sits after ALL of the above: one ``_host_fetch`` of the
-tick's token/confidence/acceptance outputs.  ``stats['compiles']`` stays at 1
-no matter which lanes are enabled — chunking and speculation add operands and
-build-time lanes, never a shape.
+tick's token/confidence outputs.  ``stats['compiles']`` stays at 1 no matter
+which lanes are enabled — chunking adds operands and a build-time lane, never
+a shape.
 
 Prefix sharing (``prefix_entries > 0``) changes admission, not decode: see
 PR 3's notes.  Chunk-prefilled admissions still READ cached prefixes (they
@@ -68,9 +56,7 @@ The L-tier admission queue additionally enforces the time-constrained
 offloading drop policy (Fresa & Champati, arXiv:2112.11413): an escalation
 whose request has outlived its ``latency_budget`` is dropped — the S-tier
 answer stands, ``stats["dropped"]`` counts it, and the result record is
-flagged.  (Speculative mode has no L queue: every request is admitted to
-BOTH tiers at the same slot index, and escalation happens per token block
-inside the tick.)
+flagged.
 
 Failure semantics (``serving/faults.py``)
 -----------------------------------------
@@ -140,18 +126,14 @@ tick:
   the single-device path's in both ``kv_dtype`` modes
   (tests/test_mesh_serving.py).  Faults/retry/breaker machinery is
   host-side and unchanged (fault TIMING shifts by the DCN hop, as a real
-  hop would).  Speculative mode and ``use_kernel`` with ``model > 1`` are
-  explicitly rejected.
+  hop would).  ``use_kernel`` with ``model > 1`` is explicitly rejected.
 
 Outputs are TOKEN-IDENTICAL to the drain path on the same bucketized
 prompts, for ANY ``admit_width``/``decode_block``, with prefix sharing ON or
 OFF and chunked prefill ON or OFF (the chunk lane's per-position math is the
 decode step's — tests/test_chunk_lane.py asserts greedy-token identity per
 family, bitwise logits for the recurrent families whose chunk IS a scan of
-the per-token step).
-Speculative mode is greedy-only and matches the host-driven
-``token_cascade`` draft-verify oracle block for block
-(tests/test_speculative.py).  One caveat: MoE routed dispatch is
+the per-token step).  One caveat: MoE routed dispatch is
 batch-coupled (capacity drops depend on co-resident rows), so MoE equality
 is exact only up to routing-drop determinism — with the generous decode-path
 ``capacity_factor`` drops are absent on this reference; see
@@ -190,9 +172,8 @@ from repro.serving.telemetry import SchedCounters, StatsView, Telemetry
 # :meth:`ContinuousScheduler.lane_of_ops` reads back.  ``cow`` copy-on-write
 # page copies; ``admit`` the admission prefill with its gate, sampling and
 # first-token scatter; ``prefix`` the prefix-cache snapshot save/restore;
-# ``chunk`` the chunk-prefill lane; ``decode`` the fused decode (or draft)
-# scan; ``verify`` the speculative verify chunk (L only).
-LANES = ("cow", "admit", "prefix", "chunk", "decode", "verify")
+# ``chunk`` the chunk-prefill lane; ``decode`` the fused decode scan.
+LANES = ("cow", "admit", "prefix", "chunk", "decode")
 TIERS = ("S", "L")
 _OP_NAME = re.compile(
     r'^\s*(?:ROOT )?%?([^\s=]+) = .*metadata=\{[^}]*op_name="([^"]*)"')
@@ -216,18 +197,17 @@ def decode_widths(num_slots: int) -> tuple:
 
 def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
                   use_kernel: bool, decode_block: int, sharing: bool,
-                  chunk: int = 0, role: str = "plain"):
+                  chunk: int = 0):
     """Device-side per-tier tick: COW copies + batched cond-prefill +
-    prefix-cache save/restore + chunk-prefill lane + K fused draft/decode
-    steps (or, for ``role == "spec_l"``, the fused verify chunk) of the
-    ``tier`` ("S" or "L"), each lane under its ``<tier>/<lane>`` scope
+    prefix-cache save/restore + chunk-prefill lane + K fused decode steps of
+    the ``tier`` ("S" or "L"), each lane under its ``<tier>/<lane>`` scope
     (:data:`LANES`).  The decode lane's attention reads K/V for the
     occupied slots only, at the width the host picked from
     :func:`decode_widths`.
 
-    ``chunk``/``role`` are BUILD-time switches: with ``chunk == 0`` and
-    ``role == "plain"`` the traced graph is exactly the PR-2/3 tick, which
-    is what keeps greedy outputs bitwise stable with the new lanes off."""
+    ``chunk`` is a BUILD-time switch: with ``chunk == 0`` the chunk lane is
+    not traced at all, which keeps greedy outputs bitwise stable with it
+    off."""
 
     lane = partial(_lane, tier)
 
@@ -333,7 +313,7 @@ def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
         tok_c = sampler.sample(keys, logits_c, tin["chunk_temp"])
         return tok_c, conf_c, core
 
-    def tick(params, theta, tin, pool, draft=None):
+    def tick(params, theta, tin, pool):
         logits0, core, prefix = admit_and_prefix(params, tin, pool)
         with lane("admit"):
             conf0 = conf_of(logits0, theta)
@@ -356,65 +336,8 @@ def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
             out["chunk_conf"] = conf_c
         block = tin["block"]
         b = block.shape[0]
-        wb = tin["draft_wblock"] if chunk else \
+        wb = tin["decode_wblock"] if chunk else \
             (tin["wblock"] if sharing else None)
-
-        if role == "spec_l":
-            # ---- fused verify chunk over the S tier's drafts -------------
-            k = decode_block
-            toks = draft["toks"]                      # (K, B) S drafts
-            confs = draft["confs"]                    # (K, B) hi_gate confs
-            vin = jnp.concatenate([draft["last0"][None], toks[:-1]], 0).T
-
-            def verify(core):
-                pre = model_zoo.chunk_stage(cfg, core)
-                vlog, core, staged = model_zoo.forward_chunk_paged(
-                    params, cfg, vin, tin["pos"], block, core,
-                    use_kernel=use_kernel, write_block=wb)
-                # greedy-only acceptance (serve_stream raises on temp > 0)
-                lv = jnp.argmax(vlog, -1).astype(jnp.int32)      # (B, K)
-                live = tin["draft_live"]
-                esc = (confs.min(axis=0) < theta) & live
-                match = lv == toks.T
-                m = jnp.where(match.all(axis=1), k,
-                              jnp.argmax(~match, axis=1)).astype(jnp.int32)
-                accept = jnp.where(esc, m, k)        # drafts kept
-                keep = jnp.where(esc, jnp.minimum(m + 1, k), k)  # inputs kept
-                core = model_zoo.restore_stage(cfg, core, pre, ~live)
-                sel = model_zoo.select_stage(cfg, staged, keep)
-                core = model_zoo.restore_stage(cfg, core, sel, live)
-                cols = jnp.arange(k)[None, :]
-                bonus = esc[:, None] & (cols == m[:, None])
-                out_toks = jnp.where(bonus, lv, toks.T)
-                out_confs = jnp.where(bonus, 1.0, confs.T)  # L-verified token
-                n_emit = jnp.where(esc & (m < k), m + 1, k)
-                return (out_toks, out_confs, keep, accept, esc, n_emit,
-                        match, core)
-
-            def v_idle(core):
-                return (jnp.zeros((b, k), jnp.int32),
-                        jnp.zeros((b, k), jnp.float32),
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.zeros((b,), bool),
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.zeros((b, k), bool), core)
-
-            with lane("verify"):
-                (out_toks, out_confs, keep, accept, esc, n_emit, match,
-                 core) = jax.lax.cond(tin["any_live"], verify, v_idle, core)
-            out.update({"toks": out_toks, "confs": out_confs, "keep": keep,
-                        "accept": accept, "esc": esc, "n_emit": n_emit,
-                        # verify-lane ground truth for the gate audit: raw
-                        # per-position L accept/reject and the S draft confs
-                        # (out_confs overwrites the bonus position to 1.0,
-                        # which would poison calibration bins)
-                        "match": match, "draft_confs": confs.T})
-            out_pool = {"core": core, "prefix": prefix} if sharing \
-                else {"core": core}
-            return out, out_pool
-
-        # ---- draft / decode scan (roles "plain" and "spec_s") ------------
         # K/V is read only for the tier's occupied slots: each layer's
         # page gather and attention run over the first w entries of the
         # host's ``decode_slot`` list, w the narrowest width of the ladder
@@ -429,44 +352,31 @@ def _tier_tick_fn(tier: str, cfg: ModelConfig, metric: str,
             confs_k = conf_of(logits, theta)
             keys = sampler.request_keys(tin["seeds"], tin["tok_idx"] + kk)
             toks_k = sampler.sample(keys, logits, tin["temps"])
-            ys = (toks_k, confs_k)
-            if role == "spec_s":
-                ys = ys + (model_zoo.chunk_stage(cfg, core),)
-            return (toks_k, core), ys
+            return (toks_k, core), (toks_k, confs_k)
 
         def decode(core):
             pre_d = model_zoo.chunk_stage(cfg, core) if chunk else None
-            (_, core), ys = jax.lax.scan(body, (last0, core),
-                                         jnp.arange(decode_block))
+            (_, core), (toks, confs) = jax.lax.scan(
+                body, (last0, core), jnp.arange(decode_block))
             if chunk:
-                # slots still mid chunk-prefill took garbage draft steps:
+                # slots still mid chunk-prefill took garbage decode steps:
                 # their page writes were null-masked, restore their state
                 core = model_zoo.restore_stage(cfg, core, pre_d,
-                                               ~tin["draft_live"])
-            if role == "spec_s":
-                toks, confs, staged = ys
-            else:
-                (toks, confs), staged = ys, {}
-            return toks, confs, staged, core
+                                               ~tin["decode_live"])
+            return toks, confs, core
 
         def idle(core):
             # this tier has no decoding slots this tick (e.g. everything is
             # still chunk-prefilling): skip the scan entirely
-            staged = jax.tree.map(
-                lambda a: jnp.zeros((decode_block,) + a.shape, a.dtype),
-                model_zoo.chunk_stage(cfg, core)) if role == "spec_s" else {}
             return (jnp.zeros((decode_block, b), jnp.int32),
-                    jnp.zeros((decode_block, b), jnp.float32), staged, core)
+                    jnp.zeros((decode_block, b), jnp.float32), core)
 
         with lane("decode"):
-            toks, confs, staged, core = jax.lax.cond(tin["any_live"],
-                                                     decode, idle, core)
+            toks, confs, core = jax.lax.cond(tin["any_live"], decode, idle,
+                                             core)
         out.update({"toks": toks, "confs": confs})       # toks (K, B)
         out_pool = {"core": core, "prefix": prefix} if sharing \
             else {"core": core}
-        if role == "spec_s":
-            return out, out_pool, {"staged": staged, "toks": toks,
-                                   "confs": confs, "last0": last0}
         return out, out_pool
 
     return tick
@@ -479,7 +389,6 @@ class _Active:
     steps: int
     tokens: List[int] = field(default_factory=list)
     confs: List[float] = field(default_factory=list)
-    rounds: List = field(default_factory=list)   # spec: (escalated, n_emit)
     first_tok: float = 0.0                       # monotonic first-emit time
     hit_eos: bool = False
 
@@ -510,8 +419,8 @@ class _TierRuntime:
                  page_size: int, admit_width: int, dtype,
                  prefix_entries: int = 0, max_prompt_len: int = 0,
                  num_pages: Optional[int] = None, chunk_size: int = 0,
-                 chunk_width: int = 2, spec: bool = False,
-                 name: str = "S", alloc: bool = True):
+                 chunk_width: int = 2, name: str = "S",
+                 alloc: bool = True):
         if num_pages is None:
             # sharing headroom: beyond every slot's full context, enough
             # pages to RETAIN prefix_entries full prompts without evicting
@@ -533,7 +442,6 @@ class _TierRuntime:
         self.chunk_size = chunk_size
         self.chunk_width = max(1, min(chunk_width, num_slots))
         self.chunk_sched: List = []    # (slot, keep, fin) rows THIS tick
-        self.spec = spec
         self.default_temp = 0.0      # engine-level fallback (Request wins)
         self.slot_req: List[Optional[_Active]] = [None] * num_slots
         self.last_tok = np.zeros((num_slots,), np.int32)
@@ -574,7 +482,7 @@ class _TierRuntime:
         if slot is None:
             return None
         chunked = bool(self.chunk_size) and adm.bucket > self.chunk_size
-        if self.sharing and not (chunked and self.spec):
+        if self.sharing:
             plan = self.pool.admit_prefix(slot, context, adm.bucket,
                                           adm.page_hashes, adm.full_hash,
                                           tick, register=not chunked)
@@ -582,16 +490,6 @@ class _TierRuntime:
                 return None
             if plan.is_restore:
                 chunked = False          # full hit: restoring beats chunking
-        elif self.sharing:
-            # speculative pairing: both tiers must chunk in LOCK-STEP (the
-            # verify lane gates on a shared readiness), so chunk admissions
-            # skip per-tier prefix hits — a hit in one tier's index but not
-            # the other's would desynchronise the pair's prefill progress
-            try:
-                self.pool.alloc(slot, context, tick=tick)
-            except ValueError:
-                return None
-            plan = AdmitPlan(slot=slot)
         else:
             if not self.pool.can_alloc(context):
                 return None
@@ -660,7 +558,6 @@ class _TierRuntime:
             "admit_seed": seeds,
             "admit_temp": temps,
         }
-        occupied = np.asarray([r is not None for r in self.slot_req])
         if self.chunk_size:
             c, w = self.chunk_size, self.chunk_width
             npg = self.pool.n_pages_per_slot
@@ -712,18 +609,16 @@ class _TierRuntime:
                 "chunk_wblock": cwb,
                 "chunk_seed": cseed,
                 "chunk_temp": ctemp,
-                "draft_live": dlive,
-                "draft_wblock": np.where(dlive[:, None], base,
-                                         0).astype(np.int32),
+                "decode_live": dlive,
+                "decode_wblock": np.where(dlive[:, None], base,
+                                          0).astype(np.int32),
             })
             out["any_live"] = np.asarray(bool(dlive.any()))
         else:
             out["any_live"] = np.asarray(self.busy > 0)
-            if self.spec:
-                out["draft_live"] = occupied
         # the decode lane's rows: occupied slots first, then the sentinel,
         # read at widths[decode_branch]
-        live = np.flatnonzero(occupied)
+        live = np.flatnonzero([r is not None for r in self.slot_req])
         dslot = np.full((self.num_slots,), self.num_slots, np.int32)
         dslot[: len(live)] = live
         k = bisect.bisect_left(self.widths, len(live))
@@ -794,16 +689,13 @@ class ContinuousScheduler:
 
     One instance = one AOT-compiled tick executable (``stats['compiles']``
     stays at 1 no matter how many prompt buckets flow through — the paged
-    pool removed the bucket from every device shape, and prefix sharing,
-    chunked prefill, and the speculative cascade add only runtime operands
-    and build-time lanes).  ``admit_width`` batches admission prefills like
-    the drain path batches prompts; ``decode_block`` fuses that many decode
-    steps per tick (and is the speculative DRAFT length k).
+    pool removed the bucket from every device shape, and prefix sharing
+    and chunked prefill add only runtime operands and build-time lanes).
+    ``admit_width`` batches admission prefills like the drain path batches
+    prompts; ``decode_block`` fuses that many decode steps per tick.
     ``prefix_sharing`` turns on the pool's content-addressed prefix reuse.
     ``chunk_prefill`` routes prompts longer than ``chunk_size`` through the
-    chunk lane (C tokens per tick, interleaved with decode).  ``speculative``
-    fuses the S→L draft-verify token cascade into the tick (greedy-only;
-    both tiers admit every request at the same slot index).
+    chunk lane (C tokens per tick, interleaved with decode).
     ``kv_dtype="int8"`` stores both tiers' KV pages quantized (int8 with
     per-page-per-head scales, dequantization fused into the page-gather
     kernels) at roughly half the pool bytes; the default ``"bf16"`` keeps
@@ -822,8 +714,8 @@ class ContinuousScheduler:
       terminal`` with the terminal ``status``, TTFT, TPOT, queue-wait
       ticks, and retry counts
       (terminal hooks sit exactly where records reach their FINAL status:
-      ``_finish_s`` un-escalated, ``_finish_l``, ``_finish_spec``,
-      ``_degrade``, ``_drop_expired``, ``_reject``);
+      ``_finish_s`` un-escalated, ``_finish_l``, ``_degrade``,
+      ``_drop_expired``, ``_reject``);
     * per-tick wall-time phases (``fault_tick`` — fault machinery + slot
       admission, ``build_operands``, ``dispatch``, ``host_fetch``,
       ``postprocess``, ``observe``), each also a ``sched.<phase>``
@@ -849,8 +741,7 @@ class ContinuousScheduler:
                  prefix_entries: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  chunk_prefill: bool = False, chunk_size: int = 8,
-                 chunk_width: int = 2, speculative: bool = False,
-                 kv_dtype: str = "bf16", mesh=None):
+                 chunk_width: int = 2, kv_dtype: str = "bf16", mesh=None):
         if max_prompt_len % page_size:
             raise ValueError(f"max_prompt_len {max_prompt_len} must be a "
                              f"multiple of page_size {page_size}")
@@ -866,11 +757,6 @@ class ContinuousScheduler:
         if chunk_prefill and chunk_size < 1:
             raise ValueError(f"chunk_size {chunk_size} must be >= 1")
         if mesh is not None:
-            if speculative:
-                raise NotImplementedError(
-                    "speculative + mesh: the fused draft-verify cascade "
-                    "pairs S and L slots 1:1, which a replicated S tier "
-                    "breaks (same precedent as speculative + faults)")
             for ax in ("data", "model"):
                 if ax not in mesh.shape:
                     raise ValueError(
@@ -890,12 +776,8 @@ class ContinuousScheduler:
         self.max_new_tokens = max_new_tokens
         self.decode_block = max(1, decode_block)
         self.prefix_sharing = prefix_sharing
-        self.speculative = speculative
         self.chunk = int(chunk_size) if chunk_prefill else 0
-        if speculative:
-            l_slots = num_slots          # strict 1:1 slot pairing
-        else:
-            l_slots = l_slots if l_slots is not None else max(2, num_slots // 2)
+        l_slots = l_slots if l_slots is not None else max(2, num_slots // 2)
         admit_width = admit_width if admit_width is not None else num_slots
         page = page_size
         raw_ctx = max_prompt_len + max_new_tokens + self.decode_block - 1
@@ -911,18 +793,16 @@ class ContinuousScheduler:
                          prefix_entries=s_entries,
                          max_prompt_len=max_prompt_len,
                          num_pages=num_pages, chunk_size=self.chunk,
-                         chunk_width=chunk_width, spec=speculative,
+                         chunk_width=chunk_width,
                          name="S" if mesh is None else f"S{r}",
                          alloc=mesh is None)
             for r in range(n_rep)]
         self.lrt = _TierRuntime(l_tier.cfg, l_slots, max_context, page,
-                                admit_width if speculative
-                                else min(admit_width, l_slots), cache_dtype,
+                                min(admit_width, l_slots), cache_dtype,
                                 prefix_entries=l_entries,
                                 max_prompt_len=max_prompt_len,
                                 num_pages=num_pages, chunk_size=self.chunk,
-                                chunk_width=chunk_width, spec=speculative,
-                                name="L")
+                                chunk_width=chunk_width, name="L")
         self.set_default_temperature(temperature)
         # with chunking on (and no prefix hits routing long prompts back to
         # the admit lane), every admit-lane prompt is <= chunk_size: the
@@ -967,37 +847,17 @@ class ContinuousScheduler:
         self._stage_tokens = np.zeros((0, 0), np.int32)
         self._stage_wix = 0
 
-        s_role = "spec_s" if speculative else "plain"
-        l_role = "spec_l" if speculative else "plain"
         s_tick = _tier_tick_fn("S", s_tier.cfg, hi.metric, use_kernel,
                                self.decode_block, self.srt.sharing,
-                               chunk=self.chunk, role=s_role)
+                               chunk=self.chunk)
         l_tick = _tier_tick_fn("L", l_tier.cfg, hi.metric, use_kernel,
                                self.decode_block, self.lrt.sharing,
-                               chunk=self.chunk, role=l_role)
+                               chunk=self.chunk)
 
-        if speculative:
-            s_cfg = s_tier.cfg
-
-            def tick(s_params, l_params, theta, s_in, l_in, s_pool, l_pool):
-                s_out, s_pool, s_ext = s_tick(s_params, theta, s_in, s_pool)
-                l_out, l_pool = l_tick(l_params, theta, l_in, l_pool,
-                                       draft=s_ext)
-                # roll the S tier back to the accepted boundary: recurrent
-                # state via the draft's per-step snapshots; attention state
-                # is positional (the host rewinds pos)
-                with _lane("L", "verify"):
-                    sel = model_zoo.select_stage(s_cfg, s_ext["staged"],
-                                                 l_out["keep"])
-                    core = model_zoo.restore_stage(s_cfg, s_pool["core"],
-                                                   sel, s_in["draft_live"])
-                s_pool = dict(s_pool, core=core)
-                return {"s": s_out, "l": l_out}, s_pool, l_pool
-        else:
-            def tick(s_params, l_params, theta, s_in, l_in, s_pool, l_pool):
-                s_out, s_pool = s_tick(s_params, theta, s_in, s_pool)
-                l_out, l_pool = l_tick(l_params, theta, l_in, l_pool)
-                return {"s": s_out, "l": l_out}, s_pool, l_pool
+        def tick(s_params, l_params, theta, s_in, l_in, s_pool, l_pool):
+            s_out, s_pool = s_tick(s_params, theta, s_in, s_pool)
+            l_out, l_pool = l_tick(l_params, theta, l_in, l_pool)
+            return {"s": s_out, "l": l_out}, s_pool, l_pool
 
         spec = partial(jax.tree.map,
                        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype))
@@ -1148,11 +1008,6 @@ class ContinuousScheduler:
         each ``run`` re-anchors tick 0, so a schedule replays identically on
         a reused scheduler)."""
         if faults is not None:
-            if self.speculative and faults.active:
-                raise ValueError(
-                    "fault injection models the S->L escalation QUEUE; "
-                    "speculative mode has no L queue (escalation is fused "
-                    "into the tick)")
             self.faults = faults
         if policy is not None:
             self.policy = policy
@@ -1389,8 +1244,8 @@ class ContinuousScheduler:
         """Drain ``queue`` through the slots; returns per-request records
         keyed by request_id: tokens / s_tokens / confidence / offloaded /
         served_remote / dropped / status / escalation_retries /
-        queue_wait_ticks / ttft (mirroring ``HIEngine.serve``'s fields, plus
-        the speculative block accounting when enabled).  Every submitted
+        queue_wait_ticks / ttft (mirroring ``HIEngine.serve``'s fields).
+        Every submitted
         request terminates with exactly one record whose ``status`` is one of
         ``faults.STATUSES`` — under ANY fault schedule installed via
         :meth:`set_faults`.  Ticks that only advance host-side timers
@@ -1400,7 +1255,7 @@ class ContinuousScheduler:
 
         ``stats['serve_time']`` accounting is SINGLE-ENTRY: one
         ``try/finally`` brackets the whole drain, so every exit path (normal
-        completion, the speculative early return, the stall RuntimeError)
+        completion, the stall RuntimeError)
         adds the elapsed time exactly once — the old per-path additions
         could in principle double-book (tests/test_telemetry.py regresses
         this)."""
@@ -1418,21 +1273,6 @@ class ContinuousScheduler:
         tel = self.tel
         self._run_theta = theta
         self._eff_theta = theta
-
-        if self.speculative:
-            while len(queue) or self.srt.busy:
-                if tel is not None:   # admission bookkeeping bucket
-                    tel.begin_tick(self.counters.ticks, "fault_tick")
-                self._try_admit_spec(queue, results)
-                if tel is not None:
-                    tel.phase("build_operands")
-                host = self._dispatch(theta_j)
-                self._absorb_spec(host, results)
-                if tel is not None:
-                    tel.phase("observe")
-                self._observe_tick()
-            return results
-
         # per-run fault state: run-relative tick 0 anchors here, so a seeded
         # FaultSchedule replays identically on a reused scheduler
         theta_fail_j = jnp.asarray(FAIL_LOCAL_THETA, jnp.float32)
@@ -1729,53 +1569,6 @@ class ContinuousScheduler:
                                       chunked=bool(rt.chunk_left[slot]))
             admitted += 1
 
-    def _try_admit_spec(self, queue, results: Dict) -> None:
-        """Speculative admission: both tiers claim the SAME slot index for a
-        request (strict pairing — the verify chunk addresses the L pool by
-        the S slot's id), prefill both caches through their admit lanes."""
-        srt, lrt = self.srt, self.lrt
-        srt.admitted, srt.plans = [], []
-        lrt.admitted, lrt.plans = [], []
-        tick = self.counters.ticks
-        admitted = 0
-        while admitted < srt.admit_width and len(queue):
-            slot = srt.free_slot()
-            if slot is None:
-                break
-            assert lrt.slot_req[slot] is None, "spec slot pairing broken"
-            adm = queue.popleft()
-            steps = min(adm.request.max_new_tokens, self.max_new_tokens)
-            if srt.admit(adm, steps, self.decode_block, tick) is None:
-                adm.admit_retries += 1
-                if adm.admit_retries > self.policy.admit_retry_limit:
-                    self._reject(adm, results)
-                    continue
-                queue.appendleft(adm)
-                break
-            if lrt.admit(adm, steps, self.decode_block, tick) is None:
-                # roll the S-side admission back and retry next tick: drop
-                # any same-tick prefix-index registrations first (their pages
-                # will never be prefilled now — a later lookup must not alias
-                # them), then free the slot
-                if srt.admitted and srt.admitted[-1] == slot:
-                    srt.admitted.pop()
-                    srt.plans.pop()
-                if srt.sharing:
-                    srt.pool.retract(slot, adm.page_hashes, adm.full_hash,
-                                     tick)
-                srt.release(slot)
-                adm.admit_retries += 1
-                if adm.admit_retries > self.policy.admit_retry_limit:
-                    self._reject(adm, results)
-                    continue
-                queue.appendleft(adm)
-                break
-            if self.tel is not None:
-                self.tel.req_admitted("S", slot, adm.request.request_id,
-                                      adm.submit_time,
-                                      chunked=bool(srt.chunk_left[slot]))
-            admitted += 1
-
     def _drop_expired(self, l_queue: deque, results: Dict,
                       cur: int = 0) -> None:
         """arXiv:2112.11413 drop policy: an escalation whose request has
@@ -1810,10 +1603,9 @@ class ContinuousScheduler:
                 kept.append(adm)
         l_queue.extend(kept)
 
-    def _absorb_chunk(self, rt: _TierRuntime, out, emit: bool) -> None:
+    def _absorb_chunk(self, rt: _TierRuntime, out) -> None:
         """Advance the tick's scheduled chunk-prefill rows; finishing rows
-        optionally emit their chunk-sampled token 0 (the S tier emits, the
-        paired L tier in speculative mode only advances bookkeeping)."""
+        emit their chunk-sampled token 0."""
         for row, (slot, keep, fin) in enumerate(rt.chunk_sched):
             rt.chunk_fed[slot] += keep
             rt.chunk_left[slot] -= keep
@@ -1821,7 +1613,7 @@ class ContinuousScheduler:
                 self.tel.req_chunk(rt.name, slot,
                                    rt.slot_req[slot].adm.request.request_id,
                                    fed=keep, keep=int(rt.chunk_left[slot]))
-            if fin and emit:
+            if fin:
                 rec = rt.slot_req[slot]
                 if self.aud is not None and not rec.done:
                     self.aud.decision(
@@ -1843,7 +1635,7 @@ class ContinuousScheduler:
                              theta=self._eff_theta)
             rec.emit(out["admit_tok"][row], out["admit_conf"][row])
         if self.chunk:
-            self._absorb_chunk(rt, out, emit=True)
+            self._absorb_chunk(rt, out)
         k_steps = out["toks"].shape[0]
         for slot in range(rt.num_slots):
             rec = rt.slot_req[slot]
@@ -1868,74 +1660,6 @@ class ContinuousScheduler:
                                     rec.adm.request.request_id, k_steps)
             if rec.done:
                 finish(rt.release(slot))
-
-    def _absorb_spec(self, host: Dict, results: Dict) -> None:
-        """Fused-cascade absorb: per decoding slot the L verify decided how
-        many draft tokens stand (``accept``), which block input boundary both
-        caches keep (``keep``) and what to emit (``n_emit`` of ``toks``).
-        The host rewinds positions by the rejected tail and asserts the
-        rewind is COW-safe (``KVPool.truncate``)."""
-        s, l = host["s"], host["l"]
-        srt, lrt = self.srt, self.lrt
-        k = self.decode_block
-        for row, slot in enumerate(srt.admitted):
-            srt.slot_req[slot].emit(s["admit_tok"][row], s["admit_conf"][row])
-        if self.chunk:
-            self._absorb_chunk(srt, s, emit=True)
-            self._absorb_chunk(lrt, s, emit=False)
-        for slot in range(srt.num_slots):
-            rec = srt.slot_req[slot]
-            if rec is None:
-                continue
-            if self.chunk and srt.chunk_left[slot] > 0:
-                continue               # still chunk-prefilling: no decode
-            n = int(l["n_emit"][slot])
-            keep = int(l["keep"][slot])
-            esc = bool(l["esc"][slot])
-            rec.rounds.append((esc, n))
-            self.counters.blocks += 1
-            self.counters.drafted += k
-            self.counters.accepted += int(l["accept"][slot])
-            if esc:
-                self.counters.escalated_blocks += 1
-            if self.tel is not None:
-                rid = rec.adm.request.request_id
-                self.tel.req_decode("S", slot, rid, n)
-                if esc:
-                    self.tel.req_l_verify(slot, rid,
-                                          int(l["accept"][slot]), n)
-            if self.aud is not None:
-                # verify-lane feedback: the block-level gate decision plus
-                # FREE per-position ground truth (L re-derived every drafted
-                # position greedily, escalated or not)
-                rid_a = rec.adm.request.request_id
-                tclass = rec.adm.request.tclass
-                dc = l["draft_confs"][slot]
-                mt = l["match"][slot]
-                self.aud.decision(rid=rid_a, tier="S", tclass=tclass,
-                                  kind="block", conf=float(dc[:k].min()),
-                                  theta=self._eff_theta, offload=esc)
-                for j in range(n):     # emitted positions (n <= k; the
-                    #                    rolled-back tail is re-drafted and
-                    #                    would double-count its positions)
-                    self.aud.outcome(rid=rid_a, tier="L", tclass=tclass,
-                                     conf=float(dc[j]),
-                                     theta=self._eff_theta,
-                                     ok=bool(mt[j]), kind="draft")
-            for j in range(n):
-                rec.emit(l["toks"][slot][j], l["confs"][slot][j])
-            last = int(l["toks"][slot][max(n - 1, 0)])
-            for rt in (srt, lrt):
-                rt.pos[slot] += keep
-                rt.tok_idx[slot] += n
-                rt.last_tok[slot] = last
-                if keep < k:
-                    # the rejected tail is rolled back: assert the rewound
-                    # write position can never reach a shared page
-                    rt.pool.truncate(slot, int(rt.pos[slot]))
-            if rec.done:
-                self._finish_spec(srt.release(slot), results)
-                lrt.release(slot)
 
     def _finish_s(self, rec: _Active, theta: float, results: Dict) -> None:
         """S decode finished: record the local answer, and when the gate
@@ -2014,36 +1738,3 @@ class ContinuousScheduler:
                 self._probe = None
         if self.tel is not None:
             self.tel.req_terminal(rid, out)
-
-    def _finish_spec(self, rec: _Active, results: Dict) -> None:
-        rid = rec.adm.request.request_id
-        self.counters.requests += 1
-        escalated = sum(1 for esc, _ in rec.rounds if esc)
-        if escalated:
-            self.counters.offloaded += 1
-        if self.aud is not None:
-            self.aud.decision(
-                rid=rid, tier="S", tclass=rec.adm.request.tclass,
-                kind="request",
-                conf=float(np.mean(np.asarray(rec.confs, np.float32)))
-                if rec.confs else 1.0,
-                theta=self._run_theta, offload=escalated > 0)
-        results[rid] = {
-            "tokens": np.asarray(rec.tokens, np.int32),
-            "s_tokens": np.asarray(rec.tokens, np.int32),
-            "confidence": float(np.mean(np.asarray(rec.confs, np.float32)))
-            if rec.confs else 1.0,
-            "offloaded": escalated > 0,
-            "served_remote": False,
-            "dropped": False,
-            "status": "ok",
-            "escalation_retries": 0,
-            "queue_wait_ticks": 0,
-            "esc_created_tick": -1,      # the fused cascade has no L queue
-            "ttft": rec.ttft,
-            "rounds": list(rec.rounds),
-            "blocks": len(rec.rounds),
-            "escalated_blocks": escalated,
-        }
-        if self.tel is not None:
-            self.tel.req_terminal(rid, results[rid])

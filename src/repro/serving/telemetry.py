@@ -47,7 +47,7 @@ Span kinds:
   every transport attempt (its ``escalate_attempt`` children), or to its
   terminal if no L slot takes it (args: ``outcome``);
 * ``l_verify``        — L-tier residency for the escalation (admission to
-  finish/abort); in speculative mode, one per escalated verify block;
+  finish/abort);
 * ``terminal``        — zero-length marker carrying the final status.
 
 Span timestamps are ``time.monotonic()`` seconds (the clock the scheduler
@@ -86,15 +86,13 @@ The time-blind half of observability lives next door: ``GateAudit`` is the
 per-decision gate audit stream.  Each :class:`~repro.serving.audit.
 AuditRecord` carries ``(rid, tier, tclass, kind, conf, theta_in_effect,
 offload, ok)`` where ``kind`` is one of ``admit`` / ``chunk`` / ``decode``
-(per-token gate evaluations), ``block`` (speculative draft-block escalation
-decision), ``request`` (the request-level escalation decision), ``draft``
-(a verify-lane ground-truthed position, ``ok`` = L accepted the S token)
-or ``l_agree`` (completed escalation: S tokens matched L's).
+(per-token gate evaluations), ``request`` (the request-level escalation
+decision) or ``l_agree`` (completed escalation: S tokens matched L's).
 ``theta_in_effect`` records the threshold the device ACTUALLY used —
 ``FAIL_LOCAL_THETA`` while the circuit breaker is open.  Aggregates:
 streaming reliability bins (``core/calibrate.p_histogram`` bin semantics),
 running ECE + offload-rate per ``Request.tclass`` traffic class, a
-theta-margin histogram, and empirical-regret counters vs the verify-lane
+theta-margin histogram, and empirical-regret counters vs the L-agreement
 oracle.  When both collectors are installed the scheduler binds
 ``telemetry.audit = audit`` so the ``hi_audit_*`` families ride
 :meth:`Telemetry.prometheus_text` and the audit gauges become Chrome-trace
@@ -196,10 +194,6 @@ class SchedCounters:
     ticks: int = 0
     compiles: int = 0
     serve_time: float = 0.0
-    blocks: int = 0
-    escalated_blocks: int = 0
-    drafted: int = 0
-    accepted: int = 0
     degraded_local: int = 0
     rejected: int = 0
     breaker_open_ticks: int = 0
@@ -625,12 +619,6 @@ class Telemetry:
                       resend_tick: int) -> None:
         self.span_point(rid, "escalate_backoff", "S", -1, k=attempt,
                         resend_tick=resend_tick)
-
-    def req_l_verify(self, slot: int, rid: int, accepted: int,
-                     emitted: int) -> None:
-        """Speculative path: one escalated verify block."""
-        self.span_point(rid, "l_verify", "L", slot, accepted=accepted,
-                        emitted=emitted)
 
     def req_l_release(self, rid: int, outcome: str) -> None:
         self.span_close(rid, "l_verify", outcome=outcome)

@@ -2,13 +2,13 @@
 
 * the streaming reliability bins / ECE match a ``core/calibrate.p_histogram``
   NumPy oracle on the same decision stream (unit stream incl. bin-edge
-  values, AND the ground-truthed records of a real speculative run);
+  values, AND the ground-truthed records of a real serving run);
 * audit-enabled greedy output is token-identical to disabled, with
   ``stream_compiles == 1`` and ONE ``_host_fetch`` per tick — in BOTH
   ``kv_dtype`` modes (the audit rides the existing sync);
 * per-traffic-class offload rates agree with the result records'
   ``offloaded`` flags (``Request.tclass`` threading);
-* the speculative verify lane feeds per-position ground truth and the
+* completed escalations feed ``l_agree`` ground truth and the
   empirical-regret counters reconcile with the record stream;
 * the SLO watchdog emits breaches as telemetry instant events, rendered in
   the Chrome trace, and ``hi_audit_*`` families (with ``# HELP``) appear in
@@ -88,21 +88,24 @@ def test_reliability_bins_match_p_histogram_oracle():
     assert bins.count == conf.size
 
 
-def test_spec_run_bins_match_oracle_on_recorded_stream():
-    """The verify lane's ground-truthed records, replayed through the
-    oracle, must reproduce the audit's streaming bins exactly."""
+def test_plain_run_bins_match_oracle_on_recorded_stream():
+    """The ground-truthed records of a run whose requests all escalate
+    (theta above every confidence: each completed escalation yields one
+    ``l_agree`` record), replayed through the oracle, must reproduce the
+    audit's streaming bins exactly."""
     cfg = ARCHS["qwen2-1.5b"].reduced()
-    eng = build_engine(cfg, HIConfig(theta=0.9, capacity_factor=1.0),
+    eng = build_engine(cfg, HIConfig(theta=1.1, capacity_factor=1.0),
                        max_new_tokens=6, cache_len=48)
     aud = GateAudit(bins=20)
     rng = np.random.default_rng(1)
     reqs = [Request(i, rng.integers(0, cfg.vocab_size, 8).astype(np.int32),
-                    max_new_tokens=6) for i in range(4)]
-    eng.serve_stream(reqs, buckets=(8,), num_slots=2, page_size=8,
-                     decode_block=4, speculative=True, audit=aud)
+                    max_new_tokens=6) for i in range(6)]
+    out = eng.serve_stream(reqs, buckets=(8,), num_slots=2, page_size=8,
+                           decode_block=4, audit=aud)
     truthed = [r for r in aud.records if r.ok is not None]
-    assert truthed, "the verify lane must produce ground truth every tick"
-    assert {r.kind for r in truthed} == {"draft"}
+    assert len(truthed) == sum(r["served_remote"] for r in out.values()) \
+        == len(reqs)
+    assert {r.kind for r in truthed} == {"l_agree"}
     conf = np.array([r.conf for r in truthed])
     ok = np.array([r.ok for r in truthed], np.float32)
     oracle = p_histogram(conf, ok, bins=20)

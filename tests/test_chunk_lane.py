@@ -1,10 +1,9 @@
-"""The unified chunked token lane: ``model_zoo.forward_chunk_paged`` must be
-bitwise the PR-2 per-token paged path per family (greedy outputs), the
-chunked-prefill admission must be token-identical to whole-prompt admission,
-and ``KVPool.truncate`` must stay refcount-safe under rollback sequences.
+"""The chunked token lane: ``model_zoo.forward_chunk_paged`` must be bitwise
+the per-token paged path per family (greedy outputs), and the chunked-prefill
+admission must be token-identical to whole-prompt admission.
 
-The fixed-parameter tests run everywhere; the hypothesis sections widen the
-same properties to arbitrary chunk sizes C and draft lengths k in CI."""
+The fixed-parameter tests run everywhere; the hypothesis section widens the
+chunk-pass property to arbitrary chunk sizes C in CI."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,7 +14,6 @@ from repro.configs.registry import ARCHS
 from repro.models import model_zoo as zoo
 from repro.serving.batcher import Request
 from repro.serving.engine import build_engine
-from repro.serving.kv_pool import KVPool
 
 FAMS = ["qwen2-1.5b", "gemma3-1b", "deepseek-moe-16b", "mamba2-370m",
         "zamba2-2.7b"]
@@ -115,7 +113,8 @@ def test_chunk_kernel_matches_jnp_path():
 
 def test_chunk_staged_rollback_restores_boundary():
     """select_stage(staged, keep) must equal the state after exactly ``keep``
-    sequential steps (the rollback contract for the recurrent families)."""
+    sequential steps (the contract the chunk lane commits a partial final
+    chunk of a recurrent family with)."""
     cfg = ARCHS["mamba2-370m"].reduced()
     params, cache, block, pos, rng = _paged_setup(cfg)
     c, keep = 4, 2
@@ -134,7 +133,20 @@ def test_chunk_staged_rollback_restores_boundary():
                                   np.asarray(cache_ref["conv"]))
 
 
-@pytest.mark.parametrize("arch", ["qwen2-1.5b", "mamba2-370m"])
+# The hybrid's chunk pass alone matches its per-token path (above), but its
+# chunk-prefill serving does not match whole-prompt admission: every token
+# of the first request differs, with sharing off and on.  The fault lies in
+# how the scheduler's chunk lane carries the hybrid's state
+# (gather_chunk_slots / scatter_chunk_slots, or restore_stage of slots still
+# mid-chunk); ROADMAP.md names it as a debt.
+_HYBRID_CHUNK_DEFECT = pytest.mark.xfail(
+    strict=True, reason="hybrid chunk-prefill serving is not token-identical "
+    "to whole-prompt admission (known chunk-lane defect, ROADMAP.md)")
+
+
+@pytest.mark.parametrize("arch", [
+    "qwen2-1.5b", "mamba2-370m",
+    pytest.param("zamba2-7b", marks=_HYBRID_CHUNK_DEFECT)])
 @pytest.mark.parametrize("sharing", [False, True])
 def test_chunked_prefill_admission_token_identical(arch, sharing):
     """serve_stream with chunk_prefill on == off, token for token, per
@@ -158,80 +170,10 @@ def test_chunked_prefill_admission_token_identical(arch, sharing):
     assert eng_b.stats["stream_compiles"] == 1
 
 
-def test_truncate_guards_shared_pages():
-    """truncate raises on rewinds that could reach a page another slot
-    aliases, passes on exclusively-held decode regions, and never perturbs
-    refcount conservation."""
-    cfg = ARCHS["qwen2-1.5b"].reduced()
-    pool = KVPool(cfg, num_slots=2, max_context=32, page_size=8,
-                  prefix_entries=2)
-    toks = np.arange(16, dtype=np.int32)
-    from repro.serving.batcher import prompt_hashes
-    hashes, full = prompt_hashes(toks, 8)
-    p0 = pool.admit_prefix(0, 32, 16, hashes, full, tick=0)
-    assert p0 is not None and p0.start == 0
-    pool.truncate(0, 17)                       # decode region: exclusive
-    pool.check_invariants()
-    # second slot aliases the first prompt's pages (next tick)
-    p1 = pool.admit_prefix(1, 32, 16, hashes, full, tick=1)
-    assert p1 is not None and p1.start > 0
-    with pytest.raises(ValueError, match="shared page"):
-        pool.truncate(1, 0)                    # rewind into the shared prefix
-    pool.truncate(1, 17)                       # its own decode region: fine
-    pool.check_invariants()
-    with pytest.raises(ValueError):
-        pool.truncate(1, -1)
-
-
-def test_retract_undoes_rolled_back_registrations():
-    """A rolled-back paired admission must not leave prefix-index entries
-    pointing at never-prefilled pages — retract drops the admission's own
-    same-tick registrations (and ONLY those: a co-admitted identical prompt's
-    entries survive)."""
-    from repro.serving.batcher import prompt_hashes
-    cfg = ARCHS["qwen2-1.5b"].reduced()
-    pool = KVPool(cfg, num_slots=2, max_context=32, page_size=8,
-                  prefix_entries=2)
-    toks = np.arange(16, dtype=np.int32)
-    hashes, full = prompt_hashes(toks, 8)
-    plan = pool.admit_prefix(0, 32, 16, hashes, full, tick=0)
-    assert plan is not None and plan.save_row >= 0
-    pool.retract(0, hashes, full, tick=0)
-    pool.free(0)
-    pool.check_invariants()
-    # the retracted entries are gone: a next-tick identical prompt MISSES
-    plan2 = pool.admit_prefix(0, 32, 16, hashes, full, tick=1)
-    assert plan2 is not None and plan2.start == 0 and not plan2.is_restore
-    # ... but retracting with a DIFFERENT slot leaves the new owner's
-    # registrations alone
-    pool.retract(1, hashes, full, tick=1)
-    fe_hit, pages = pool.lookup(hashes, full, 16, tick=2)
-    assert fe_hit is not None or pages
-    pool.check_invariants()
-
-
 # ---------------------------------------------------------------------------
-# hypothesis: arbitrary chunk sizes / draft lengths / rollback sequences
-# (guarded so the fixed-parameter tests above still run without hypothesis)
+# hypothesis: arbitrary chunk sizes (guarded so the fixed-parameter tests
+# above still run without hypothesis)
 # ---------------------------------------------------------------------------
-def _spec_vs_oracle(cfg, k, chunk, seed, max_new=6):
-    from repro.serving.token_cascade import TokenCascade
-    hi = HIConfig(theta=0.5, capacity_factor=1.0)
-    rng = np.random.default_rng(seed)
-    prompt = rng.integers(0, cfg.vocab_size, 8).astype(np.int32)
-    eng = build_engine(cfg, hi, max_new_tokens=max_new, cache_len=48)
-    out = eng.serve_stream(
-        [Request(0, prompt, max_new_tokens=max_new)], buckets=(8,),
-        num_slots=1, page_size=8, decode_block=k,
-        speculative=True, chunk_prefill=chunk > 0, chunk_size=max(chunk, 1))
-    tc = TokenCascade(s_cfg=eng.s.cfg, l_cfg=eng.l.cfg,
-                      s_params=eng.s.params, l_params=eng.l.params,
-                      hi=hi, block=k, cache_len=48)
-    ref = tc.generate_speculative(prompt[None, :], max_new)
-    np.testing.assert_array_equal(out[0]["tokens"], ref["tokens"][0])
-    assert out[0]["rounds"] == ref["rounds"]
-
-
 try:
     from hypothesis import given, settings, strategies as st
     HAVE_HYPOTHESIS = True
@@ -252,38 +194,3 @@ if HAVE_HYPOTHESIS:
         out, ref, _ = _chunk_vs_steps(cfg, c=c, seed=seed)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
-
-    @settings(max_examples=4)   # each example compiles a tick executable
-    @given(st.integers(1, 5), st.integers(1, 6), st.integers(0, 2 ** 16))
-    def test_spec_lane_equiv_arbitrary_k(k, c, seed):
-        """Draft length k (decode_block) and chunk size C are free knobs:
-        the fused speculative lane's greedy outputs must match the host
-        oracle for any combination (small model, one request)."""
-        cfg = ARCHS["qwen2-1.5b"].reduced()
-        _spec_vs_oracle(cfg, k=k, chunk=c, seed=seed)
-
-    @given(st.lists(st.tuples(st.integers(0, 3), st.integers(0, 40)),
-                    min_size=1, max_size=24),
-           st.integers(0, 2 ** 16))
-    def test_pool_invariants_under_truncate_rollback(ops, seed):
-        """check_invariants holds through arbitrary alloc /
-        truncate(rollback) / free sequences (truncate either passes or
-        raises cleanly — never corrupts the allocator)."""
-        cfg = ARCHS["qwen2-1.5b"].reduced()
-        pool = KVPool(cfg, num_slots=4, max_context=32, page_size=8)
-        rng = np.random.default_rng(seed)
-        held = set()
-        for slot, arg in ops:
-            kind = rng.integers(0, 3)
-            try:
-                if kind == 0 and slot not in held:
-                    pool.alloc(slot, 8 + (arg % 25))
-                    held.add(slot)
-                elif kind == 1 and slot in held:
-                    pool.truncate(slot, arg)
-                elif kind == 2 and slot in held:
-                    pool.free(slot)
-                    held.discard(slot)
-            except ValueError:
-                pass             # rejection is fine; corruption is not
-            pool.check_invariants()

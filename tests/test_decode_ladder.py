@@ -38,7 +38,8 @@ class _Waves(AdmissionQueue):
     scheduler has dispatched ``t`` ticks."""
 
     def __init__(self, sched, waves):
-        super().__init__(buckets=(8, 16))
+        super().__init__(buckets=(8, 16),
+                         page_size=PAGE if sched.prefix_sharing else None)
         self._sched, self._waves = sched, dict(waves)
 
     def __len__(self):
@@ -159,17 +160,17 @@ def test_ladder_matches_full_width_lane(served):
 
 
 @pytest.fixture(scope="module", params=[
-    {"chunk_prefill": True, "chunk_size": 8}, {"speculative": True}],
-    ids=["chunk_prefill", "speculative"])
+    {"chunk_prefill": True, "chunk_size": 8},
+    {"chunk_prefill": True, "chunk_size": 8, "prefix_sharing": True}],
+    ids=["chunk_prefill", "chunk_prefill_sharing"])
 def served_other(request):
     return {"ladder": _serve("qwen2-1.5b", False, **request.param),
             "full": _serve("qwen2-1.5b", True, **request.param)}
 
 
-def test_ladder_matches_full_width_in_chunk_and_speculative_builds(
-        served_other):
-    """The chunk lane's mid-prefill slots and the speculative draft read
-    K/V through the same ladder: answers identical to the full-width
+def test_ladder_matches_full_width_in_chunk_builds(served_other):
+    """The chunk lane's mid-prefill slots read K/V through the same ladder,
+    with prefix sharing off and on: answers identical to the full-width
     lane's, narrow ticks run, and pages outside the live slots' and the
     null page stay untouched."""
     _assert_same_answers(served_other)

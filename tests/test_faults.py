@@ -11,7 +11,8 @@ from repro.configs.base import HIConfig
 from repro.configs.registry import ARCHS
 from repro.serving.batcher import Request
 from repro.serving.engine import build_engine
-from repro.serving.faults import CircuitBreaker, FaultSchedule, RetryPolicy
+from repro.serving.faults import (STATUSES, CircuitBreaker, FaultSchedule,
+                                  RetryPolicy)
 
 STEPS = 3
 KW = dict(buckets=(8,), num_slots=2, page_size=8)
@@ -89,14 +90,31 @@ def test_circuit_breaker_state_machine():
     assert brk.state == CircuitBreaker.CLOSED and brk.failures == 0
 
 
-def test_speculative_mode_rejects_active_faults():
-    """Fault injection models the S→L escalation QUEUE; the fused
-    speculative cascade has none, so an active schedule is refused."""
-    from repro.serving.scheduler import ContinuousScheduler
-    sched = ContinuousScheduler.__new__(ContinuousScheduler)
-    sched.speculative = True
-    with pytest.raises(ValueError, match="speculative"):
-        ContinuousScheduler.set_faults(sched, FaultSchedule(loss_prob=0.5))
+def test_active_faults_run_on_chunk_sharing_build():
+    """An active loss/delay schedule composes with chunked prefill and
+    prefix sharing: one compile, exactly one record with a legal status per
+    request, and no slot holds pages after the drain."""
+    cfg = ARCHS["qwen2-1.5b"].reduced()
+    e = build_engine(cfg, HIConfig(theta=1.1, capacity_factor=1.0),
+                     max_new_tokens=STEPS, cache_len=48)
+    rng = np.random.default_rng(1)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, n).astype(np.int32),
+                    max_new_tokens=STEPS)
+            for i, n in enumerate([8, 24, 16, 24, 8, 16])]
+    out = e.serve_stream(
+        reqs, buckets=(8, 16, 24), num_slots=3, page_size=8,
+        prefix_sharing=True, chunk_prefill=True, chunk_size=8,
+        validate=True,
+        faults=FaultSchedule(seed=5, loss_prob=0.4, delay_ticks=1,
+                             delay_jitter=2),
+        retry=RetryPolicy(ack_timeout_ticks=2, max_retries=2,
+                          backoff_base_ticks=1, backoff_cap_ticks=2))
+    assert sorted(out) == list(range(len(reqs)))
+    assert all(r["status"] in STATUSES for r in out.values())
+    assert e.stats["esc_lost"] > 0            # the schedule was active
+    assert e.stats["stream_compiles"] == 1
+    sched = e._stream[1]
+    assert sched.srt.pool.held_slots == [] and sched.lrt.pool.held_slots == []
 
 
 # ---------------------------------------------------------------------------

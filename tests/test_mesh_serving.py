@@ -122,15 +122,19 @@ def test_mesh_pool_shards_clean_after_faulted_escalations(cfg):
 
 
 def test_mesh_rejects_bad_configs(cfg):
-    """Guard rails: a mesh without the serving axes, and speculative +
-    mesh, fail loudly at construction."""
+    """Guard rail: a mesh without the serving axes fails loudly at
+    construction."""
+    import jax
+    from jax.sharding import Mesh
+
     from repro.serving.scheduler import ContinuousScheduler
     e = build_engine(cfg, HIConfig(theta=0.5, capacity_factor=1.0),
                      max_new_tokens=STEPS, cache_len=32)
-    with pytest.raises(NotImplementedError, match="speculative"):
+    bad = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("x", "model"))
+    with pytest.raises(ValueError, match="serving mesh needs axes"):
         ContinuousScheduler(e.s, e.l, e.hi, max_prompt_len=8,
                             max_new_tokens=STEPS, num_slots=2, page_size=8,
-                            speculative=True, mesh=make_serving_mesh(1, 1))
+                            mesh=bad)
 
 
 _DATA2_SCRIPT = textwrap.dedent("""

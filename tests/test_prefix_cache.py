@@ -14,8 +14,10 @@ from repro.serving.engine import build_engine
 STEPS = 3
 
 # one reduced config per decoder family: dense (partial-hit + COW capable),
-# moe (batch-coupled routing), ssm + hybrid (whole-prompt snapshot/restore)
-FAMS = ["qwen2-1.5b", "deepseek-moe-16b", "mamba2-370m", "zamba2-2.7b"]
+# moe (batch-coupled routing), ssm + hybrid (whole-prompt snapshot/restore),
+# plus the benchmarked hybrid (zamba2-7b)
+FAMS = ["qwen2-1.5b", "deepseek-moe-16b", "mamba2-370m", "zamba2-2.7b",
+        "zamba2-7b"]
 
 
 def _repeated_prefix_traffic(cfg, seed=3):

@@ -139,15 +139,14 @@ def test_confidence_ranges(n, c, seed):
 # ---------------------------------------------------------------------------
 # quantized pool: allocator invariants with scale rows attached
 # ---------------------------------------------------------------------------
-@given(st.lists(st.tuples(st.sampled_from(["alloc", "free", "admit",
-                                           "truncate"]),
+@given(st.lists(st.tuples(st.sampled_from(["alloc", "free", "admit"]),
                           st.integers(0, 3),
                           st.sampled_from([8, 12, 16, 20])),
                 min_size=1, max_size=25),
        st.integers(0, 5))
 def test_int8_pool_invariants_under_random_ops(ops, nprompts):
     """Random alloc / free / prefix-admission (aliasing, COW tail pages,
-    eviction) / truncate sequences on an int8 pool keep every refcount
+    eviction) sequences on an int8 pool keep every refcount
     invariant PLUS the scale-row accounting (`check_invariants` asserts the
     fp32 scale tensors stay one-row-per-physical-page beside the int8
     pools).  Hashed prompts repeat, so admissions alias retained pages and
@@ -173,10 +172,8 @@ def test_int8_pool_invariants_under_random_ops(ops, nprompts):
                 full = bytes([pid, 0xFF, bucket])
                 pool.admit_prefix(slot, bucket + 4, bucket, hashes, full,
                                   tick)
-            elif op == "truncate":
-                pool.truncate(slot, bucket)
         except ValueError:
-            pass          # exhaustion / double-free / shared-page rewind
+            pass          # exhaustion / double-free
         pool.check_invariants()
     for slot in list(pool.held_slots):
         pool.free(slot)

@@ -12,6 +12,8 @@ from repro.serving.batcher import Request
 from repro.serving.engine import build_engine
 
 STEPS = 3
+# the dense family and the benchmarked hybrid (Mamba2 + shared attention)
+ARCH_IDS = ["qwen2-1.5b", "zamba2-7b"]
 
 
 def _mixed_requests(cfg, lens, rng, steps=STEPS, temperature=0.0):
@@ -33,7 +35,7 @@ def _drain_by_bucket(eng, reqs, temps=False):
     return out
 
 
-@pytest.mark.parametrize("arch", ["qwen2-1.5b", "mamba2-370m"])
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "mamba2-370m", "zamba2-7b"])
 def test_stream_matches_drain_on_mixed_lengths(arch):
     """serve_stream must produce token-identical greedy outputs (S tokens,
     merged tokens, confidence, offload set) to the drain path on mixed-length
@@ -62,7 +64,8 @@ def test_stream_matches_drain_on_mixed_lengths(arch):
     assert eng_d.stats["compiles"] == 2
 
 
-def test_stream_single_sync_per_tick(monkeypatch):
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_stream_single_sync_per_tick(arch, monkeypatch):
     """Each scheduler tick performs exactly ONE device->host sync, through
     the engine's ``_host_fetch`` — no hidden fetches in admission,
     escalation, or completion handling."""
@@ -70,7 +73,7 @@ def test_stream_single_sync_per_tick(monkeypatch):
     real = engine_mod._host_fetch
     monkeypatch.setattr(engine_mod, "_host_fetch",
                         lambda tree: (calls.append(1), real(tree))[1])
-    cfg = ARCHS["qwen2-1.5b"].reduced()
+    cfg = ARCHS[arch].reduced()
     eng = build_engine(cfg, HIConfig(theta=0.6, capacity_factor=1.0),
                        max_new_tokens=STEPS, cache_len=32)
     reqs = _mixed_requests(cfg, [8, 16, 8], np.random.default_rng(0))
@@ -78,11 +81,12 @@ def test_stream_single_sync_per_tick(monkeypatch):
     assert len(calls) == eng.stats["stream_ticks"] > 0
 
 
-def test_stream_temperature_matches_drain():
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_stream_temperature_matches_drain(arch):
     """Per-request seeded sampling: temp > 0 continuations are reproducible
     across the two schedulers (keys depend only on request id + token index,
     not slot / tick / batch row)."""
-    cfg = ARCHS["qwen2-1.5b"].reduced()
+    cfg = ARCHS[arch].reduced()
     hi = HIConfig(theta=0.0, capacity_factor=1.0)   # S-only: compare S tokens
     rng = np.random.default_rng(5)
     reqs = _mixed_requests(cfg, [8, 8, 16], rng, temperature=0.7)
@@ -102,10 +106,11 @@ def test_stream_temperature_matches_drain():
                for r in drain)
 
 
-def test_stream_per_request_max_new_and_eos():
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_stream_per_request_max_new_and_eos(arch):
     """Unlike the drain path's engine-wide step count, the scheduler honours
     per-request max_new_tokens and stops early on EOS, freeing the slot."""
-    cfg = ARCHS["qwen2-1.5b"].reduced()
+    cfg = ARCHS[arch].reduced()
     hi = HIConfig(theta=0.0, capacity_factor=1.0)
     rng = np.random.default_rng(9)
     r_short = Request(0, rng.integers(0, cfg.vocab_size, 8).astype(np.int32),
@@ -127,10 +132,11 @@ def test_stream_per_request_max_new_and_eos():
     assert int(out2[1]["tokens"][0]) == first
 
 
-def test_stream_slot_count_smaller_than_traffic():
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_stream_slot_count_smaller_than_traffic(arch):
     """More requests than slots: admission must recycle slots (the
     continuous part) and still serve everyone exactly once."""
-    cfg = ARCHS["qwen2-1.5b"].reduced()
+    cfg = ARCHS[arch].reduced()
     hi = HIConfig(theta=1.1, capacity_factor=1.0)   # everything escalates
     rng = np.random.default_rng(4)
     reqs = _mixed_requests(cfg, [8] * 5, rng)
@@ -142,24 +148,33 @@ def test_stream_slot_count_smaller_than_traffic():
     assert eng.stats["offloaded"] == 5
 
 
-@pytest.mark.parametrize("build, lanes", [
-    ({}, {"admit", "decode"}),
-    ({"prefix_sharing": True, "chunk_prefill": True},
-     {"cow", "admit", "prefix", "chunk", "decode"}),
-    ({"speculative": True}, {"admit", "decode"}),
-    ({"mesh": (1, 1)}, {"admit", "decode"}),
-], ids=["plain", "sharing_chunk", "speculative", "mesh_1x1"])
-def test_lane_of_ops_names_every_cond_and_while(build, lanes):
+# build name -> (scheduler settings, the lanes each tier traces)
+_BUILDS = {
+    "plain": ({}, {"admit", "decode"}),
+    "sharing_chunk": ({"prefix_sharing": True, "chunk_prefill": True},
+                      {"cow", "admit", "prefix", "chunk", "decode"}),
+    "chunk_only": ({"chunk_prefill": True}, {"admit", "chunk", "decode"}),
+    "int8": ({"kv_dtype": "int8"}, {"admit", "decode"}),
+    "mesh_1x1": ({"mesh": (1, 1)}, {"admit", "decode"}),
+}
+
+
+# the hybrid on a serving mesh is a known defect (ROADMAP.md), so the mesh
+# build runs on the dense family only
+@pytest.mark.parametrize("arch, build", [
+    (arch, build) for arch in ARCH_IDS for build in _BUILDS
+    if build != "mesh_1x1" or arch == "qwen2-1.5b"])
+def test_lane_of_ops_names_every_cond_and_while(arch, build):
     """Every ``conditional`` and ``while`` of the compiled tick lies in
     exactly one lane scope, and each tier's lanes that this build traces
-    appear in the map (the speculative L tier verifies, it does not
-    decode)."""
+    appear in the map."""
     import re
 
     from repro.launch.mesh import make_serving_mesh
     from repro.serving.scheduler import ContinuousScheduler
 
-    cfg = ARCHS["qwen2-1.5b"].reduced()
+    build, lanes = _BUILDS[build]
+    cfg = ARCHS[arch].reduced()
     eng = build_engine(cfg, HIConfig(theta=0.6, capacity_factor=1.0),
                        max_new_tokens=STEPS, cache_len=32)
     kw = dict(build)
@@ -175,6 +190,4 @@ def test_lane_of_ops_names_every_cond_and_while(build, lanes):
     assert loops and all(name in lane_of for name in loops), \
         [n for n in loops if n not in lane_of]
     want = {f"{t}/{lane}" for t in ("S", "L") for lane in lanes}
-    if build.get("speculative"):
-        want = (want - {"L/decode"}) | {"L/verify"}
     assert set(lane_of.values()) == want
