@@ -380,11 +380,12 @@ def four_chips(cfg, *, seed: int = 0) -> str:
         for leaf in jax.tree.leaves(tree):
             devs |= set(leaf.sharding.device_set)
         check(len(devs) == 4, f"mesh: {what} span {len(devs)} devices")
-    kp = sched.lrt.pool.buffers["kp"]
-    local_k = kp.addressable_shards[0].data.shape[3]
-    check(local_k * 2 == kp.shape[3],
-          f"mesh: L pages split over model ({local_k} of {kp.shape[3]} kv "
-          "heads per shard)")
+    kp = sched.lrt.pool.buffers["kp"]            # (L, P, page, K*Dh)
+    dh = sched.l.cfg.resolved_head_dim
+    local_k = kp.addressable_shards[0].data.shape[3] // dh
+    check(local_k * 2 == kp.shape[3] // dh,
+          f"mesh: L pages split over model ({local_k} of {kp.shape[3] // dh} "
+          "kv heads per shard)")
 
     # the model-sharded L tier computes what one device computes
     l_cfg, l_params = eng.l.cfg, eng.l.params

@@ -9,14 +9,15 @@ identity block table (the cache viewed as one page run per row).
 
 Grid: (batch, page).  The block table is a SCALAR-PREFETCH operand, so the
 K/V index_maps dereference it: grid step (b, j) DMAs physical page
-``block[b, j]`` as a whole ``(1, page, K, D)`` tile — every KV head of the
-page in one copy — and the body loops over the K heads.  Whole trailing
-dims keep every block shape legal for the TPU's (8, 128) tiling at any
-head count and head_dim (qwen2's K=2/D=128, zamba2-7b's K=32/D=224).  Per step
-the kernel holds:
+``block[b, j]`` of a ``(P, page, K*D)`` pool (``layers.paged_kv``) as a
+whole ``(1, page, K*D)`` tile — every KV head of the page in one copy — and
+the body loops over the K heads, head k being lanes ``[k*D, (k+1)*D)``.
+Whole trailing dims keep every block shape legal for the TPU's (8, 128)
+tiling at any head count and head_dim (qwen2's K=2/D=128, zamba2-7b's
+K=32/D=224).  Per step the kernel holds:
 
   q     (1, K, C*G, D)      the slot's query rows, head-major   [VMEM]
-  k/v   (1, page, K, D)     one physical page                   [VMEM]
+  k/v   (1, page, K*D)      one physical page                   [VMEM]
   mask  (1, 1, C*G, page)   positional + intra-chunk causal     [VMEM]
 
 and keeps the online-softmax state (running max m, normaliser l, fp32
@@ -31,7 +32,7 @@ probability mass is explicitly zeroed (a fully-masked page keeps l = 0).
 
 Quantized page pools (int8, per-page-per-head scales)
 -----------------------------------------------------
-``pool_k/v`` may be (P, page, K, Dh) int8 with a symmetric fp32 scale
+``pool_k/v`` may be (P, page, K*Dh) int8 with a symmetric fp32 scale
 tensor ``scale_k/v`` of shape (P, K) beside each pool (dequantized value =
 ``int8 * scale[page, head]``).  The scales ride the same block-table
 index_map as their pool, viewed as (P, 1, K) so the block is one whole
@@ -86,7 +87,7 @@ def _check_heads(h: int, kh: int) -> int:
 
 
 def _paged_kernel(blk_ref, q_ref, k_ref, v_ref, *refs, scale: float,
-                  kh: int, quant: bool):
+                  kh: int, d: int, quant: bool):
     """One (slot, page) grid step for all K heads: fold page ``block[b, j]``
     into every query row's online-softmax state; normalise and write the
     output at the slot's last page."""
@@ -107,8 +108,9 @@ def _paged_kernel(blk_ref, q_ref, k_ref, v_ref, *refs, scale: float,
     live = valid.astype(jnp.float32)
     for hd in range(kh):
         q = q_ref[0, hd].astype(jnp.float32)            # (R, D)
-        k = k_ref[0, :, hd, :].astype(jnp.float32)      # (page, D)
-        v = v_ref[0, :, hd, :].astype(jnp.float32)
+        lanes = slice(hd * d, (hd + 1) * d)
+        k = k_ref[0, :, lanes].astype(jnp.float32)      # (page, D)
+        v = v_ref[0, :, lanes].astype(jnp.float32)
         if quant:
             k = k * sk_ref[0, :, hd:hd + 1]             # (1, 1) page scale
             v = v * sv_ref[0, :, hd:hd + 1]
@@ -140,7 +142,7 @@ def decode_attention_chunk_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
                                         interpret: bool = False
                                         ) -> jnp.ndarray:
     """q: (B, C, H, D) — a chunk of C query tokens per slot; pool_k/v:
-    (P, page, K, D); block: (B, n_pages) int32; valid: (B, C, n_pages * page)
+    (P, page, K*D); block: (B, n_pages) int32; valid: (B, C, n_pages * page)
     bool — per-slot positional AND intra-chunk causal mask (query i of slot b
     may attend key position s iff ``valid[b, i, s]``).
 
@@ -151,7 +153,8 @@ def decode_attention_chunk_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
     docstring); dequant fuses into the gather.  Returns (B, C, H, D)
     attention output (fp32 accumulation)."""
     b, c, h, d = q.shape
-    n_phys, page, kh = pool_k.shape[:3]
+    n_phys, page, kd = pool_k.shape
+    kh = kd // d
     npg = block.shape[1]
     g = _check_heads(h, kh)
     r = c * g                                       # query rows per kv head
@@ -164,8 +167,8 @@ def decode_attention_chunk_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
                             (b, npg, c, g, page)).reshape(b, npg, r, page)
     quant = scale_k is not None
 
-    page_spec = pl.BlockSpec((1, page, kh, d),
-                             lambda bi, j, blk: (blk[bi, j], 0, 0, 0))
+    page_spec = pl.BlockSpec((1, page, kd),
+                             lambda bi, j, blk: (blk[bi, j], 0, 0))
     scale_spec = pl.BlockSpec((1, 1, kh),
                               lambda bi, j, blk: (blk[bi, j], 0, 0))
     row_spec = pl.BlockSpec((1, kh, r, d), lambda bi, j, blk: (bi, 0, 0, 0))
@@ -187,7 +190,7 @@ def decode_attention_chunk_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
         scales = (scale_k.reshape(n_phys, 1, kh),
                   scale_v.reshape(n_phys, 1, kh))
     kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(d),
-                               kh=kh, quant=quant)
+                               kh=kh, d=d, quant=quant)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -206,7 +209,7 @@ def decode_attention_paged_pallas(q: jnp.ndarray, pool_k: jnp.ndarray,
                                   scale_k: jnp.ndarray | None = None,
                                   scale_v: jnp.ndarray | None = None,
                                   interpret: bool = False) -> jnp.ndarray:
-    """q: (B, 1, H, D); pool_k/v: (P, page, K, D); block: (B, n_pages) int32;
+    """q: (B, 1, H, D); pool_k/v: (P, page, K*D); block: (B, n_pages) int32;
     valid: (B, n_pages * page) bool (per-slot positional mask); scale_k/v
     (P, K) fp32 mark the pools int8-quantized.  The C = 1 case of
     :func:`decode_attention_chunk_paged_pallas`.
@@ -230,7 +233,7 @@ def decode_attention_pallas(q: jnp.ndarray, cache_k: jnp.ndarray,
     # largest divisor of S not exceeding the requested tile
     block_s = math.gcd(s, block_s)
     nsb = s // block_s
-    pool_shape = (b * nsb, block_s) + cache_k.shape[2:]
+    pool_shape = (b * nsb, block_s, -1)
     table = jnp.arange(b * nsb, dtype=jnp.int32).reshape(b, nsb)
     return decode_attention_paged_pallas(
         q, cache_k.reshape(pool_shape), cache_v.reshape(pool_shape), table,
@@ -241,7 +244,7 @@ def copy_pages_pallas(pool: jnp.ndarray, src_of: jnp.ndarray, *,
                       interpret: bool = False) -> jnp.ndarray:
     """Copy-on-write page duplication over a physical page pool.
 
-    pool: (L, P, ...) — (L, P, page, K, D) pages or (L, P, K) scale rows;
+    pool: (L, P, ...) — (L, P, page, K*D) pages or (L, P, K) scale rows;
     src_of: (P,) int32 per-page SOURCE map — identity everywhere except the
     COW destinations, which name the page they clone.  The map is a
     scalar-prefetch operand (the same idiom as the paged attention kernel's

@@ -63,7 +63,7 @@ def decode_attention_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
                            scale_v: jnp.ndarray | None = None) -> jnp.ndarray:
     """Flash decode attention over a PAGED KV pool.
 
-    q: (B,1,H,D); pool_k/v: (P, page, K, D); block: (B, n_pages) int32 block
+    q: (B,1,H,D); pool_k/v: (P, page, K*D); block: (B, n_pages) int32 block
     table (scalar-prefetched — the kernel DMAs physical pages directly);
     valid: (B, n_pages * page) per-slot positional mask.  Passing
     ``scale_k/v`` (P, K) fp32 marks the pools int8-quantized: the page's
@@ -84,7 +84,7 @@ def decode_attention_chunk_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     """Flash CHUNK attention over a paged KV pool: C query tokens per slot at
     per-slot start positions in one streaming pass over the slot's pages.
 
-    q: (B, C, H, D); pool_k/v: (P, page, K, D); block: (B, n_pages) int32
+    q: (B, C, H, D); pool_k/v: (P, page, K*D); block: (B, n_pages) int32
     (scalar-prefetched); valid: (B, C, n_pages * page) positional +
     intra-chunk causal mask.  ``scale_k/v`` (P, K) fp32 mark the pools
     int8-quantized with dequant fused into the page gather."""
@@ -101,7 +101,7 @@ def copy_pages(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray
     pages ``src`` (pairs padded with (0, 0) — null onto null).
 
     pool: (L, P, ...) of any dtype — the kernel's block shape and out_shape
-    derive from the operand, so the same op moves (L, P, page, K, D) int8/bf16
+    derive from the operand, so the same op moves (L, P, page, K*D) int8/bf16
     page pools AND their (L, P, K) fp32 per-page scale rows.  The (src, dst)
     pairs are expanded into a per-page source map so the kernel writes every
     output page exactly once (identity for non-COW pages) with the map

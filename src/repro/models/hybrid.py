@@ -283,16 +283,16 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 #
 # Mamba states are per-slot (constant-size, nothing to page); each shared
 # block application's K/V is a paged pool behind one leading axis,
-# (A, P, page, K, Dh), indexed by the scheduler's single block table (one
-# physical page holds every application's K/V for a page worth of
-# positions).
+# (A, P, page, K·Dh) as ``layers.paged_kv`` lays it out, indexed by the
+# scheduler's single block table (one physical page holds every
+# application's K/V for a page worth of positions).
 
 
 def init_paged_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
                      page_size: int, dtype=jnp.bfloat16):
     cache = mamba2.init_paged_cache(cfg, num_slots, num_pages, page_size, dtype)
     kv_shape = (len(cfg.hybrid_layer_ids), num_pages, page_size,
-                cfg.num_kv_heads, cfg.resolved_head_dim)
+                cfg.num_kv_heads * cfg.resolved_head_dim)
     cache.update(kp=jnp.zeros(kv_shape, dtype), vp=jnp.zeros(kv_shape, dtype))
     if jnp.dtype(dtype) == jnp.int8:
         sshape = kv_shape[:2] + (cfg.num_kv_heads,)

@@ -316,8 +316,14 @@ def attention_decode(params: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
 
 
 def paged_kv(cache: Params) -> Params:
-    """The stacked KV pools of a paged cache: ``kp``/``vp`` (L, P, page, K,
-    Dh), plus the int8 pools' ``ks``/``vs`` (L, P, K) fp32 scales."""
+    """The stacked KV pools of a paged cache: ``kp``/``vp`` (L, P, page,
+    K·Dh), plus the int8 pools' ``ks``/``vs`` (L, P, K) fp32 scales.
+
+    A pool row holds one position's K (or V) for every KV head, head-major
+    in one minor dim: every lane (COW, admission scatter, decode append,
+    page gather) reads and writes whole ``(page, K·Dh)`` rows, so no lane
+    wants another tiling of the pool than the others and XLA never copies
+    it to a second layout.  Heads are split out only on gathered rows."""
     return {k: cache[k] for k in ("kp", "vp", "ks", "vs") if k in cache}
 
 
@@ -334,16 +340,16 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
     pools, updated in place.
 
     x: (B, 1, D); kv: :func:`paged_kv` of the cache — ``kp``/``vp`` (L, P,
-    page, K, Dh), every layer's pages in ONE physical allocation shared by
+    page, K·Dh), every layer's pages in ONE physical allocation shared by
     every slot; layer: int32 scalar index into their leading axis; block:
     (B, n_pages) int32 block table mapping each slot's logical pages to
     physical ones (0 = the null/trash page); pos: (B,) int32 per-slot
     positions (continuous batching: slots decode at DIFFERENT positions,
     unlike the contiguous cache's single scalar).
 
-    The new token's K/V is scattered into ``[layer, block[b, pos_b // page],
-    pos_b % page]``; reads gather every slot's pages back through the table
-    straight from the stacked pools (or stream the layer's pages inside the
+    The new token's (K·Dh) K/V row is scattered into ``[layer, block[b,
+    pos_b // page], pos_b % page]``; reads gather every slot's pages back
+    through the table straight from the stacked pools (or stream the layer's pages inside the
     Pallas kernel when ``use_kernel``).  Masking is positional (``kpos <=
     pos_b``) so stale page contents are never observable.  Callers carry
     ``kv`` through their layer scan: with no layer sliced out and none
@@ -391,10 +397,10 @@ def attention_decode_paged(params: Params, x: jnp.ndarray, kv: Params,
                                             layer=layer)
         kv = {"kp": pool_k, "vp": pool_v, "ks": scale_k, "vs": scale_v}
     else:
-        pool_k = pool_k.at[layer, pg, off].set(k[:, 0].astype(pool_k.dtype),
-                                               mode="drop")
-        pool_v = pool_v.at[layer, pg, off].set(v[:, 0].astype(pool_v.dtype),
-                                               mode="drop")
+        pool_k = pool_k.at[layer, pg, off].set(
+            k[:, 0].reshape(b, -1).astype(pool_k.dtype), mode="drop")
+        pool_v = pool_v.at[layer, pg, off].set(
+            v[:, 0].reshape(b, -1).astype(pool_v.dtype), mode="drop")
         kv = {"kp": pool_k, "vp": pool_v}
     kpos = jnp.arange(s_tot)[None, :]        # logical key positions per slot
     valid = kpos <= pos[:, None]
@@ -473,11 +479,12 @@ def attention_chunk_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
     (same K/V values: both paths round to the cache dtype before the read).
     Positions past the slot's page row write to the null page.
 
-    ``scale_k/v`` (P, K) fp32 mark the pools int8-quantized; the chunk's
-    appends quantize through a monotone running-max page scale (whole chunk
-    committed at the final scale — one rounding, vs the per-token path's
-    potential requant, which is why chunk-vs-steps equivalence is
-    tolerance-based under int8).
+    pool_k/v: one layer's (P, page, K·Dh) pools.  ``scale_k/v`` (P, K)
+    fp32 mark the pools int8-quantized; the chunk's appends quantize
+    through a monotone running-max page scale (whole chunk committed at the
+    final scale — one rounding, vs the per-token path's potential requant,
+    which is why chunk-vs-steps equivalence is tolerance-based under
+    int8).
 
     Returns (out (B, C, D'), pool_k, pool_v) — plus (scale_k, scale_v) when
     quantized."""
@@ -501,8 +508,10 @@ def attention_chunk_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
         pool_k, scale_k = quant_append_page(pool_k, scale_k, pg, off, k)
         pool_v, scale_v = quant_append_page(pool_v, scale_v, pg, off, v)
     else:
-        pool_k = pool_k.at[pg, off].set(k.astype(pool_k.dtype), mode="drop")
-        pool_v = pool_v.at[pg, off].set(v.astype(pool_v.dtype), mode="drop")
+        pool_k = pool_k.at[pg, off].set(
+            k.reshape(b, c, -1).astype(pool_k.dtype), mode="drop")
+        pool_v = pool_v.at[pg, off].set(
+            v.reshape(b, c, -1).astype(pool_v.dtype), mode="drop")
     kpos = jnp.arange(s_tot)[None, None, :]
     valid = kpos <= positions[:, :, None]                    # (B, C, S_tot)
     if window is not None:
@@ -525,7 +534,7 @@ def attention_chunk_paged(params: Params, x: jnp.ndarray, pool_k: jnp.ndarray,
 # int8 page quantization (per-page-per-head symmetric scales)
 # ---------------------------------------------------------------------------
 #
-# An int8 pool (P, page, K, Dh) carries a (P, K) fp32 scale tensor: one
+# An int8 pool (P, page, K·Dh) carries a (P, K) fp32 scale tensor: one
 # symmetric scale per physical page per kv head, dequant = int8 * scale.
 # Write contract:
 #   * prefill overwrites whole pages -> scale rows use SET semantics
@@ -555,8 +564,8 @@ def quant_append_page(pool: jnp.ndarray, scale: jnp.ndarray, pg: jnp.ndarray,
     """Append token K/V into an int8 pool at (pg, off) under a monotone
     per-page-per-head running-max scale.
 
-    pool: (P, page, K, Dh) int8; scale: (P, K) fp32 — or, with ``layer``, a
-    stacked (L, P, page, K, Dh) pool and (L, P, K) scales updated at that
+    pool: (P, page, K·Dh) int8; scale: (P, K) fp32 — or, with ``layer``, a
+    stacked (L, P, page, K·Dh) pool and (L, P, K) scales updated at that
     layer in place; pg/off: (...,) int32 (token -> physical page / in-page
     offset); val: (..., K, Dh).  Pages whose scale grows are requantized to
     the new grid (duplicate pg entries write identical bytes — ratio and
@@ -575,11 +584,14 @@ def quant_append_page(pool: jnp.ndarray, scale: jnp.ndarray, pg: jnp.ndarray,
     scale = scale.at[lead + (pg,)].max(absmax / _QMAX, mode="drop")
     new = scale[lead + (pg,)]
     ratio = jnp.clip(_safe_scale(old) / _safe_scale(new), 0.0, 1.0)
-    repack = jnp.round(pool[lead + (pg,)].astype(jnp.float32)
-                       * ratio[..., None, :, None])
-    pool = pool.at[lead + (pg,)].set(repack.astype(pool.dtype), mode="drop")
+    rows = pool[lead + (pg,)]                           # (..., page, K·Dh)
+    heads = rows.reshape(rows.shape[:-1] + val.shape[-2:])
+    repack = jnp.round(heads.astype(jnp.float32) * ratio[..., None, :, None])
+    pool = pool.at[lead + (pg,)].set(
+        repack.reshape(rows.shape).astype(pool.dtype), mode="drop")
     q = jnp.clip(jnp.round(v32 / _safe_scale(new)[..., None]), -_QMAX, _QMAX)
-    pool = pool.at[lead + (pg, off)].set(q.astype(pool.dtype), mode="drop")
+    pool = pool.at[lead + (pg, off)].set(
+        q.reshape(q.shape[:-2] + (-1,)).astype(pool.dtype), mode="drop")
     return pool, scale
 
 
@@ -588,16 +600,19 @@ def dequant_gather(pool: jnp.ndarray, scale: Optional[jnp.ndarray],
                    layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Gather a batch's pages as (B, n_pages * page, K, Dh), dequantizing
     through the per-page scales when given (None = full-precision pool:
-    bitwise the plain gather).  With ``layer`` the pool (and scales) are
-    stacked over layers and the pages come from that layer in one gather,
-    with no layer slice in between."""
+    bitwise the plain gather).  pool: (P, page, K·Dh); with ``layer`` the
+    pool (and scales) are stacked over layers and the pages come from that
+    layer in one gather, with no layer slice in between.  The heads are
+    split out of the gathered rows, never of the pool."""
     b, npg = block.shape
     lead = () if layer is None else (layer,)
-    gathered = pool[lead + (block,)]             # (B, npg, page, K, Dh)
+    page = pool.shape[-2]
+    gathered = pool[lead + (block,)].reshape(    # (B, npg, page, K, Dh)
+        b, npg, page, num_kv, head_dim)
     if scale is not None:
         gathered = gathered.astype(jnp.float32) \
             * scale[lead + (block,)][:, :, None, :, None]
-    return gathered.reshape(b, npg * pool.shape[-3], num_kv, head_dim)
+    return gathered.reshape(b, npg * page, num_kv, head_dim)
 
 
 def quant_dequant_pages(kv: jnp.ndarray, page: int) -> jnp.ndarray:
@@ -623,7 +638,7 @@ def quant_scatter_prefill_pages(pool: jnp.ndarray, scale: jnp.ndarray,
     (padding / shared-prefix suffixing) drop both page and scale writes
     there harmlessly.  ``layer``: as for :func:`scatter_prefill_pages`."""
     lead = () if layer is None else (layer,)
-    page = pool.shape[-3]
+    page = pool.shape[-2]
     a, s = seq_kv.shape[:2]
     paged = seq_kv.astype(jnp.float32).reshape(a, s // page, page,
                                                *seq_kv.shape[2:])
@@ -631,7 +646,8 @@ def quant_scatter_prefill_pages(pool: jnp.ndarray, scale: jnp.ndarray,
     sc = jnp.max(jnp.abs(paged), axis=(2, 4)) / _QMAX         # (A, npg, K)
     q = jnp.clip(jnp.round(paged / _safe_scale(sc)[:, :, None, :, None]),
                  -_QMAX, _QMAX)
-    pool = pool.at[lead + (rows,)].set(q.astype(pool.dtype), mode="drop")
+    pool = pool.at[lead + (rows,)].set(
+        q.reshape(a, s // page, page, -1).astype(pool.dtype), mode="drop")
     scale = scale.at[lead + (rows,)].set(sc, mode="drop")
     return pool, scale
 
@@ -639,7 +655,7 @@ def quant_scatter_prefill_pages(pool: jnp.ndarray, scale: jnp.ndarray,
 def cow_copy_scales(scale: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray
                     ) -> jnp.ndarray:
     """Scale-row companion of :func:`cow_copy_pages`: scale (..., P, K) with
-    the page axis at ndim-2 (vs ndim-4 for pools); same (src, dst) pairs,
+    the page axis at ndim-2 (vs ndim-3 for pools); same (src, dst) pairs,
     same null-page padding discipline."""
     axis = scale.ndim - 2
     idx = (slice(None),) * axis + (dst,)
@@ -651,17 +667,17 @@ def scatter_prefill_pages(pool: jnp.ndarray, seq_kv: jnp.ndarray,
                           layer: Optional[int] = None) -> jnp.ndarray:
     """Write a batch of sequences' prefill K (or V) into their pages.
 
-    pool: (P, page, K, Dh) — or, with ``layer``, a stacked (L, P, page, K,
-    Dh) pool written at that layer in place; seq_kv: (A, S, K, Dh) with
-    S % page == 0; block_rows: (A, n_pages) — only the first S // page
-    entries of each row are written.  Rows belonging to masked/padded
-    admissions point at the null page 0; their (raced, garbage) writes land
-    there harmlessly.
+    pool: (P, page, K·Dh) — or, with ``layer``, a stacked (L, P, page,
+    K·Dh) pool written at that layer in place; seq_kv: (A, S, K, Dh) with
+    S % page == 0, written as (A, S // page, page, K·Dh) page rows;
+    block_rows: (A, n_pages) — only the first S // page entries of each row
+    are written.  Rows belonging to masked/padded admissions point at the
+    null page 0; their (raced, garbage) writes land there harmlessly.
     """
     lead = () if layer is None else (layer,)
-    page = pool.shape[-3]
+    page = pool.shape[-2]
     a, s = seq_kv.shape[:2]
-    paged = seq_kv.reshape(a, s // page, page, *seq_kv.shape[2:])
+    paged = seq_kv.reshape(a, s // page, page, -1)
     return pool.at[lead + (block_rows[:, : s // page],)].set(
         paged.astype(pool.dtype), mode="drop")
 
@@ -686,7 +702,7 @@ def substitute_prefix_kv(pool: jnp.ndarray, inpass: jnp.ndarray,
                          scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Splice cached prefix K (or V) under the in-pass suffix values.
 
-    pool: (P, page, Kh, Dh); inpass: (A, S, Kh, Dh); block_rows: (A, n_pages);
+    pool: (P, page, Kh·Dh); inpass: (A, S, Kh, Dh); block_rows: (A, n_pages);
     start: (A,) first uncached position per row.  Positions < start read the
     slot's aliased pages (bitwise the values the row's own full prefill would
     have produced — the whole sharing-equivalence argument rests on this);
@@ -701,7 +717,7 @@ def substitute_prefix_kv(pool: jnp.ndarray, inpass: jnp.ndarray,
     a, s = inpass.shape[:2]
     page = pool.shape[1]
     rows = block_rows[:, : s // page]
-    cached = pool[rows]
+    cached = pool[rows].reshape(a, s // page, page, *inpass.shape[2:])
     if scale is not None:
         cached = cached.astype(jnp.float32) * scale[rows][:, :, None, :, None]
     cached = cached.reshape(a, s, *inpass.shape[2:])
@@ -714,13 +730,13 @@ def cow_copy_pages(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray
                    ) -> jnp.ndarray:
     """Copy-on-write page duplication: pool[..., dst_i, :] = pool[..., src_i, :].
 
-    pool: (..., P, page, K, Dh) with the page axis at ndim-4; src/dst: (C,)
+    pool: (..., P, page, K·Dh) with the page axis at ndim-3; src/dst: (C,)
     int32 physical page ids, padded with (0, 0) pairs — the null page copied
     onto itself is a no-op by construction.  Runs at the top of the scheduler
     tick, BEFORE prefill and decode, so an appending slot always owns a
     private copy of a retained tail page.
     """
-    axis = pool.ndim - 4
+    axis = pool.ndim - 3
     idx = (slice(None),) * axis + (dst,)
     return pool.at[idx].set(jnp.take(pool, src, axis=axis))
 

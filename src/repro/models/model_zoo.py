@@ -136,7 +136,7 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, cache, *,
 # ---------------------------------------------------------------------------
 #
 # ``init_paged_cache`` allocates ONE device buffer set per tier: attention
-# K/V lives in a (…, num_pages, page_size, K, Dh) physical page pool indexed
+# K/V lives in a (…, num_pages, page_size, K·Dh) physical page pool indexed
 # by the scheduler's int32 block table (physical page 0 is the null page);
 # recurrent SSM state is per-slot (constant-size — nothing to page).
 # ``prefill_paged`` admits ONE right-padded request into a slot at a FIXED

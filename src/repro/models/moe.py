@@ -455,7 +455,7 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lambda p, sc, kv: L.quant_scatter_prefill_pages(p, sc, kv, wrows)
         )(cache["vp"], cache["vs"], vs)
         return logits, {"kp": new_k, "vp": new_v, "ks": new_sk, "vs": new_sv}
-    shape = ks.shape[:1] + (b, npg, page) + ks.shape[3:]
+    shape = ks.shape[:1] + (b, npg, page, -1)
     new_k = cache["kp"].at[:, wrows].set(ks.reshape(shape), mode="drop")
     new_v = cache["vp"].at[:, wrows].set(vs.reshape(shape), mode="drop")
     return logits, {"kp": new_k, "vp": new_v}

@@ -347,7 +347,8 @@ def _prefill_body(cfg: ModelConfig, s: int, b: int, kv_dtype,
 # paged KV cache (continuous batching)
 # ---------------------------------------------------------------------------
 #
-# One physical page pool per tier — (L, P, page, K, Dh) — shared by every
+# One physical page pool per tier — (L, P, page, K·Dh), each position's KV
+# heads side by side in one minor dim (``layers.paged_kv``) — shared by every
 # decode slot through an int32 block table (slot, logical page) -> physical
 # page.  Physical page 0 is the null/trash page: idle slots and unallocated
 # logical pages point there, its contents are garbage by design, and no
@@ -358,8 +359,8 @@ def _prefill_body(cfg: ModelConfig, s: int, b: int, kv_dtype,
 def init_paged_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
                      page_size: int, dtype=jnp.bfloat16) -> Params:
     del num_slots                       # attention state lives in pages only
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads,
-             cfg.resolved_head_dim)
+    shape = (cfg.num_layers, num_pages, page_size,
+             cfg.num_kv_heads * cfg.resolved_head_dim)
     cache = {"kp": jnp.zeros(shape, dtype), "vp": jnp.zeros(shape, dtype)}
     if jnp.dtype(dtype) == jnp.int8:
         # symmetric per-page-per-head scales ride beside the int8 pools in
@@ -432,8 +433,8 @@ def prefill_paged(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lambda p, sc, kv: L.quant_scatter_prefill_pages(p, sc, kv, wrows)
         )(cache["vp"], cache["vs"], vs)
         return logits, {"kp": new_k, "vp": new_v, "ks": new_sk, "vs": new_sv}
-    # ks: (L, A, S, K, Dh) -> every layer's pages in one scatter
-    shape = ks.shape[:1] + (b, npg, page) + ks.shape[3:]
+    # ks: (L, A, S, K, Dh) -> every layer's (page, K·Dh) rows in one scatter
+    shape = ks.shape[:1] + (b, npg, page, -1)
     new_k = cache["kp"].at[:, wrows].set(ks.reshape(shape), mode="drop")
     new_v = cache["vp"].at[:, wrows].set(vs.reshape(shape), mode="drop")
     return logits, {"kp": new_k, "vp": new_v}

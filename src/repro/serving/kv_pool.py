@@ -550,13 +550,14 @@ class KVPool:
                     f"quantized pool {pool_key} must be int8, got {pool.dtype}"
                 assert jnp.dtype(scale.dtype) == jnp.float32, \
                     f"scale {scale_key} must be fp32, got {scale.dtype}"
-                # pools are (..., P, page, K, Dh), scales (..., P, K): one
+                # pools are (..., P, page, K·Dh), scales (..., P, K): one
                 # scale row per physical page and head
                 assert scale.shape[-2] == self.num_pages, \
                     f"scale {scale_key} has {scale.shape[-2]} rows for " \
                     f"{self.num_pages} pages"
-                assert scale.shape[:-2] == pool.shape[:-4] and \
-                    scale.shape[-1] == pool.shape[-2], \
+                assert scale.shape[:-2] == pool.shape[:-3] and \
+                    scale.shape[-1] * self.cfg.resolved_head_dim \
+                    == pool.shape[-1], \
                     f"scale {scale_key} shape {scale.shape} does not match " \
                     f"pool {pool_key} shape {pool.shape}"
         else:

@@ -205,26 +205,26 @@ def paged_cache_specs(cfg: ModelConfig, mesh: Mesh, buffers: Any) -> Any:
     """Sharding for a serving-tier PAGED pool pytree (``KVPool.buffers``).
 
     The L tier of the mesh-sharded scheduler keeps ONE pool whose page
-    tensors shard over ``model`` on the KV-head dim — the same tensor-
+    tensors shard over ``model`` on the KV heads — the same tensor-
     parallel cut as the attention projections, so the page-gather feeding a
     head group reads only that group's local pages.  Per leaf:
 
-    * ``kp`` / ``vp`` (L, P, page, K, Dh): K over ``model`` when divisible
-      (same ``_div`` degrade-to-replicate rule as the param specs);
+    * ``kp`` / ``vp`` (L, P, page, K·Dh): the folded head dim over
+      ``model`` when ``model`` divides K — each head's Dh lanes are
+      contiguous in it, so a shard holds whole heads; else replicated;
     * ``ks`` / ``vs`` int8-pool scales (L, P, K): K over ``model``;
     * everything else (recurrent ``state`` / ``conv`` rows, logits) —
       replicated: per-slot state is small and the slot dim is the DATA-axis
       concern, which the S tier handles by replica-stacking, not sharding.
     """
-    del cfg
+    heads = _div(cfg.num_kv_heads, mesh, "model")
 
     def rule(path, leaf):
         names = tuple(p.key if hasattr(p, "key") else str(p) for p in path)
         name = names[-1]
         nd = len(leaf.shape)
-        if name in ("kp", "vp") and nd == 5:
-            return P(None, None, None, _div(leaf.shape[3], mesh, "model"),
-                     None)
+        if name in ("kp", "vp") and nd == 4:
+            return P(None, None, None, heads)
         if name in ("ks", "vs") and nd == 3:
             return P(None, None, _div(leaf.shape[2], mesh, "model"))
         return P(*(None,) * nd)

@@ -150,8 +150,8 @@ def test_decode_attention_paged_matches_gather_oracle():
     rng = np.random.default_rng(3)
     b, h, k, d, page, npg, P = 3, 4, 2, 16, 8, 4, 13
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    pk = jnp.asarray(rng.standard_normal((P, page, k, d)), jnp.float32)
-    pv = jnp.asarray(rng.standard_normal((P, page, k, d)), jnp.float32)
+    pk = jnp.asarray(rng.standard_normal((P, page, k * d)), jnp.float32)
+    pv = jnp.asarray(rng.standard_normal((P, page, k * d)), jnp.float32)
     block = jnp.asarray(rng.integers(0, P, (b, npg)), jnp.int32)
     pos = jnp.asarray([5, 17, 31], jnp.int32)       # per-slot depths
     valid = jnp.arange(npg * page)[None, :] <= pos[:, None]

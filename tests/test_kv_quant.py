@@ -138,7 +138,7 @@ def test_copy_pages_int8_pool_with_scales():
     from repro.models import layers as L
 
     rng = np.random.default_rng(0)
-    pool = jnp.asarray(rng.integers(-127, 128, (2, 6, 4, 2, 3)), jnp.int8)
+    pool = jnp.asarray(rng.integers(-127, 128, (2, 6, 4, 2 * 3)), jnp.int8)
     scale = jnp.asarray(rng.random((2, 6, 2)), jnp.float32)
     src = jnp.asarray([4, 2, 0], jnp.int32)
     dst = jnp.asarray([1, 3, 0], jnp.int32)

@@ -199,7 +199,7 @@ def test_cow_kernel_matches_jnp_path():
     from repro.models import layers as L
 
     rng = np.random.default_rng(0)
-    for shape in [(2, 6, 4, 2, 3), (3, 5, 8, 1, 4)]:
+    for shape in [(2, 6, 4, 2 * 3), (3, 5, 8, 1 * 4)]:
         pool = jnp.asarray(rng.normal(size=shape), jnp.float32)
         src = jnp.asarray([4, 2, 0], jnp.int32)
         dst = jnp.asarray([1, 3, 0], jnp.int32)

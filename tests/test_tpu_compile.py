@@ -8,6 +8,10 @@ tiling, ops Mosaic cannot lower, VMEM overruns).  The topology is described
 inside a module fixture, never at import, so every xdist worker collects the
 same tests and only the one running this file loads the TPU library.
 """
+import dataclasses
+import re
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -17,6 +21,8 @@ from repro.configs.registry import ARCHS
 from repro.kernels import decode_attention as da
 from repro.kernels import hi_gate as hg
 from repro.kernels import ssd_scan as ssd
+from repro.models import model_zoo
+from repro.serving import scheduler as sch
 
 QWEN = ARCHS["qwen2-1.5b"]
 ZAMBA = ARCHS["zamba2-7b"]
@@ -61,7 +67,7 @@ def _paged_shapes(cfg, c: int, pool_dtype):
     """q, pools, block table, mask (+ int8 scales); the decode entry point
     (c = 1) takes its mask without the chunk axis."""
     h, kh, d = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    pool = ((POOL_PAGES, PAGE, kh, d), pool_dtype)
+    pool = ((POOL_PAGES, PAGE, kh * d), pool_dtype)
     valid = (SLOTS,) + (() if c == 1 else (c,)) + (PAGES_PER_SLOT * PAGE,)
     shapes = [((SLOTS, c, h, d), jnp.bfloat16), pool, pool,
               ((SLOTS, PAGES_PER_SLOT), jnp.int32), (valid, jnp.bool_)]
@@ -109,7 +115,7 @@ def test_contiguous_decode_compiles(one_chip):
 @pytest.mark.parametrize("what", ["pages", "scales"])
 def test_copy_pages_compiles(one_chip, what):
     kh, d = QWEN.num_kv_heads, QWEN.resolved_head_dim
-    pool = ((QWEN.num_layers, POOL_PAGES, PAGE, kh, d), jnp.bfloat16)
+    pool = ((QWEN.num_layers, POOL_PAGES, PAGE, kh * d), jnp.bfloat16)
     if what == "scales":
         pool = ((QWEN.num_layers, POOL_PAGES, kh), jnp.float32)
     hlo = _compile(lambda p, src: da.copy_pages_pallas(p, src,
@@ -139,3 +145,68 @@ def test_ssd_chunk_compiles_zamba2(one_chip):
         ((h,), jnp.float32), ((1, nc, g, q, n), jnp.float32),
         ((1, nc, g, q, n), jnp.float32))
     assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's tick: no copy of a whole K/V pool
+# ---------------------------------------------------------------------------
+
+_COPY = re.compile(r"^\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* "
+                   r"(copy|transpose)\(", re.M)
+
+
+def _two_layers(cfg):
+    """``cfg`` cut to two layers at its own widths; a hybrid applies its
+    shared blocks at both."""
+    ids = (0, 1) if cfg.hybrid_layer_ids else ()
+    return dataclasses.replace(cfg, num_layers=2, hybrid_layer_ids=ids)
+
+
+def _tick_pool_copies(sharding, s_cfg, l_cfg, *, sharing: bool):
+    """Compile both tiers' ticks (``scheduler._tier_tick_fn``) as the
+    scheduler composes them, pools donated, for the described chip; returns
+    the ``copy``/``transpose`` instructions whose result has a K/V pool's
+    shape, and the pool shapes."""
+    slots, page, prompt, new, block = 16, 16, 64, 16, 4
+    ctx = -(-(prompt + new + block - 1) // page) * page
+    on_chip = partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding))
+    ticks, args, pools = [], [], set()
+    for name, cfg, n in (("S", s_cfg, slots), ("L", l_cfg, slots // 2)):
+        rt = sch._TierRuntime(cfg, n, ctx, page, 4, jnp.bfloat16,
+                              prefix_entries=2 * n if sharing else 0,
+                              max_prompt_len=prompt, name=name, alloc=False)
+        ticks.append(sch._tier_tick_fn(name, cfg, "max_prob", False, block,
+                                       rt.sharing))
+        args.append(on_chip((model_zoo.init_params_spec(cfg),
+                             rt.tick_inputs(prompt), rt.pool_operand())))
+        pools |= {tuple(rt.pool.buffers[k].shape) for k in ("kp", "vp")}
+    (s_tick, l_tick), ((sp, si, s_pool), (lp, li, l_pool)) = ticks, args
+
+    def tick(s_params, l_params, theta, s_in, l_in, s_pool, l_pool):
+        s_out, s_pool = s_tick(s_params, theta, s_in, s_pool)
+        l_out, l_pool = l_tick(l_params, theta, l_in, l_pool)
+        return {"s": s_out, "l": l_out}, s_pool, l_pool
+
+    hlo = jax.jit(tick, donate_argnums=(5, 6)).lower(
+        sp, lp, jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding),
+        si, li, s_pool, l_pool).compile().as_text()
+    found = [(name, op, dims) for name, dims, op in _COPY.findall(hlo)
+             if tuple(int(d) for d in dims.split(",") if d) in pools]
+    return found, pools
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "zamba2-7b"])
+def test_tick_copies_no_pool(one_chip, arch):
+    """The tick XLA compiles for the chip reads and writes every K/V pool
+    where it lies: no lane (COW, admission, decode append, page gather)
+    wants another tiling of the pool than the others, so no whole pool is
+    copied, whatever each cell's K and head_dim: qwen2-1.5b's L and S tiers
+    (K 2 and 1, head_dim 128, prefix sharing on) and zamba2-7b's hybrid
+    tiers (K 32 and 8, head_dim 224, sharing off as in its cell)."""
+    cfg = ARCHS[arch]
+    found, pools = _tick_pool_copies(
+        one_chip, _two_layers(cfg.s_variant(4)), _two_layers(cfg),
+        sharing=arch == "qwen2-1.5b")
+    assert len(pools) == 2, pools
+    assert not found, found
